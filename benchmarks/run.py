@@ -24,6 +24,8 @@ import json  # noqa: E402
 import sys  # noqa: E402
 import traceback  # noqa: E402
 
+from repro.cache import enable_compile_cache  # noqa: E402
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -43,6 +45,7 @@ def main() -> None:
                     help="with --json: append the model-vs-HLO compile "
                          "audit lane (repro.obs.audit) to the artifact")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import (
         bench_apss_stream,

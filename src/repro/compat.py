@@ -1,19 +1,13 @@
-"""Version-tolerant shims over drifting JAX public APIs.
+"""One home for the mesh / shard_map conventions every caller shares.
 
-The repo targets the newest JAX mesh/shard_map surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``lax.pcast``) but must run on older installs
-where those names live elsewhere or don't exist. Every mesh construction
-and shard_map entry in src/, tests/ and benchmarks/ routes through this
-module so the drift is handled in exactly one place.
-
-Pallas compiler-params drift is handled separately in
-``repro.kernels._compat`` (kernel code should not import distributed
-helpers).
+Every mesh in src/, tests/ and benchmarks/ is Auto-typed, every
+``shard_map`` that hosts a Pallas kernel turns the replication checker off,
+and loop carries inside ``shard_map`` are marked device-varying. These
+helpers spell those three conventions once, on the installed JAX API.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
@@ -26,61 +20,25 @@ def make_mesh(
     *,
     devices=None,
 ):
-    """``jax.make_mesh`` with explicit Auto axis types when supported.
-
-    Newer JAX requires (or defaults differently) ``axis_types``; older JAX
-    (≤0.4.x) has neither ``axis_types`` nor ``jax.sharding.AxisType``. All
-    our meshes are Auto-typed, so on old versions the plain call is
-    equivalent.
-    """
-    shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with every axis Auto-typed."""
     names = tuple(axis_names)
-    mk = getattr(jax, "make_mesh", None)
-    if mk is None:  # pre-0.4.35: build the Mesh directly
-        import numpy as np
-
-        if devices is None:
-            from jax.experimental import mesh_utils
-
-            devices = mesh_utils.create_device_mesh(shapes)
-        return jax.sharding.Mesh(np.asarray(devices).reshape(shapes), names)
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None and (
-        "axis_types" in inspect.signature(mk).parameters
-    ):
-        kwargs["axis_types"] = (axis_type.Auto,) * len(names)
-    return mk(shapes, names, **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+        devices=devices,
+    )
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on old.
-
-    The replication-checker kwarg renamed ``check_rep`` → ``check_vma``;
-    callers use the new name and we translate.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    check_kwarg = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(
+    """``jax.shard_map``; ``check_vma=False`` for bodies that call Pallas
+    (the replication checker has no rule for ``pallas_call``)."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{check_kwarg: check_vma},
+        check_vma=check_vma,
     )
 
 
 def pvary(x, axis_names):
-    """Mark ``x`` device-varying over ``axis_names`` (VMA loop-carry typing).
-
-    ``lax.pcast`` only exists on JAX versions that track varying-manual-axes
-    in the type system; where it doesn't, the annotation is meaningless and
-    the identity is correct.
-    """
+    """Mark ``x`` device-varying over ``axis_names`` (VMA loop-carry typing)."""
     names = axis_names if isinstance(axis_names, tuple) else (axis_names,)
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, names, to="varying")
+    return lax.pcast(x, names, to="varying")

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.matches import Matches, extract_matches
+from repro.core.matches import SCORE_PRECISION, Matches, extract_matches
 from repro.core.pruning import (
     PruneStats,
     block_prune_mask,
@@ -78,7 +78,9 @@ def apss_reference(
     O(n²m) FLOPs, O(n²) memory — only for validation-scale inputs.
     """
     S = jnp.einsum(
-        "im,jm->ij", D, D, preferred_element_type=jnp.float32
+        "im,jm->ij", D, D,
+        precision=SCORE_PRECISION,
+        preferred_element_type=jnp.float32,
     )
     return extract_matches(S, threshold, k, exclude_self=exclude_self)
 
@@ -174,7 +176,11 @@ def similarity_topk(
 
     def body(carry, inputs):
         blk_idx, q_blk = inputs
-        s = jnp.einsum("im,jm->ij", q_blk, C, preferred_element_type=jnp.float32)
+        s = jnp.einsum(
+            "im,jm->ij", q_blk, C,
+            precision=SCORE_PRECISION,
+            preferred_element_type=jnp.float32,
+        )
         m = extract_matches(
             s,
             threshold,

@@ -51,6 +51,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.compat import pvary, shard_map
 from repro.core.apss import similarity_topk
 from repro.core.matches import (
+    SCORE_PRECISION,
     Matches,
     NEG_INF,
     extract_matches,
@@ -348,7 +349,9 @@ def _horizontal_halfring(
             )
             return fwd, bwd
         S = jnp.einsum(
-            "im,jm->ij", D_loc, buf, preferred_element_type=jnp.float32
+            "im,jm->ij", D_loc, buf,
+            precision=SCORE_PRECISION,
+            preferred_element_type=jnp.float32,
         )
         fwd = extract_matches(
             S, threshold, k, row_offset=row_off, col_offset=col_off,
@@ -821,7 +824,11 @@ def _apss_vertical_sparse(
 def _partial_scores(D_loc, blk, block_rows):
     """Partial similarity of one query row block in the local dim slice."""
     q = lax.dynamic_slice_in_dim(D_loc, blk * block_rows, block_rows, axis=0)
-    return jnp.einsum("im,jm->ij", q, D_loc, preferred_element_type=jnp.float32)
+    return jnp.einsum(
+        "im,jm->ij", q, D_loc,
+        precision=SCORE_PRECISION,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _vertical_allreduce(partials_fn, n, *, threshold, k, axis_name, block_rows):
@@ -1219,6 +1226,7 @@ def _apss_2d_local(
         qrows = lax.dynamic_slice_in_dim(D_loc, blk * bs, bs, axis=0)
         return jnp.einsum(
             "im,jm->ij", qrows, _from_wire(buf, D_loc.dtype),
+            precision=SCORE_PRECISION,
             preferred_element_type=jnp.float32,
         )
 

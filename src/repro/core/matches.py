@@ -23,6 +23,14 @@ import jax.numpy as jnp
 
 NEG_INF = jnp.float32(-jnp.inf)
 
+# Precision of every scoring dot: the kernels, the XLA scans, the pruning
+# bounds and the oracle. On a TPU, DEFAULT computes an f32 dot in a single
+# bf16 pass (about three significant digits), which moves scores across the
+# threshold, reorders near-ties in top-k and can push a tile's upper bound
+# below a score inside it. HIGHEST keeps f32 accuracy; on the CPU it is
+# what DEFAULT already computes.
+SCORE_PRECISION = jax.lax.Precision.HIGHEST
+
 
 class Matches(NamedTuple):
     """Top-k thresholded matches for a block of query rows."""

@@ -31,6 +31,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.matches import SCORE_PRECISION
+
 
 class BlockStats(NamedTuple):
     """Per-row-block pruning summaries — the *index-build* half of pruning.
@@ -134,7 +136,9 @@ def block_upper_bounds(maxw_rows: jax.Array, maxw_cols: jax.Array) -> jax.Array:
     bound at tile granularity).
     """
     return jnp.einsum(
-        "im,jm->ij", maxw_rows, maxw_cols, preferred_element_type=jnp.float32
+        "im,jm->ij", maxw_rows, maxw_cols,
+        precision=SCORE_PRECISION,
+        preferred_element_type=jnp.float32,
     )
 
 

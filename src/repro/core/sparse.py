@@ -45,7 +45,13 @@ import numpy as np
 from jax import lax
 
 from repro.compat import pvary
-from repro.core.matches import Matches, empty_matches, extract_matches, merge_matches
+from repro.core.matches import (
+    SCORE_PRECISION,
+    Matches,
+    empty_matches,
+    extract_matches,
+    merge_matches,
+)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -286,7 +292,9 @@ def gather_dot(
         i, v = iv  # (cols, chunk) each
         g = jnp.take(qd, i, axis=1)  # (rows, cols, chunk)
         return acc + jnp.einsum(
-            "rck,ck->rc", g, v, preferred_element_type=jnp.float32
+            "rck,ck->rc", g, v,
+            precision=SCORE_PRECISION,
+            preferred_element_type=jnp.float32,
         ), None
 
     acc, _ = lax.scan(step, jnp.zeros((rows, cols), jnp.float32), (idxc, valc))

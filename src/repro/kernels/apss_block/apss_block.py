@@ -27,10 +27,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.apss_block.fused import _tile_dot
 
 
 def _apss_block_kernel(
-    mask_ref,  # (1, 1) i32 — live flag for this (i, j) tile
+    mask_ref,  # SMEM (1, 1, grid_n) i32 — live flags of this row block's tiles
     x_ref,     # (bm, bk)
     y_ref,     # (bn, bk)
     o_ref,     # (bm, bn)
@@ -41,7 +44,7 @@ def _apss_block_kernel(
 ):
     kf = pl.program_id(2)
     nkf = pl.num_programs(2)
-    live = mask_ref[0, 0] != 0
+    live = mask_ref[0, 0, pl.program_id(1)] != 0
 
     @pl.when(kf == 0)
     def _init():
@@ -49,12 +52,7 @@ def _apss_block_kernel(
 
     @pl.when(live)
     def _accumulate():
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...],
-            y_ref[...],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += _tile_dot(x_ref[...], y_ref[...])
 
     @pl.when(kf == nkf - 1)
     def _emit():
@@ -99,29 +97,18 @@ def apss_block_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, kf: (i, j)),          # mask
+            pl.BlockSpec(
+                (1, 1, grid[1]), lambda i, j, kf: (i, 0, 0),
+                memory_space=pltpu.SMEM,
+            ),
             pl.BlockSpec((block_m, block_k), lambda i, j, kf: (i, kf)),
             pl.BlockSpec((block_n, block_k), lambda i, j, kf: (j, kf)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kf: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_rows, n_cols), out_dtype),
-        scratch_shapes=[_vmem((block_m, block_n), jnp.float32)],
-        compiler_params=_tpu_params(),
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(block_mask.astype(jnp.int32), x, y)
-
-
-def _vmem(shape, dtype):
-    from repro.kernels._compat import vmem
-
-    return vmem(shape, dtype)
-
-
-def _tpu_params():
-    """Mark (i, j) parallel and the feature axis sequential for the TPU
-    pipeline; harmless under interpret mode."""
-    from repro.kernels._compat import tpu_compiler_params
-
-    return tpu_compiler_params(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
+    )(block_mask.astype(jnp.int32).reshape(grid[0], 1, grid[1]), x, y)

@@ -38,14 +38,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params, vmem
+from repro.core.matches import SCORE_PRECISION
 
 # Finite stand-in for -inf inside the kernel (keeps Mosaic select/max
 # NaN-free); converted to true -inf at the ops boundary. Any real similarity
 # is a dot product of normalized rows, |s| « 1e30.
 NEG_LARGE = -0.5e30
 _VALID = -0.25e30  # values above this are real candidates
+
+
+def _tile_dot(x, y):
+    """``x · yᵀ`` of two row tiles, f32 accumulation at full f32 precision
+    (``SCORE_PRECISION``): the one scoring contraction of every kernel."""
+    return jax.lax.dot_general(
+        x, y,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=SCORE_PRECISION,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _merge_topk(topv, topi, cand_v, cand_i, k: int):
@@ -56,27 +68,34 @@ def _merge_topk(topv, topi, cand_v, cand_i, k: int):
     Returns the new ``(bm, kb)`` buffer holding the k best of the union
     (slots beyond k stay empty). Iterative max-extraction: k rounds of
     row-max, first-position select, mask-out — no sort, no lax.top_k.
+
+    The rounds are a ``fori_loop``, not a Python unroll: unrolled, every
+    round's ``(bm, kb + c)`` temporaries stay live at once, which overflows
+    the kernel's VMEM stack at k = 32 on a v5e, and k = 64 compiles for
+    minutes.
     """
-    bm, kb = topv.shape
     allv = jnp.concatenate([topv, cand_v], axis=1)
     alli = jnp.concatenate([topi, cand_i], axis=1)
     cols = allv.shape[1]
     colid = jax.lax.broadcasted_iota(jnp.int32, allv.shape, 1)
-    outv, outi = [], []
-    for _ in range(min(k, cols)):
+    slot = jax.lax.broadcasted_iota(jnp.int32, topv.shape, 1)
+
+    def round_(r, carry):
+        allv, outv, outi = carry
         m = jnp.max(allv, axis=1, keepdims=True)
         pos = jnp.min(jnp.where(allv >= m, colid, cols), axis=1, keepdims=True)
         sel = colid == pos
         idx = jnp.sum(jnp.where(sel, alli, 0), axis=1, keepdims=True)
         valid = m > _VALID
-        outv.append(jnp.where(valid, m, NEG_LARGE))
-        outi.append(jnp.where(valid, idx, -1))
-        allv = jnp.where(sel, NEG_LARGE, allv)
-    pad = kb - len(outv)
-    if pad:
-        outv.append(jnp.full((bm, pad), NEG_LARGE, jnp.float32))
-        outi.append(jnp.full((bm, pad), -1, jnp.int32))
-    return jnp.concatenate(outv, axis=1), jnp.concatenate(outi, axis=1)
+        outv = jnp.where(slot == r, jnp.where(valid, m, NEG_LARGE), outv)
+        outi = jnp.where(slot == r, jnp.where(valid, idx, -1), outi)
+        return jnp.where(sel, NEG_LARGE, allv), outv, outi
+
+    empty_v, empty_i = _empty_buffers(*topv.shape)
+    _, outv, outi = jax.lax.fori_loop(
+        0, min(k, cols), round_, (allv, empty_v, empty_i)
+    )
+    return outv, outi
 
 
 def _empty_buffers(bm: int, k: int):
@@ -148,19 +167,28 @@ def _tile_packets(
     fc = jnp.sum(ok, axis=1, keepdims=True, dtype=jnp.int32)
 
     # S = Sᵀ: the same tile scores the mirrored pairs — rows become the
-    # y-block's vectors, candidate ids the x-block's.
+    # y-block's vectors, candidate ids the x-block's. The mirror mask is
+    # rebuilt from the transposed f32/i32 operands: Mosaic transposes those
+    # but not a bool mask, so ``ok.T`` would not compile for the chip.
+    sT, growT, gcolT = s.T, grow.T, gcol.T
+    okT = (
+        (sT >= jnp.float32(threshold))
+        & (growT != gcolT)
+        & (growT < n_valid)
+        & (gcolT < n_valid)
+    )
     diag = ib == jb
     ev, ei = _empty_buffers(block_n, k)
     mv, mi = topk(
         ev, ei,
-        jnp.where(ok.T, s.T, NEG_LARGE), jnp.where(ok.T, grow.T, -1), k,
+        jnp.where(okT, sT, NEG_LARGE), jnp.where(okT, growT, -1), k,
     )
     bv = jnp.where(diag, ev, mv)
     bi = jnp.where(diag, ei, mi)
     bc = jnp.where(
         diag,
         jnp.int32(0),
-        jnp.sum(ok.T, axis=1, keepdims=True, dtype=jnp.int32),
+        jnp.sum(okT, axis=1, keepdims=True, dtype=jnp.int32),
     )
     return fv, fi, fc, bv, bi, bc
 
@@ -197,8 +225,8 @@ def _rect_tile_packets(
 
 
 def _fused_kernel(
-    mask_ref,   # (1, 1) i32 — live flag for this (i, j) tile
-    meta_ref,   # (1, 2) i32 — [row_offset, col_offset] (dynamic)
+    mask_ref,   # SMEM (1, 1, nb_c) i32 — live flags of this row block's tiles
+    meta_ref,   # SMEM (2,) i32 — [row_offset, col_offset] (dynamic)
     x_ref,      # (bm, bk)
     y_ref,      # (bn, bk)
     v_ref,      # out (bm, k) f32
@@ -221,7 +249,7 @@ def _fused_kernel(
     kf = pl.program_id(2)
     nj = pl.num_programs(1)
     nkf = pl.num_programs(2)
-    live = mask_ref[0, 0] != 0
+    live = mask_ref[0, 0, j] != 0
 
     @pl.when((j == 0) & (kf == 0))
     def _init_row_block():
@@ -235,17 +263,12 @@ def _fused_kernel(
 
     @pl.when(live)
     def _accumulate():
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...],
-            y_ref[...],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += _tile_dot(x_ref[...], y_ref[...])
 
     @pl.when((kf == nkf - 1) & live)
     def _merge_tile():
-        row_off = meta_ref[0, 0]
-        col_off = meta_ref[0, 1]
+        row_off = meta_ref[0]
+        col_off = meta_ref[1]
         s = acc_ref[...]
         lcol = j * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         gcol = lcol + col_off
@@ -296,6 +319,11 @@ def apss_fused_pallas(
         ``x[0]`` / ``y[0]`` (dynamic, for self-exclusion + global indices).
       n_valid_cols: number of non-padding rows of ``y`` (static).
 
+    The kernel reads each tile's live flag as a scalar from SMEM, one row
+    block's flags at a time (a ``(1, 1)`` VMEM block breaks Mosaic's
+    (8, 128) block rule, and the whole ``(nb, nb)`` mask would take SMEM
+    quadratic in the block count).
+
     Returns ``(values (n_rows, k) f32, indices (n_rows, k) i32,
     counts (n_rows, 1) i32)``. Empty slots are ``NEG_LARGE`` / ``-1``.
     """
@@ -313,12 +341,15 @@ def apss_fused_pallas(
         threshold=threshold, k=k, block_m=block_m, block_n=block_n,
         n_valid_cols=n_valid_cols, exclude_self=exclude_self,
     )
+    smem = pltpu.SMEM
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, kf: (i, j)),           # mask
-            pl.BlockSpec((1, 2), lambda i, j, kf: (0, 0)),           # meta
+            pl.BlockSpec(
+                (1, 1, grid[1]), lambda i, j, kf: (i, 0, 0), memory_space=smem
+            ),
+            pl.BlockSpec(memory_space=smem),
             pl.BlockSpec((block_m, block_k), lambda i, j, kf: (i, kf)),
             pl.BlockSpec((block_n, block_k), lambda i, j, kf: (j, kf)),
         ],
@@ -333,16 +364,20 @@ def apss_fused_pallas(
             jax.ShapeDtypeStruct((n_rows, 1), jnp.int32),
         ],
         scratch_shapes=[
-            vmem((block_m, block_n), jnp.float32),
-            vmem((block_m, k), jnp.float32),
-            vmem((block_m, k), jnp.int32),
-            vmem((block_m, 1), jnp.int32),
+            pltpu.VMEM((block_m, block_n), jnp.float32),
+            pltpu.VMEM((block_m, k), jnp.float32),
+            pltpu.VMEM((block_m, k), jnp.int32),
+            pltpu.VMEM((block_m, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(block_mask.astype(jnp.int32), meta.astype(jnp.int32), x, y)
+    )(
+        block_mask.astype(jnp.int32).reshape(grid[0], 1, grid[1]),
+        meta.astype(jnp.int32).reshape(2),
+        x, y,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -377,12 +412,7 @@ def _tile_cand_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Every worklist tile is live: no @pl.when gate, no wasted pipeline slot.
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...],
-        y_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _tile_dot(x_ref[...], y_ref[...])
 
     @pl.when(kf == nkf - 1)
     def _emit():
@@ -422,12 +452,7 @@ def _rect_cand_kernel(
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...],
-        y_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _tile_dot(x_ref[...], y_ref[...])
 
     @pl.when(kf == nkf - 1)
     def _emit():
@@ -468,8 +493,6 @@ def rect_tile_candidates_pallas(
     two so repeat queries never retrace; padding entries are masked at fold
     time (``ops.fold_rect_packets``), so the kernel just computes them.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     nq, m = Q.shape
     nc, m2 = C.shape
     assert m == m2, (m, m2)
@@ -496,7 +519,7 @@ def rect_tile_candidates_pallas(
             pl.BlockSpec((1, block_q, k), lambda t, kf, ij: (t, 0, 0)),
             pl.BlockSpec((1, block_q, 1), lambda t, kf, ij: (t, 0, 0)),
         ],
-        scratch_shapes=[vmem((block_q, block_c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, block_c), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
@@ -506,7 +529,7 @@ def rect_tile_candidates_pallas(
             jax.ShapeDtypeStruct((T, block_q, k), jnp.int32),
             jax.ShapeDtypeStruct((T, block_q, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -515,13 +538,14 @@ def rect_tile_candidates_pallas(
 
 def _rect_ee_cand_kernel(
     ij_ref,     # scalar-prefetch (2, T) i32 — live (qi, cj) tile coordinates
+    ub_ref,     # scalar-prefetch (T,) f32 — tile upper bounds (NEG_LARGE on
+                # padding)
     x_ref,      # (bq, bk) query tile
     y_ref,      # (bc, bk) corpus tile
-    ub_ref,     # (1, 1) f32 — this tile's upper bound (NEG_LARGE on padding)
     fv_ref,     # out (1, bq, k) f32
     fi_ref,     # out (1, bq, k) i32
     fc_ref,     # out (1, bq, 1) i32
-    sk_ref,     # out (1, 1) i32 — 1 iff this tile was early-exit skipped
+    sk_ref,     # out SMEM (T,) i32 — 1 iff tile t was early-exit skipped
     acc_ref,    # scratch (bq, bc) f32
     topv_ref,   # scratch (nq, k) f32 — running top-k VALUES per query row
     *,
@@ -550,13 +574,14 @@ def _rect_ee_cand_kernel(
     # (global row ≥ nq_valid) are excluded or an unfull row would pin the
     # block forever; padding worklist entries carry ub = NEG_LARGE and are
     # always skipped.
-    cur = pl.load(topv_ref, (pl.ds(qi * block_q, block_q), slice(None)))
+    rows_q = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+    cur = topv_ref[rows_q, :]
     kth = cur[:, k - 1:k]                                   # (bq, 1)
     rows = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, 1), 0
     )
     kth = jnp.where(rows < nq_valid, kth, -NEG_LARGE)
-    skip = jnp.min(kth) >= ub_ref[0, 0]
+    skip = jnp.min(kth) >= ub_ref[t]
 
     @pl.when(~skip & (kf == 0))
     def _init_acc():
@@ -564,19 +589,14 @@ def _rect_ee_cand_kernel(
 
     @pl.when(~skip)
     def _accumulate():
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...],
-            y_ref[...],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += _tile_dot(x_ref[...], y_ref[...])
 
     @pl.when((kf == nkf - 1) & skip)
     def _emit_neutral():
         fv_ref[0] = jnp.full((block_q, k), NEG_LARGE, jnp.float32)
         fi_ref[0] = jnp.full((block_q, k), -1, jnp.int32)
         fc_ref[0] = jnp.zeros((block_q, 1), jnp.int32)
-        sk_ref[0, 0] = jnp.int32(1)
+        sk_ref[t] = jnp.int32(1)
 
     @pl.when((kf == nkf - 1) & ~skip)
     def _emit():
@@ -588,14 +608,10 @@ def _rect_ee_cand_kernel(
         fv_ref[0] = fv
         fi_ref[0] = fi
         fc_ref[0] = fc
-        sk_ref[0, 0] = jnp.int32(0)
+        sk_ref[t] = jnp.int32(0)
         dummy = jnp.zeros((block_q, k), jnp.int32)
         merged_v, _ = _merge_topk(cur, dummy, fv, dummy, k)
-        pl.store(
-            topv_ref,
-            (pl.ds(qi * block_q, block_q), slice(None)),
-            merged_v,
-        )
+        topv_ref[rows_q, :] = merged_v
 
 
 def rect_tile_candidates_early_exit_pallas(
@@ -622,13 +638,11 @@ def rect_tile_candidates_early_exit_pallas(
     cannot terminate early, so — unlike the XLA while_loop path — skipped
     tiles still occupy pipeline slots; the win is the gated matmul.
 
-    Returns ``(fv, fi, fc, skipped)`` where ``skipped`` is ``(T, 1)`` i32.
+    Returns ``(fv, fi, fc, skipped)`` where ``skipped`` is ``(T,)`` i32.
     Exactness contract matches the XLA early-exit fold: top-k values and
     indices are bit-identical to the non-early-exit path; only counts
     beyond k are lost (the caller saturates them at k).
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     nq, m = Q.shape
     nc, m2 = C.shape
     assert m == m2, (m, m2)
@@ -637,7 +651,6 @@ def rect_tile_candidates_early_exit_pallas(
     T = ij.shape[1]
     assert ij.shape == (2, T)
     nkf = m // block_k
-    ub2 = ub.astype(jnp.float32).reshape(T, 1)
 
     kernel = functools.partial(
         _rect_ee_cand_kernel,
@@ -645,22 +658,27 @@ def rect_tile_candidates_early_exit_pallas(
         nc_valid=nc_valid, nq_valid=nq_valid,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(T, nkf),
         in_specs=[
-            pl.BlockSpec((block_q, block_k), lambda t, kf, ij: (ij[0, t], kf)),
-            pl.BlockSpec((block_c, block_k), lambda t, kf, ij: (ij[1, t], kf)),
-            pl.BlockSpec((1, 1), lambda t, kf, ij: (t, 0)),
+            pl.BlockSpec(
+                (block_q, block_k), lambda t, kf, ij, ub: (ij[0, t], kf)
+            ),
+            pl.BlockSpec(
+                (block_c, block_k), lambda t, kf, ij, ub: (ij[1, t], kf)
+            ),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, k), lambda t, kf, ij: (t, 0, 0)),
-            pl.BlockSpec((1, block_q, k), lambda t, kf, ij: (t, 0, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda t, kf, ij: (t, 0, 0)),
-            pl.BlockSpec((1, 1), lambda t, kf, ij: (t, 0)),
+            pl.BlockSpec((1, block_q, k), lambda t, kf, ij, ub: (t, 0, 0)),
+            pl.BlockSpec((1, block_q, k), lambda t, kf, ij, ub: (t, 0, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda t, kf, ij, ub: (t, 0, 0)),
+            # Skip flags stay whole in SMEM (a (1, 1) VMEM block per tile
+            # breaks Mosaic's (8, 128) block rule).
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
-            vmem((block_q, block_c), jnp.float32),
-            vmem((nq, k), jnp.float32),
+            pltpu.VMEM((block_q, block_c), jnp.float32),
+            pltpu.VMEM((nq, k), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -670,16 +688,16 @@ def rect_tile_candidates_early_exit_pallas(
             jax.ShapeDtypeStruct((T, block_q, k), jnp.float32),
             jax.ShapeDtypeStruct((T, block_q, k), jnp.int32),
             jax.ShapeDtypeStruct((T, block_q, 1), jnp.int32),
-            jax.ShapeDtypeStruct((T, 1), jnp.int32),
+            jax.ShapeDtypeStruct((T,), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # Both axes "arbitrary": the running top-k scratch carried across
             # tiles makes the t axis order-dependent (vs "parallel" in the
             # non-early-exit kernel).
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(ij.astype(jnp.int32), Q, C, ub2)
+    )(ij.astype(jnp.int32), ub.astype(jnp.float32).reshape(T), Q, C)
 
 
 def apss_tile_candidates_pallas(
@@ -705,8 +723,6 @@ def apss_tile_candidates_pallas(
     (mirror) packets ``(T, bn, k)×2 + (T, bn, 1)``. Total output is
     ``O(live_tiles · block · k)`` — candidate-proportional, never n².
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     n, m = D.shape
     assert n % block_m == 0 and n % block_n == 0, (n, block_m, block_n)
     assert m % block_k == 0, (m, block_k)
@@ -734,7 +750,7 @@ def apss_tile_candidates_pallas(
             pl.BlockSpec((1, block_n, k), lambda t, kf, ij: (t, 0, 0)),
             pl.BlockSpec((1, block_n, 1), lambda t, kf, ij: (t, 0, 0)),
         ],
-        scratch_shapes=[vmem((block_m, block_n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
@@ -747,7 +763,7 @@ def apss_tile_candidates_pallas(
             jax.ShapeDtypeStruct((T, block_n, k), jnp.int32),
             jax.ShapeDtypeStruct((T, block_n, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

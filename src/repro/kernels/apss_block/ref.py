@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.matches import SCORE_PRECISION
+
 
 def apss_block_reference(
     x: jax.Array,
@@ -25,7 +27,9 @@ def apss_block_reference(
     oracles coincide (asserted by the property tests).
     """
     s = jnp.einsum(
-        "im,jm->ij", x, y, preferred_element_type=jnp.float32
+        "im,jm->ij", x, y,
+        precision=SCORE_PRECISION,
+        preferred_element_type=jnp.float32,
     )
     out = jnp.where(s >= jnp.float32(threshold), s, 0.0)
     if block_mask is not None:

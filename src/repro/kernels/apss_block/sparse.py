@@ -20,7 +20,8 @@ indexing):
    outside it contribute zero. MXU work per tile drops from ``O(bm·bn·m)``
    to ``O(bm·bn·S)``.
 4. :func:`sparse_tile_candidates_pallas` consumes ``(bx, yg)`` on a
-   scalar-prefetched 1-D worklist grid and emits forward/mirror candidate
+   scalar-prefetched worklist grid (live tile × support chunk, so VMEM
+   never holds a whole ``(bm, S)`` block) and emits forward/mirror candidate
    packets exactly like the dense ``apss_tile_candidates_pallas``
    (S = Sᵀ halves work; ``ops.fold_packets`` folds them into ``Matches``).
    ``use_kernel=False`` runs the same tiles through an XLA scan instead —
@@ -42,13 +43,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.matches import Matches, empty_matches
+from repro.core.matches import SCORE_PRECISION, Matches, empty_matches
 from repro.core.pruning import sparse_block_prune_mask
 from repro.core.sparse import SparseCorpus, pad_rows_sparse
-from repro.kernels._compat import tpu_compiler_params
 from repro.kernels.apss_block.fused import (
     _rect_tile_packets,
+    _tile_dot,
     _tile_packets,
     _topk_sort,
 )
@@ -109,20 +111,35 @@ def _gather_block(bd: jax.Array, idx: jax.Array, val: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: 1-D worklist grid over support-compacted tiles
+# Pallas kernels: worklist grid (tile t, support chunk s) over
+# support-compacted tiles
 # ---------------------------------------------------------------------------
+
+
+def _support_tile(S: int) -> int:
+    """Support-axis chunk of the CSR kernels: the largest of 512/256/128
+    that divides ``S`` (``S`` itself when it is not lane-aligned).
+
+    The support is a reduction grid axis, like ``kf`` in the dense kernels,
+    so VMEM holds ``(block, chunk)`` operand slices and never a whole
+    ``(block, S)`` block: at the radikal shape (S = 13,824 at block 256)
+    one whole f32 block is 13.5 MiB, and two double-buffered operands
+    would want about 54 MiB of VMEM.
+    """
+    return next((c for c in (512, 256, 128) if S % c == 0), S)
 
 
 def _sparse_tile_kernel(
     ij_ref,     # scalar-prefetch (2, T) i32 — live (i, j) tile coordinates
-    bx_ref,     # (1, bm, S) — row block densified on its own support
-    yg_ref,     # (1, bm, S) — col block gathered onto the row block support
+    bx_ref,     # (1, bm, bs) — row block densified on its own support
+    yg_ref,     # (1, bm, bs) — col block gathered onto the row block support
     fv_ref,     # out (1, bm, k) f32 — forward candidates (tile rows)
     fi_ref,     # out (1, bm, k) i32
     fc_ref,     # out (1, bm, 1) i32
     bv_ref,     # out (1, bm, k) f32 — backward candidates (mirror rows)
     bi_ref,     # out (1, bm, k) i32
     bc_ref,     # out (1, bm, 1) i32
+    acc_ref,    # scratch (bm, bm) f32
     *,
     threshold: float,
     k: int,
@@ -130,23 +147,27 @@ def _sparse_tile_kernel(
     n_valid: int,
 ):
     t = pl.program_id(0)
-    s = jax.lax.dot_general(
-        bx_ref[0],
-        yg_ref[0],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    fv, fi, fc, bv, bi, bc = _tile_packets(
-        s, ij_ref[0, t], ij_ref[1, t],
-        threshold=threshold, k=k, block_m=block_m, block_n=block_m,
-        n_valid=n_valid,
-    )
-    fv_ref[0] = fv
-    fi_ref[0] = fi
-    fc_ref[0] = fc
-    bv_ref[0] = bv
-    bi_ref[0] = bi
-    bc_ref[0] = bc
+    sc = pl.program_id(1)
+
+    @pl.when(sc == 0)
+    def _init_acc():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += _tile_dot(bx_ref[0], yg_ref[0])
+
+    @pl.when(sc == pl.num_programs(1) - 1)
+    def _emit():
+        fv, fi, fc, bv, bi, bc = _tile_packets(
+            acc_ref[...], ij_ref[0, t], ij_ref[1, t],
+            threshold=threshold, k=k, block_m=block_m, block_n=block_m,
+            n_valid=n_valid,
+        )
+        fv_ref[0] = fv
+        fi_ref[0] = fi
+        fc_ref[0] = fc
+        bv_ref[0] = bv
+        bi_ref[0] = bi
+        bc_ref[0] = bc
 
 
 def sparse_tile_candidates_pallas(
@@ -163,17 +184,16 @@ def sparse_tile_candidates_pallas(
     """Per-live-tile candidate packets from support-compacted operands.
 
     ``bx (nb, bm, S)`` rides the scalar-prefetched row-block index
-    ``ij[0, t]``; ``yg (T, bm, S)`` is per-worklist-tile. One grid step per
-    live tile, one ``(bm, S)×(S, bm)`` MXU contraction each — the sparse
-    analogue of ``apss_tile_candidates_pallas`` with ``S`` in place of
-    ``m``.
+    ``ij[0, t]``; ``yg (T, bm, S)`` is per-worklist-tile. One ``(bm, S)×(S,
+    bm)`` MXU contraction per live tile, accumulated over support chunks
+    (:func:`_support_tile`) — the sparse analogue of
+    ``apss_tile_candidates_pallas`` with ``S`` in place of ``m``.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     nb, bm, S = bx.shape
     T = ij.shape[1]
     assert yg.shape == (T, bm, S), (yg.shape, (T, bm, S))
     assert ij.shape == (2, T)
+    bs = _support_tile(S)
 
     kernel = functools.partial(
         _sparse_tile_kernel,
@@ -181,19 +201,20 @@ def sparse_tile_candidates_pallas(
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(T,),
+        grid=(T, S // bs),
         in_specs=[
-            pl.BlockSpec((1, bm, S), lambda t, ij: (ij[0, t], 0, 0)),
-            pl.BlockSpec((1, bm, S), lambda t, ij: (t, 0, 0)),
+            pl.BlockSpec((1, bm, bs), lambda t, sc, ij: (ij[0, t], 0, sc)),
+            pl.BlockSpec((1, bm, bs), lambda t, sc, ij: (t, 0, sc)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bm, k), lambda t, ij: (t, 0, 0)),
-            pl.BlockSpec((1, bm, k), lambda t, ij: (t, 0, 0)),
-            pl.BlockSpec((1, bm, 1), lambda t, ij: (t, 0, 0)),
-            pl.BlockSpec((1, bm, k), lambda t, ij: (t, 0, 0)),
-            pl.BlockSpec((1, bm, k), lambda t, ij: (t, 0, 0)),
-            pl.BlockSpec((1, bm, 1), lambda t, ij: (t, 0, 0)),
+            pl.BlockSpec((1, bm, k), lambda t, sc, ij: (t, 0, 0)),
+            pl.BlockSpec((1, bm, k), lambda t, sc, ij: (t, 0, 0)),
+            pl.BlockSpec((1, bm, 1), lambda t, sc, ij: (t, 0, 0)),
+            pl.BlockSpec((1, bm, k), lambda t, sc, ij: (t, 0, 0)),
+            pl.BlockSpec((1, bm, k), lambda t, sc, ij: (t, 0, 0)),
+            pl.BlockSpec((1, bm, 1), lambda t, sc, ij: (t, 0, 0)),
         ],
+        scratch_shapes=[pltpu.VMEM((bm, bm), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
@@ -206,8 +227,8 @@ def sparse_tile_candidates_pallas(
             jax.ShapeDtypeStruct((T, bm, k), jnp.int32),
             jax.ShapeDtypeStruct((T, bm, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary",)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
     )(ij.astype(jnp.int32), bx, yg)
@@ -215,11 +236,12 @@ def sparse_tile_candidates_pallas(
 
 def _rect_sparse_tile_kernel(
     ij_ref,     # scalar-prefetch (2, T) i32 — live (qi, cj) tile coordinates
-    qg_ref,     # (1, bq, S) — query block gathered onto the corpus support
-    bx_ref,     # (1, bm, S) — corpus block densified on its own support
+    qg_ref,     # (1, bq, bs) — query block gathered onto the corpus support
+    bx_ref,     # (1, bm, bs) — corpus block densified on its own support
     fv_ref,     # out (1, bq, k) f32
     fi_ref,     # out (1, bq, k) i32
     fc_ref,     # out (1, bq, 1) i32
+    acc_ref,    # scratch (bq, bm) f32
     *,
     threshold: float,
     k: int,
@@ -228,20 +250,24 @@ def _rect_sparse_tile_kernel(
     nc_valid: int,
 ):
     t = pl.program_id(0)
-    s = jax.lax.dot_general(
-        qg_ref[0],
-        bx_ref[0],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    fv, fi, fc = _rect_tile_packets(
-        s, ij_ref[1, t],
-        threshold=threshold, k=k, block_q=block_q, block_c=block_c,
-        nc_valid=nc_valid,
-    )
-    fv_ref[0] = fv
-    fi_ref[0] = fi
-    fc_ref[0] = fc
+    sc = pl.program_id(1)
+
+    @pl.when(sc == 0)
+    def _init_acc():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += _tile_dot(qg_ref[0], bx_ref[0])
+
+    @pl.when(sc == pl.num_programs(1) - 1)
+    def _emit():
+        fv, fi, fc = _rect_tile_packets(
+            acc_ref[...], ij_ref[1, t],
+            threshold=threshold, k=k, block_q=block_q, block_c=block_c,
+            nc_valid=nc_valid,
+        )
+        fv_ref[0] = fv
+        fi_ref[0] = fi
+        fc_ref[0] = fc
 
 
 def rect_sparse_tile_candidates_pallas(
@@ -266,14 +292,14 @@ def rect_sparse_tile_candidates_pallas(
     per-worklist-tile (the query rows' components at ``bdims[cj]``,
     gathered in XLA); ``bx (nb, bm, S)`` rides the scalar-prefetched
     corpus-block index. Forward packets only — no mirror, no self-pairs.
+    The support axis is a reduction grid axis (:func:`_support_tile`).
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     nb, bm, S = bx.shape
     T = ij.shape[1]
     assert qg.shape == (T, block_q, S), (qg.shape, (T, block_q, S))
     assert bm == block_c, (bm, block_c)
     assert ij.shape == (2, T)
+    bs = _support_tile(S)
 
     kernel = functools.partial(
         _rect_sparse_tile_kernel,
@@ -282,16 +308,19 @@ def rect_sparse_tile_candidates_pallas(
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(T,),
+        grid=(T, S // bs),
         in_specs=[
-            pl.BlockSpec((1, block_q, S), lambda t, ij: (t, 0, 0)),
-            pl.BlockSpec((1, block_c, S), lambda t, ij: (ij[1, t], 0, 0)),
+            pl.BlockSpec((1, block_q, bs), lambda t, sc, ij: (t, 0, sc)),
+            pl.BlockSpec(
+                (1, block_c, bs), lambda t, sc, ij: (ij[1, t], 0, sc)
+            ),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, k), lambda t, ij: (t, 0, 0)),
-            pl.BlockSpec((1, block_q, k), lambda t, ij: (t, 0, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda t, ij: (t, 0, 0)),
+            pl.BlockSpec((1, block_q, k), lambda t, sc, ij: (t, 0, 0)),
+            pl.BlockSpec((1, block_q, k), lambda t, sc, ij: (t, 0, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda t, sc, ij: (t, 0, 0)),
         ],
+        scratch_shapes=[pltpu.VMEM((block_q, block_c), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
@@ -301,8 +330,8 @@ def rect_sparse_tile_candidates_pallas(
             jax.ShapeDtypeStruct((T, block_q, k), jnp.int32),
             jax.ShapeDtypeStruct((T, block_q, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary",)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
     )(ij.astype(jnp.int32), qg, bx)
@@ -344,6 +373,7 @@ def _sparse_compacted_inner(
         def tile(_, t):
             s = jnp.einsum(
                 "rs,cs->rc", bx[ij[0, t]], gather_t(t),
+                precision=SCORE_PRECISION,
                 preferred_element_type=jnp.float32,
             )
             return _, _tile_packets(

@@ -95,8 +95,6 @@ def decode_attention_pallas(
 
     from jax.experimental.pallas import tpu as pltpu
 
-    from repro.kernels._compat import tpu_compiler_params
-
     kernel = functools.partial(_decode_kernel, scale=scale, block_k=block_k)
     lengths2d = lengths.reshape(b, 1).astype(jnp.int32)
     acc, m, l = pl.pallas_call(
@@ -129,7 +127,7 @@ def decode_attention_pallas(
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -106,8 +106,6 @@ def flash_attention_pallas(
     )
     from jax.experimental.pallas import tpu as pltpu
 
-    from repro.kernels._compat import tpu_compiler_params
-
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -133,7 +131,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -1,12 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # placeholder devices; never the chip
 
 """Multi-pod dry-run: lower + compile every (arch × shape) on the production
 mesh and extract the roofline terms from the compiled artifact.
 
 MUST be executed as its own process (``python -m repro.launch.dryrun``): the
-XLA_FLAGS line above runs before any other import so jax initializes with
-512 placeholder host devices. Smoke tests / benches never import this module.
+two environment lines above run before any other import so jax initializes
+with 512 placeholder host devices on the CPU platform. Its ``--subprocess``
+children inherit both, so neither the driver nor a child ever takes an
+attached accelerator. Smoke tests / benches never import this module.
 
 Per cell it records (JSON under --out):
   - compile wall time, per-device memory_analysis (args/outputs/temps)

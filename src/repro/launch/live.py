@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from repro.cache import enable_compile_cache
 from repro.obs import MetricsRegistry, Tracer, export
 from repro.planner import telemetry
 from repro.serving import MutableAPSSIndex, RetrievalServer
@@ -52,6 +53,7 @@ def main() -> None:
                     help="write a metrics snapshot to PATH (.prom/.txt ->"
                          " Prometheus text, otherwise JSON)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = Tracer() if args.trace_out else None
     registry = MetricsRegistry() if args.metrics_out else None

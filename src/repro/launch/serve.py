@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.cache import enable_compile_cache
 from repro.models.transformer import (
     decode_step,
     init_transformer,
@@ -254,6 +255,7 @@ def main() -> None:
                     help="write a metrics snapshot to PATH (.prom/.txt ->"
                          " Prometheus text, otherwise JSON)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.obs import MetricsRegistry, Tracer, export
 

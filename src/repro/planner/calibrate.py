@@ -13,7 +13,8 @@ exists, else the deterministic :func:`~repro.planner.costmodel.default_profile`
 ``launch/serve.py --mode auto`` / ``benchmarks/bench_planner.py``) to
 measure.
 
-Cache location: ``$REPRO_CALIB_DIR`` or ``~/.cache/repro_apss/``.
+Cache location: ``$REPRO_CALIB_DIR`` or ``<checkout>/.cache/calibration/``
+(``repro.cache.CACHE_ROOT``).
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.cache import CACHE_ROOT
 from repro.planner.costmodel import CalibrationProfile, default_profile
 
 _MEMO: dict[str, CalibrationProfile] = {}
@@ -40,7 +42,7 @@ def device_kind() -> str:
 
 def profile_path(kind: Optional[str] = None) -> Path:
     base = Path(
-        os.environ.get("REPRO_CALIB_DIR", Path.home() / ".cache" / "repro_apss")
+        os.environ.get("REPRO_CALIB_DIR", CACHE_ROOT / "calibration")
     )
     return base / f"calibration_{kind or device_kind()}.json"
 

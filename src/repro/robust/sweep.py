@@ -46,7 +46,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.core.apss import pad_rows
-from repro.core.matches import Matches, extract_matches, merge_matches
+from repro.core.matches import (
+    SCORE_PRECISION,
+    Matches,
+    extract_matches,
+    merge_matches,
+)
 from repro.obs import trace
 from repro.planner import telemetry
 
@@ -63,7 +68,9 @@ def _sweep_step(Db, values, indices, counts, s, *, threshold, k, bn, n):
     B = Db.shape[0]
     rolled = jnp.roll(Db, s, axis=0)
     S = jnp.einsum(
-        "bim,bjm->bij", Db, rolled, preferred_element_type=jnp.float32
+        "bim,bjm->bij", Db, rolled,
+        precision=SCORE_PRECISION,
+        preferred_element_type=jnp.float32,
     )
     bi = jnp.arange(B, dtype=jnp.int32)
     row_off = bi * bn

@@ -79,7 +79,7 @@ from repro.checkpoint import (
     load_checkpoint,
 )
 from repro.core.apss import normalize_rows
-from repro.core.matches import Matches
+from repro.core.matches import SCORE_PRECISION, Matches
 from repro.core.pruning import (
     BlockStats,
     dense_block_stats,
@@ -234,7 +234,9 @@ def _mut_dense_inner(
     def tile(_, t):
         i, j = ij[0, t], ij[1, t]
         s = jnp.einsum(
-            "qm,cm->qc", Qb[i], Cb[j], preferred_element_type=jnp.float32
+            "qm,cm->qc", Qb[i], Cb[j],
+            precision=SCORE_PRECISION,
+            preferred_element_type=jnp.float32,
         )
         gcol = j * block_c + lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(liveb[j][None, :], s, NEG_LARGE)

@@ -58,6 +58,7 @@ from jax.sharding import PartitionSpec as P
 from repro.compat import shard_map
 from repro.core.matches import (
     NEG_INF,
+    SCORE_PRECISION,
     Matches,
     empty_matches,
     merge_matches,
@@ -381,6 +382,7 @@ def _rect_dense_inner(
         def tile(_, t):
             s = jnp.einsum(
                 "qm,cm->qc", Qb[ij[0, t]], Cb[ij[1, t]],
+                precision=SCORE_PRECISION,
                 preferred_element_type=jnp.float32,
             )
             return _, _rect_tile_packets(
@@ -436,6 +438,7 @@ def _rect_sparse_inner(
         def tile(_, t):
             s = jnp.einsum(
                 "qs,cs->qc", gather_t(t), bx[ij[1, t]],
+                precision=SCORE_PRECISION,
                 preferred_element_type=jnp.float32,
             )
             return _, _rect_tile_packets(
@@ -547,6 +550,7 @@ def _rect_dense_ee_inner(
     def score_tile(t):
         s = jnp.einsum(
             "qm,cm->qc", Qb[ij[0, t]], Cb[ij[1, t]],
+            precision=SCORE_PRECISION,
             preferred_element_type=jnp.float32,
         )
         return _rect_tile_packets(
@@ -582,6 +586,7 @@ def _rect_sparse_ee_inner(
         qg = jnp.take(Qb[ij[0, t]], bdims[ij[1, t]], axis=1)  # (bq, S)
         s = jnp.einsum(
             "qs,cs->qc", qg, bx[ij[1, t]],
+            precision=SCORE_PRECISION,
             preferred_element_type=jnp.float32,
         )
         return _rect_tile_packets(
@@ -631,7 +636,7 @@ def _rect_dense_ee_kernel(
         ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q, block_q=block_q, k=k
     )
     counts = jnp.minimum(counts, k)
-    scored = jnp.sum(jnp.where(tvalid, 1 - sk[:, 0], 0).astype(jnp.int32))
+    scored = jnp.sum(jnp.where(tvalid, 1 - sk, 0).astype(jnp.int32))
     return values, indices, counts, scored
 
 
@@ -771,6 +776,7 @@ def _sharded_query(
             def tile(_, t):
                 s = jnp.einsum(
                     "qm,cm->qc", Qb[ij_l[0, t]], Cb[ij_l[1, t]],
+                    precision=SCORE_PRECISION,
                     preferred_element_type=jnp.float32,
                 )
                 return _, _rect_tile_packets(

@@ -523,8 +523,8 @@ def test_modifyitems_respects_env(monkeypatch):
 
 def test_ci_workflow_wires_the_gate():
     """The workflow must call the schema module (not a heredoc), set the
-    virtual-device count job-wide, and fan the matrix out over python and
-    jax versions with sharded tier-1."""
+    virtual-device count job-wide, pin the one JAX the code targets, and
+    fan the matrix out over python versions with sharded tier-1."""
     wf = (
         pathlib.Path(__file__).resolve().parent.parent
         / ".github" / "workflows" / "ci.yml"
@@ -534,7 +534,8 @@ def test_ci_workflow_wires_the_gate():
     assert "xla_force_host_platform_device_count=8" in wf
     assert "fail-fast: false" in wf
     assert "PYTEST_NUM_SHARDS" in wf
-    assert '"3.10"' in wf and '"3.11"' in wf
+    assert '"3.11"' in wf and '"3.12"' in wf
+    assert "jax[cpu]==${JAX_VERSION}" in wf and "JAX_VERSION: 0.9.0" in wf
     assert "upload-artifact" in wf
     assert "ruff check" in wf and "ruff format --check" in wf
     assert "python - <<" not in wf  # the heredoc is gone for good

@@ -23,6 +23,7 @@ from repro.checkpoint import (
     save_checkpoint,
 )
 from repro.core.apss import apss_reference
+from repro.core.graph import match_set
 from repro.distributed.straggler import StepTimer
 from repro.planner import telemetry
 from repro.robust import (
@@ -42,6 +43,22 @@ def _matches_equal(a, b):
         np.array_equal(np.asarray(a.values), np.asarray(b.values))
         and np.array_equal(np.asarray(a.indices), np.asarray(b.indices))
         and np.array_equal(np.asarray(a.counts), np.asarray(b.counts))
+    )
+
+
+def _agrees_with_oracle(got, ref):
+    """Against ``apss_reference``, which sums each score in another order:
+    the same match pairs, indices and counts exactly, values to f32
+    rounding. (A sweep against its own uninterrupted run stays
+    bit-identical: ``_matches_equal``.)"""
+    return (
+        match_set(got) == match_set(ref)
+        and np.array_equal(np.asarray(got.indices), np.asarray(ref.indices))
+        and np.array_equal(np.asarray(got.counts), np.asarray(ref.counts))
+        and np.allclose(
+            np.asarray(got.values), np.asarray(ref.values),
+            rtol=1e-6, atol=1e-6,
+        )
     )
 
 
@@ -97,7 +114,7 @@ def test_sweep_matches_oracle(corpus, tmp_path):
     got = ResumableSweep(
         corpus, threshold=T, k=K, block_rows=BN, directory=str(tmp_path)
     ).run()
-    assert _matches_equal(got, ref)
+    assert _agrees_with_oracle(got, ref)
 
 
 def test_sweep_mesh_bit_identical_to_single_device(corpus, tmp_path, mesh8):
@@ -222,7 +239,7 @@ def test_restore_falls_back_past_corrupt_step(corpus, tmp_path):
         )
         got = resumed.run()
     assert resumed.resumed_from == 2  # one checkpoint window lost, not the job
-    assert _matches_equal(got, ref)
+    assert _agrees_with_oracle(got, ref)
 
 
 def test_restore_raises_when_all_corrupt(tmp_path):
@@ -297,7 +314,7 @@ def test_evict_report_shrinks_mesh_and_resume_is_exact(corpus, tmp_path, mesh8):
     smaller = mesh_after_eviction(mesh8, report)
     assert smaller.devices.size == 7
     got = killer.resume_on(smaller).run()
-    assert _matches_equal(got, ref)
+    assert _agrees_with_oracle(got, ref)
 
 
 def test_mesh_after_eviction_noop_without_stragglers(mesh8):
