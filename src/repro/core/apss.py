@@ -35,6 +35,7 @@ from repro.core.sparse import (
     pad_rows_sparse,
     sparse_similarity_topk,
 )
+from repro.obs import trace
 from repro.planner import telemetry
 
 
@@ -265,6 +266,17 @@ def apss_blocked(
     return m, prune_stats(mask)
 
 
+@functools.partial(jax.jit, static_argnames=("block_rows",))
+def _sparse_self_bounds(D: SparseCorpus, threshold, *, block_rows: int):
+    """The self-join's live tile mask and tile upper bounds, ``(nb, nb)``
+    each over ``D`` row-padded to ``block_rows``: block stats and bounds in
+    one program."""
+    with jax.named_scope("mask"):
+        Dp, _ = pad_rows_sparse(D, block_rows)
+        stats = sparse_block_stats(Dp, block_rows)
+        return live_tile_mask(stats, stats, threshold, return_ub=True)
+
+
 def _apss_blocked_sparse(
     D: SparseCorpus,
     threshold: float,
@@ -274,41 +286,43 @@ def _apss_blocked_sparse(
     with_prune_stats: bool,
     use_kernel: bool,
 ) -> Matches | tuple[Matches, PruneStats]:
-    mask = ub = None
     bs = _kernel_tile(block_rows) if use_kernel else block_rows
-    if with_prune_stats or use_kernel:
-        # Index-build half (block stats) separated from the scoring-time
-        # mask so the bounds are computed exactly once here and shared by
-        # the worklist AND the accounting (serving builds the same stats
-        # once per corpus — see serving/index.py).
-        Dp, _ = pad_rows_sparse(D, bs)
-        stats = sparse_block_stats(Dp, bs)
-        mask, ub = live_tile_mask(stats, stats, threshold, return_ub=True)
-    if use_kernel:
-        from repro.kernels.apss_block.sparse import apss_sparse_compacted
+    with trace.span("apss/selfjoin", n=D.n, k=k, block_rows=bs):
+        mask = ub = None
+        if with_prune_stats or use_kernel:
+            # Index-build half (block stats) separated from the scoring-time
+            # mask so the bounds are computed exactly once here and shared by
+            # the worklist AND the accounting (serving builds the same stats
+            # once per corpus — see serving/index.py).
+            with trace.span("apss/bounds"):
+                mask, ub = _sparse_self_bounds(D, threshold, block_rows=bs)
+                if use_kernel:  # host-compacted below: pull the mask here
+                    mask, ub = np.asarray(mask), np.asarray(ub)
+        if use_kernel:
+            from repro.kernels.apss_block.sparse import apss_sparse_compacted
 
-        m = apss_sparse_compacted(
-            D, float(threshold), k,
-            block_m=bs, block_mask=mask, block_ub=ub, use_kernel=True,
-        )
-    else:
-        m = sparse_similarity_topk(
-            D, D, threshold, k, block_rows=block_rows, exclude_self=True
-        )
-    if telemetry.enabled():
-        live, total, counts = _mask_counts(mask)
-        flops = telemetry.sparse_join_flops(D.n, D.n, D.cap)
-        if use_kernel and live is not None and total:
-            flops *= live / total  # worklist compaction skips dead tiles
-        telemetry.record(telemetry.ApssStats(
-            variant="blocked/sparse-kernel" if use_kernel else "blocked/sparse-xla",
-            n=D.n, m=D.m, block_rows=bs, sparse=True, flops=flops,
-            live_tiles=live, total_tiles=total, tile_counts=counts,
-            extra={"cap": D.cap},
-        ))
-    if not with_prune_stats:
-        return m
-    return m, prune_stats(mask)
+            m = apss_sparse_compacted(
+                D, float(threshold), k,
+                block_m=bs, block_mask=mask, block_ub=ub, use_kernel=True,
+            )
+        else:
+            m = sparse_similarity_topk(
+                D, D, threshold, k, block_rows=block_rows, exclude_self=True
+            )
+        if telemetry.enabled():
+            live, total, counts = _mask_counts(mask)
+            flops = telemetry.sparse_join_flops(D.n, D.n, D.cap)
+            if use_kernel and live is not None and total:
+                flops *= live / total  # worklist compaction skips dead tiles
+            telemetry.record(telemetry.ApssStats(
+                variant="blocked/sparse-kernel" if use_kernel else "blocked/sparse-xla",
+                n=D.n, m=D.m, block_rows=bs, sparse=True, flops=flops,
+                live_tiles=live, total_tiles=total, tile_counts=counts,
+                extra={"cap": D.cap},
+            ))
+        if not with_prune_stats:
+            return m
+        return m, prune_stats(mask)
 
 
 @functools.partial(jax.jit, static_argnames=("threshold", "k", "block_rows"))

@@ -269,27 +269,28 @@ def fold_rect_packets(ij, tvalid, fv, fi, fc, *, grid_q, block_q, k):
     (values → −∞, ids → −1, counts → 0) BEFORE the merge so a padding
     entry that aliases a real tile can never double-count.
     """
-    dead = ~tvalid
-    fv = jnp.where(dead[:, None, None], NEG_INF, fv)
-    fi = jnp.where(dead[:, None, None], -1, fi)
-    fc = jnp.where(dead[:, None], 0, fc)
+    with jax.named_scope("fold"):
+        dead = ~tvalid
+        fv = jnp.where(dead[:, None, None], NEG_INF, fv)
+        fi = jnp.where(dead[:, None, None], -1, fi)
+        fc = jnp.where(dead[:, None], 0, fc)
 
-    def step(carry, inp):
-        cv, ci, cc = carry
-        ib, fv_t, fi_t, fc_t = inp
-        cv, ci, cc = _merge_packet(cv, ci, cc, ib, fv_t, fi_t, fc_t, k)
-        return (cv, ci, cc), None
+        def step(carry, inp):
+            cv, ci, cc = carry
+            ib, fv_t, fi_t, fc_t = inp
+            cv, ci, cc = _merge_packet(cv, ci, cc, ib, fv_t, fi_t, fc_t, k)
+            return (cv, ci, cc), None
 
-    carry0 = (
-        jnp.full((grid_q, block_q, k), -jnp.inf, jnp.float32),
-        jnp.full((grid_q, block_q, k), -1, jnp.int32),
-        jnp.zeros((grid_q, block_q), jnp.int32),
-    )
-    (cv, ci, cc), _ = jax.lax.scan(step, carry0, (ij[0], fv, fi, fc))
-    values = jnp.where(ci >= 0, cv, NEG_INF).reshape(grid_q * block_q, k)
-    indices = ci.reshape(grid_q * block_q, k)
-    counts = cc.reshape(grid_q * block_q)
-    return values, indices, counts
+        carry0 = (
+            jnp.full((grid_q, block_q, k), -jnp.inf, jnp.float32),
+            jnp.full((grid_q, block_q, k), -1, jnp.int32),
+            jnp.zeros((grid_q, block_q), jnp.int32),
+        )
+        (cv, ci, cc), _ = jax.lax.scan(step, carry0, (ij[0], fv, fi, fc))
+        values = jnp.where(ci >= 0, cv, NEG_INF).reshape(grid_q * block_q, k)
+        indices = ci.reshape(grid_q * block_q, k)
+        counts = cc.reshape(grid_q * block_q)
+        return values, indices, counts
 
 
 def fold_packets(ij, fv, fi, fc, bv, bi, bc, *, grid_m, block_m, k):
@@ -303,27 +304,28 @@ def fold_packets(ij, fv, fi, fc, bv, bi, bc, *, grid_m, block_m, k):
     the dense (:func:`apss_fused_compacted`) and sparse
     (``kernels.apss_block.sparse``) worklist paths.
     """
+    with jax.named_scope("fold"):
 
-    def step(carry, inp):
-        cv, ci, cc = carry
-        ib, jb, fv_t, fi_t, fc_t, bv_t, bi_t, bc_t = inp
-        cv, ci, cc = _merge_packet(cv, ci, cc, ib, fv_t, fi_t, fc_t, k)
-        # Mirror packet (empty for diagonal tiles): rows of block jb.
-        cv, ci, cc = _merge_packet(cv, ci, cc, jb, bv_t, bi_t, bc_t, k)
-        return (cv, ci, cc), None
+        def step(carry, inp):
+            cv, ci, cc = carry
+            ib, jb, fv_t, fi_t, fc_t, bv_t, bi_t, bc_t = inp
+            cv, ci, cc = _merge_packet(cv, ci, cc, ib, fv_t, fi_t, fc_t, k)
+            # Mirror packet (empty for diagonal tiles): rows of block jb.
+            cv, ci, cc = _merge_packet(cv, ci, cc, jb, bv_t, bi_t, bc_t, k)
+            return (cv, ci, cc), None
 
-    carry0 = (
-        jnp.full((grid_m, block_m, k), -jnp.inf, jnp.float32),
-        jnp.full((grid_m, block_m, k), -1, jnp.int32),
-        jnp.zeros((grid_m, block_m), jnp.int32),
-    )
-    (cv, ci, cc), _ = jax.lax.scan(
-        step, carry0, (ij[0], ij[1], fv, fi, fc, bv, bi, bc)
-    )
-    values = jnp.where(ci >= 0, cv, NEG_INF).reshape(grid_m * block_m, k)
-    indices = ci.reshape(grid_m * block_m, k)
-    counts = cc.reshape(grid_m * block_m)
-    return values, indices, counts
+        carry0 = (
+            jnp.full((grid_m, block_m, k), -jnp.inf, jnp.float32),
+            jnp.full((grid_m, block_m, k), -1, jnp.int32),
+            jnp.zeros((grid_m, block_m), jnp.int32),
+        )
+        (cv, ci, cc), _ = jax.lax.scan(
+            step, carry0, (ij[0], ij[1], fv, fi, fc, bv, bi, bc)
+        )
+        values = jnp.where(ci >= 0, cv, NEG_INF).reshape(grid_m * block_m, k)
+        indices = ci.reshape(grid_m * block_m, k)
+        counts = cc.reshape(grid_m * block_m)
+        return values, indices, counts
 
 
 @functools.partial(

@@ -55,6 +55,7 @@ from repro.kernels.apss_block.fused import (
     _topk_sort,
 )
 from repro.kernels.apss_block.ops import _on_tpu, compact_worklist, fold_packets
+from repro.obs import trace
 
 
 def block_support_gather(
@@ -362,7 +363,8 @@ def _sparse_compacted_inner(
         # The kernel consumes per-tile gathered operands as a streamed
         # input, so the (T, bm, S) buffer is materialized; moving the
         # binary-search gather in-kernel would remove it (ROADMAP).
-        _, yg = lax.scan(lambda _, t: (_, gather_t(t)), 0, jnp.arange(T))
+        with jax.named_scope("support_gather"):
+            _, yg = lax.scan(lambda _, t: (_, gather_t(t)), 0, jnp.arange(T))
         fv, fi, fc, bv, bi, bc = sparse_tile_candidates_pallas(
             bx, yg, ij, float(threshold), k,
             block_m=block_m, n_valid=n_valid, interpret=interpret,
@@ -371,8 +373,10 @@ def _sparse_compacted_inner(
         # XLA path gathers INSIDE the tile scan: peak extra memory is one
         # (bm, S) tile, never O(T · bm · S).
         def tile(_, t):
+            with jax.named_scope("support_gather"):
+                yg_t = gather_t(t)
             s = jnp.einsum(
-                "rs,cs->rc", bx[ij[0, t]], gather_t(t),
+                "rs,cs->rc", bx[ij[0, t]], yg_t,
                 precision=SCORE_PRECISION,
                 preferred_element_type=jnp.float32,
             )
@@ -427,21 +431,37 @@ def apss_sparse_compacted(
     if block_mask is not None:
         mask, ub = block_mask, block_ub
     else:
-        mask, ub = sparse_block_prune_mask(
-            spp, spp, threshold, block_m, use_minsize=use_minsize,
-            return_ub=True,
+        with trace.span("apss/bounds"):
+            mask, ub = sparse_block_prune_mask(
+                spp, spp, threshold, block_m, use_minsize=use_minsize,
+                return_ub=True,
+            )
+            mask, ub = np.asarray(mask), np.asarray(ub)
+    with trace.span("apss/worklist"):
+        wl = compact_worklist(mask, ub)
+        live = 0 if wl is None else int(wl.shape[1])
+        # the worklist is not padded: every entry is a live tile
+        trace.annotate(
+            live=live, total=grid_m * (grid_m + 1) // 2, entries=live
         )
-    wl = compact_worklist(mask, ub)
-    if wl is None:
-        return empty_matches(n, k)
-    ij = jnp.asarray(wl)
+        if wl is None:
+            return empty_matches(n, k)
+        ij = jnp.asarray(wl)
 
-    bdims, bx = block_support_gather(spp, block_m, pad_to=lane_pad)
+    with trace.span("apss/support_gather"):
+        bdims, bx = block_support_gather(spp, block_m, pad_to=lane_pad)
+        S = bdims.shape[1]
+        trace.annotate(blocks=grid_m, block_rows=block_m, support=S)
+        if use_kernel:
+            trace.annotate(support_chunk=_support_tile(S))
+    with trace.span("apss/upload", bytes=bx.nbytes + bdims.nbytes):
+        bx_d, bdims_d = jnp.asarray(bx), jnp.asarray(bdims)
     idxb = spp.indices.reshape(grid_m, block_m, spp.cap)
     valb = spp.values.reshape(grid_m, block_m, spp.cap)
-    values, indices, counts = _sparse_compacted_inner(
-        jnp.asarray(bx), jnp.asarray(bdims), idxb, valb, ij,
-        threshold=float(threshold), k=k, block_m=block_m, n_valid=n,
-        grid_m=grid_m, use_kernel=use_kernel, interpret=interpret,
-    )
+    with trace.span("apss/dispatch"):
+        values, indices, counts = _sparse_compacted_inner(
+            bx_d, bdims_d, idxb, valb, ij,
+            threshold=float(threshold), k=k, block_m=block_m, n_valid=n,
+            grid_m=grid_m, use_kernel=use_kernel, interpret=interpret,
+        )
     return Matches(values=values[:n], indices=indices[:n], counts=counts[:n])

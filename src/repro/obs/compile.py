@@ -2,16 +2,12 @@
 lower/compile spans with XLA memory analysis.
 
 The repo's retrace discipline ("build once, query many" — DESIGN.md §6/§9)
-was enforced by test-only ``TRACE_COUNTS`` dicts scattered in
-``serving/query.py`` / ``serving/mutable.py``. This module promotes them to
-ONE public registry:
+is enforced through ONE public registry:
 
 - :class:`CompileMonitor` (module singleton :data:`MONITOR`) holds the
   per-entry-point retrace :attr:`~CompileMonitor.counts`. Every jitted
   entry point calls :func:`mark` at trace time (a Python side effect runs
-  only when jit re-traces, so the counter IS the compilation count);
-  ``serving.query.TRACE_COUNTS`` remains a back-compat alias to the same
-  ``Counter`` object.
+  only when jit re-traces, so the counter IS the compilation count).
 - :func:`assert_no_retrace` is the budget contract: inside the context any
   watched entry point that re-traces fires every active
   :class:`~repro.obs.recorder.FlightRecorder` (reason
@@ -116,10 +112,9 @@ class _NoRetraceContract:
             stack.remove(self)
         if exc_type is not None:
             return  # already failing (possibly with our own RetraceError)
-        # Belt-and-braces: catch legacy `TRACE_COUNTS[x] += 1` bumps that
-        # bypassed mark() (the alias shares the Counter object). Names
-        # whose mark-time violation was already raised (and possibly
-        # caught by the caller) are not re-raised here.
+        # Belt-and-braces: catch direct `counts[x] += 1` bumps that
+        # bypassed mark(). Names whose mark-time violation was already
+        # raised (and possibly caught by the caller) are not re-raised here.
         counts = self.monitor.counts
         for n in (counts if self.watch_all else self.names):
             if n not in self.violated and counts[n] > self.baseline.get(n, 0):
@@ -141,8 +136,7 @@ class CompileMonitor:
     """Public registry of retrace counts, contracts, and compile records."""
 
     def __init__(self) -> None:
-        # Per-entry-point compilation counts. serving.query.TRACE_COUNTS
-        # aliases this object — legacy readers keep working unchanged.
+        # Per-entry-point compilation counts.
         self.counts: collections.Counter = collections.Counter()
         self.records: list[CompileRecord] = []
         self.groups: dict[str, tuple[str, ...]] = {}

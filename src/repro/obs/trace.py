@@ -3,25 +3,37 @@
 The planner's :class:`~repro.planner.telemetry.ApssStats` records are
 trace-time *models* — static shapes, modeled FLOPs, zero wall-clock (except
 the :class:`~repro.distributed.straggler.StepTicker`). This module adds the
-measured half: a :class:`Tracer` collects a tree of :class:`Span` objects
-(monotonic ``perf_counter`` wall-clock, nesting, per-span attributes) from
-every instrumented execution path — ``plan_apss``/``execute``, the
-distributed ring sweeps (whose per-step times arrive through the existing
-``StepTicker``/``jax.debug.callback`` seam and are adapted into
-``ring_step`` child spans at finalize), the serving request lifecycle
-(admit → batch → score → merge, with shed/degrade/retry events), the
-mutable-index WAL ops, and checkpoint save/restore.
+measured half. One :func:`span` call writes to two sinks:
 
-Guard discipline mirrors ``telemetry.enabled()``: with no active
-:class:`Tracer`, :func:`span` returns one shared no-op context manager and
-:func:`event`/:func:`annotate` return immediately — instrumented hot paths
-allocate nothing, plant no callbacks, and add zero device work
-(``tests/test_obs.py`` asserts this with ``TRACE_COUNTS`` and jaxprs).
+- a :class:`Tracer` collects a tree of :class:`Span` objects (monotonic
+  ``perf_counter`` wall-clock, nesting, per-span attributes) from every
+  instrumented execution path — ``plan_apss``/``execute``, the distributed
+  ring sweeps (whose per-step times arrive through the existing
+  ``StepTicker``/``jax.debug.callback`` seam and are adapted into
+  ``ring_step`` child spans at finalize), the serving request lifecycle
+  (admit → batch → score → merge, with shed/degrade/retry events), the
+  mutable-index WAL ops, and checkpoint save/restore;
+- a JAX profiler session (``jax.profiler.trace``): while one is on, each
+  span is also a ``jax.profiler.TraceAnnotation`` of the same name and
+  attributes, so it lands in the session's ``.xplane.pb`` on the
+  profiler's clock, beside the device's operations. :func:`annotate`
+  attaches counts known only at a span's end as its metadata.
+
+Guard discipline mirrors ``telemetry.enabled()``: with neither sink on,
+:func:`span` returns one shared no-op context manager (one list check and
+one ``TraceAnnotation.is_enabled()`` call) and :func:`event`/:func:`annotate`
+return immediately — instrumented hot paths allocate nothing, plant no
+callbacks, and add zero device work (``tests/test_obs.py`` asserts this
+with the retrace registry and jaxprs). A profiler session alone changes
+none of that: it enters no ``CommLog``, plants no ``StepTicker`` and adds
+no device work, so a traced run runs the program an untraced run times.
 
 Entering a :class:`Tracer` also enters a private ``telemetry.CommLog``:
 tracing alone is enough to turn on the record/ticker seams, and every
 ``ApssStats`` emitted during a span is pinned to it (the join key
-``drift.py`` uses for predicted-vs-measured residuals).
+``drift.py`` uses for predicted-vs-measured residuals). Each thread keeps
+its own stack of open spans, so spans opened by server worker threads nest
+under the tracer's root and never under another thread's span.
 """
 
 from __future__ import annotations
@@ -29,6 +41,8 @@ from __future__ import annotations
 import threading
 import time
 from typing import Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import recorder as _recorder
 from repro.planner import telemetry
@@ -106,7 +120,7 @@ class Tracer:
     def __init__(self, *, clock=time.perf_counter):
         self.clock = clock
         self.root = Span("trace", {}, clock())
-        self._open: list[Span] = [self.root]
+        self._local = threading.local()
         self._lock = threading.Lock()
         self._log: Optional[telemetry.CommLog] = None
         self.finalized = False
@@ -137,6 +151,14 @@ class Tracer:
         return self._log
 
     # -- span lifecycle ------------------------------------------------------
+
+    @property
+    def _open(self) -> list[Span]:
+        """This thread's open spans, outermost first (the root is shared)."""
+        stack = getattr(self._local, "open", None)
+        if stack is None:
+            stack = self._local.open = [self.root]
+        return stack
 
     def start(self, name: str, attrs: dict) -> Span:
         with self._lock:
@@ -255,32 +277,55 @@ class _NullSpanCtx:
 NULL_SPAN = _NullSpanCtx()
 
 
+_LOCAL = threading.local()  # per thread: open profiler annotations
+
+
+def _annotations() -> list:
+    stack = getattr(_LOCAL, "annotations", None)
+    if stack is None:
+        stack = _LOCAL.annotations = []
+    return stack
+
+
 class _SpanCtx:
-    __slots__ = ("_name", "_attrs", "_span")
+    __slots__ = ("_name", "_attrs", "_span", "_tracer", "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self._name = name
         self._attrs = attrs
         self._span: Optional[Span] = None
+        self._tracer: Optional[Tracer] = None
+        self._annotation: Optional[TraceAnnotation] = None
 
     def __enter__(self) -> Optional[Span]:
-        t = active()
-        if t is None:
-            return None
-        self._span = t.start(self._name, self._attrs)
+        if TraceAnnotation.is_enabled():
+            self._annotation = TraceAnnotation(self._name, **self._attrs)
+            self._annotation.__enter__()
+            _annotations().append(self._annotation)
+        t = self._tracer = active()
+        if t is not None:
+            self._span = t.start(self._name, self._attrs)
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        t = active()
+        t = self._tracer
         if t is not None and self._span is not None:
             err = None if exc is None else repr(exc)
             t.end(self._span, error=err)
+        a = self._annotation
+        if a is not None:
+            stack = _annotations()
+            if a in stack:
+                stack.remove(a)
+            a.__exit__(exc_type, exc, tb)
         return False
 
 
 def span(name: str, **attrs):
-    """Open a child span of the current span (no-op when tracing is off)."""
-    if not _STACK:
+    """Open a child span of the current span in every active sink: the
+    active :class:`Tracer` and, while a JAX profiler session is on, the
+    profiler (as a ``TraceAnnotation``). No-op when neither is on."""
+    if not _STACK and not TraceAnnotation.is_enabled():
         return NULL_SPAN
     return _SpanCtx(name, attrs)
 
@@ -296,7 +341,12 @@ def event(name: str, **attrs) -> None:
 
 
 def annotate(**attrs) -> None:
-    """Merge attributes into the current span (no-op when tracing is off)."""
+    """Merge attributes into the current span: the active :class:`Tracer`'s
+    and, as profiler metadata, this thread's innermost open profiler span
+    (no-op when neither is open)."""
     t = active()
     if t is not None:
         t.current().attrs.update(attrs)
+    stack = getattr(_LOCAL, "annotations", None)
+    if stack:
+        stack[-1].set_metadata(**attrs)

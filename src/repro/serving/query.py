@@ -86,14 +86,11 @@ from repro.obs import metrics, trace
 from repro.planner import telemetry
 from repro.serving.index import APSSIndex
 
-# Trace-time retrace counters, owned by the public registry
+# Trace-time retrace counters live in the public registry
 # (repro.obs.compile.MONITOR). The serving contract is "build once, query
 # many": after the first call of a given shape these must not move —
 # enforced by assert_no_retrace("serving.query") contracts in
-# tests/test_serving.py. This name is a back-compat alias to the SAME
-# Counter object, so legacy dict-snapshot readers keep working.
-TRACE_COUNTS = obs_compile.MONITOR.counts
-
+# tests/test_serving.py.
 obs_compile.register_entry_points(
     "serving.query",
     "query_mask", "dense_inner", "sparse_inner", "sharded_query",
@@ -205,14 +202,22 @@ def _query_topk_impl(
     rem = (-B) % block_q
     Qp = jnp.pad(Q, ((0, rem), (0, 0))) if rem else Q
     grid_q = Qp.shape[0] // block_q
-    mask, ub = _query_mask(
-        Qp, index.stats, threshold=float(threshold), block_q=block_q,
-        use_minsize=use_minsize, normalized=index.normalized,
-    )
-    mk = np.asarray(mask)
-    ubh = np.asarray(ub)
-    wl = compact_rect_worklist(mk, ubh)
-    live = 0 if wl is None else int(wl.shape[1])
+    with trace.span("query/mask"):
+        mask, ub = _query_mask(
+            Qp, index.stats, threshold=float(threshold), block_q=block_q,
+            use_minsize=use_minsize, normalized=index.normalized,
+        )
+        mk = np.asarray(mask)
+        ubh = np.asarray(ub)
+    with trace.span("query/worklist", batch=B):
+        wl = compact_rect_worklist(mk, ubh)
+        live = 0 if wl is None else int(wl.shape[1])
+        if wl is not None:
+            ij_np, tv_np = pad_worklist(wl)
+        trace.annotate(
+            live=live, total=int(mk.size),
+            entries=0 if wl is None else int(tv_np.shape[0]),
+        )
     if telemetry.enabled() or metrics.enabled():
         depth = (
             int(index.bdims.shape[1]) if index.is_sparse
@@ -232,36 +237,74 @@ def _query_topk_impl(
             metrics.observe(
                 "serving.live_tile_fraction", live / max(1, mk.size)
             )
-        trace.annotate(batch=B, live_tiles=live, total_tiles=int(mk.size))
     if wl is None:
         return empty_matches(B, k)
-    ij_np, tv_np = pad_worklist(wl)
-    ij, tvalid = jnp.asarray(ij_np), jnp.asarray(tv_np)
-    ubw = None
-    if early_exit:
-        # Per-worklist-entry upper bounds, in worklist (descending) order;
-        # bucket-padding entries get NEG_LARGE so they are always skipped
-        # and never gate the global stop.
-        u = np.full((tv_np.shape[0],), NEG_LARGE, np.float32)
-        u[: wl.shape[1]] = ubh[wl[0], wl[1]].astype(np.float32)
-        ubw = jnp.asarray(u)
-
-    scored = None
-    if index.is_sparse:
+    with trace.span("query/dispatch", early_exit=early_exit):
+        ij, tvalid = jnp.asarray(ij_np), jnp.asarray(tv_np)
+        ubw = None
         if early_exit:
+            # Per-worklist-entry upper bounds, in worklist (descending) order;
+            # bucket-padding entries get NEG_LARGE so they are always skipped
+            # and never gate the global stop.
+            u = np.full((tv_np.shape[0],), NEG_LARGE, np.float32)
+            u[: wl.shape[1]] = ubh[wl[0], wl[1]].astype(np.float32)
+            ubw = jnp.asarray(u)
+
+        scored = None
+        if index.is_sparse:
+            if early_exit:
+                inner_kwargs = dict(
+                    threshold=float(threshold), k=k, block_q=block_q,
+                    block_c=index.block_rows, nc_valid=index.n, grid_q=grid_q,
+                )
+                nqv = jnp.asarray(B, jnp.int32)
+                obs_compile.offer_capture(
+                    "serving.sparse_ee_inner", _rect_sparse_ee_inner,
+                    Qp, index.bdims, index.bx, ij, tvalid, ubw, nqv,
+                    **inner_kwargs,
+                )
+                values, indices, counts, scored = _rect_sparse_ee_inner(
+                    Qp, index.bdims, index.bx, ij, tvalid, ubw, nqv,
+                    **inner_kwargs,
+                )
+            else:
+                inner_kwargs = dict(
+                    threshold=float(threshold), k=k, block_q=block_q,
+                    block_c=index.block_rows, nc_valid=index.n, grid_q=grid_q,
+                    use_kernel=use_kernel, interpret=interpret,
+                )
+                obs_compile.offer_capture(
+                    "serving.sparse_inner", _rect_sparse_inner,
+                    Qp, index.bdims, index.bx, ij, tvalid, **inner_kwargs,
+                )
+                values, indices, counts = _rect_sparse_inner(
+                    Qp, index.bdims, index.bx, ij, tvalid, **inner_kwargs,
+                )
+        elif early_exit and use_kernel:
+            inner_kwargs = dict(
+                threshold=float(threshold), k=k, block_q=block_q,
+                block_c=index.block_rows, nc_valid=index.n, grid_q=grid_q,
+                nq_valid=B, interpret=interpret,
+            )
+            obs_compile.offer_capture(
+                "serving.dense_ee_kernel", _rect_dense_ee_kernel,
+                Qp, index.corpus, ij, tvalid, ubw, **inner_kwargs,
+            )
+            values, indices, counts, scored = _rect_dense_ee_kernel(
+                Qp, index.corpus, ij, tvalid, ubw, **inner_kwargs,
+            )
+        elif early_exit:
             inner_kwargs = dict(
                 threshold=float(threshold), k=k, block_q=block_q,
                 block_c=index.block_rows, nc_valid=index.n, grid_q=grid_q,
             )
             nqv = jnp.asarray(B, jnp.int32)
             obs_compile.offer_capture(
-                "serving.sparse_ee_inner", _rect_sparse_ee_inner,
-                Qp, index.bdims, index.bx, ij, tvalid, ubw, nqv,
-                **inner_kwargs,
+                "serving.dense_ee_inner", _rect_dense_ee_inner,
+                Qp, index.corpus, ij, tvalid, ubw, nqv, **inner_kwargs,
             )
-            values, indices, counts, scored = _rect_sparse_ee_inner(
-                Qp, index.bdims, index.bx, ij, tvalid, ubw, nqv,
-                **inner_kwargs,
+            values, indices, counts, scored = _rect_dense_ee_inner(
+                Qp, index.corpus, ij, tvalid, ubw, nqv, **inner_kwargs,
             )
         else:
             inner_kwargs = dict(
@@ -270,66 +313,27 @@ def _query_topk_impl(
                 use_kernel=use_kernel, interpret=interpret,
             )
             obs_compile.offer_capture(
-                "serving.sparse_inner", _rect_sparse_inner,
-                Qp, index.bdims, index.bx, ij, tvalid, **inner_kwargs,
+                "serving.dense_inner", _rect_dense_inner,
+                Qp, index.corpus, ij, tvalid, **inner_kwargs,
             )
-            values, indices, counts = _rect_sparse_inner(
-                Qp, index.bdims, index.bx, ij, tvalid, **inner_kwargs,
+            values, indices, counts = _rect_dense_inner(
+                Qp, index.corpus, ij, tvalid, **inner_kwargs,
             )
-    elif early_exit and use_kernel:
-        inner_kwargs = dict(
-            threshold=float(threshold), k=k, block_q=block_q,
-            block_c=index.block_rows, nc_valid=index.n, grid_q=grid_q,
-            nq_valid=B, interpret=interpret,
-        )
-        obs_compile.offer_capture(
-            "serving.dense_ee_kernel", _rect_dense_ee_kernel,
-            Qp, index.corpus, ij, tvalid, ubw, **inner_kwargs,
-        )
-        values, indices, counts, scored = _rect_dense_ee_kernel(
-            Qp, index.corpus, ij, tvalid, ubw, **inner_kwargs,
-        )
-    elif early_exit:
-        inner_kwargs = dict(
-            threshold=float(threshold), k=k, block_q=block_q,
-            block_c=index.block_rows, nc_valid=index.n, grid_q=grid_q,
-        )
-        nqv = jnp.asarray(B, jnp.int32)
-        obs_compile.offer_capture(
-            "serving.dense_ee_inner", _rect_dense_ee_inner,
-            Qp, index.corpus, ij, tvalid, ubw, nqv, **inner_kwargs,
-        )
-        values, indices, counts, scored = _rect_dense_ee_inner(
-            Qp, index.corpus, ij, tvalid, ubw, nqv, **inner_kwargs,
-        )
-    else:
-        inner_kwargs = dict(
-            threshold=float(threshold), k=k, block_q=block_q,
-            block_c=index.block_rows, nc_valid=index.n, grid_q=grid_q,
-            use_kernel=use_kernel, interpret=interpret,
-        )
-        obs_compile.offer_capture(
-            "serving.dense_inner", _rect_dense_inner,
-            Qp, index.corpus, ij, tvalid, **inner_kwargs,
-        )
-        values, indices, counts = _rect_dense_inner(
-            Qp, index.corpus, ij, tvalid, **inner_kwargs,
-        )
 
-    if scored is not None and (telemetry.enabled() or metrics.enabled()):
-        skipped = live - int(scored)
-        if metrics.enabled():
-            metrics.incr("serving.early_exit_skipped_tiles", skipped)
-        if telemetry.enabled():
-            telemetry.record(telemetry.ApssStats(
-                variant="serving/early-exit",
-                n=index.n, m=index.m, block_rows=index.block_rows,
-                sparse=index.is_sparse,
-                live_tiles=live, total_tiles=int(mk.size),
-                extra={"batch": B, "skipped_tiles": skipped},
-            ))
-        trace.annotate(early_exit_skipped_tiles=skipped)
-    return Matches(values=values[:B], indices=indices[:B], counts=counts[:B])
+        if scored is not None and (telemetry.enabled() or metrics.enabled()):
+            skipped = live - int(scored)
+            if metrics.enabled():
+                metrics.incr("serving.early_exit_skipped_tiles", skipped)
+            if telemetry.enabled():
+                telemetry.record(telemetry.ApssStats(
+                    variant="serving/early-exit",
+                    n=index.n, m=index.m, block_rows=index.block_rows,
+                    sparse=index.is_sparse,
+                    live_tiles=live, total_tiles=int(mk.size),
+                    extra={"batch": B, "skipped_tiles": skipped},
+                ))
+            trace.annotate(early_exit_skipped_tiles=skipped)
+        return Matches(values=values[:B], indices=indices[:B], counts=counts[:B])
 
 
 @functools.partial(
@@ -344,11 +348,12 @@ def _query_mask(Qp, corpus_stats, *, threshold, block_q, use_minsize, normalized
     arrive as index leaves — never recomputed here.
     """
     obs_compile.mark("query_mask")
-    qstats = dense_block_stats(Qp.astype(jnp.float32), block_q)
-    return live_tile_mask(
-        qstats, corpus_stats, threshold,
-        use_minsize=use_minsize, normalized=normalized, return_ub=True,
-    )
+    with jax.named_scope("mask"):
+        qstats = dense_block_stats(Qp.astype(jnp.float32), block_q)
+        return live_tile_mask(
+            qstats, corpus_stats, threshold,
+            use_minsize=use_minsize, normalized=normalized, return_ub=True,
+        )
 
 
 @functools.partial(
@@ -669,17 +674,25 @@ def _sharded_query_pruned(
     rem = (-B) % block_q
     Qp = jnp.pad(Q, ((0, rem), (0, 0))) if rem else Q
     grid_q = Qp.shape[0] // block_q
-    mask, ub = _query_mask(
-        Qp, index.stats, threshold=float(threshold), block_q=block_q,
-        use_minsize=use_minsize, normalized=index.normalized,
-    )
-    mk = np.asarray(mask)
-    ubh = np.asarray(ub)
-    wls = []
-    for s in range(p):
-        lo, hi = index.shard_block_range(s)
-        wls.append(compact_rect_worklist(mk[:, lo:hi], ubh[:, lo:hi]))
-    live = sum(0 if w is None else int(w.shape[1]) for w in wls)
+    with trace.span("query/mask"):
+        mask, ub = _query_mask(
+            Qp, index.stats, threshold=float(threshold), block_q=block_q,
+            use_minsize=use_minsize, normalized=index.normalized,
+        )
+        mk = np.asarray(mask)
+        ubh = np.asarray(ub)
+    with trace.span("query/worklist", batch=B, shards=p):
+        wls = []
+        for s in range(p):
+            lo, hi = index.shard_block_range(s)
+            wls.append(compact_rect_worklist(mk[:, lo:hi], ubh[:, lo:hi]))
+        live = sum(0 if w is None else int(w.shape[1]) for w in wls)
+        # every shard pads to the longest shard's power-of-two bucket
+        Tmax = max((int(w.shape[1]) for w in wls if w is not None), default=0)
+        Tb = 1 << max(0, (Tmax - 1).bit_length())
+        trace.annotate(
+            live=live, total=int(mk.size), entries=p * Tb if live else 0
+        )
     if telemetry.enabled() or metrics.enabled():
         depth = (
             int(index.corpus[0].shape[1]) if index.is_sparse
@@ -701,13 +714,8 @@ def _sharded_query_pruned(
             metrics.observe(
                 "serving.live_tile_fraction", live / max(1, mk.size)
             )
-        trace.annotate(
-            batch=B, live_tiles=live, total_tiles=int(mk.size), shards=p
-        )
     if live == 0:
         return empty_matches(B, k)
-    Tmax = max(int(w.shape[1]) for w in wls if w is not None)
-    Tb = 1 << max(0, (Tmax - 1).bit_length())
     ij_all = np.zeros((p, 2, Tb), np.int32)
     tv_all = np.zeros((p, Tb), bool)
     for s, w in enumerate(wls):
@@ -715,13 +723,14 @@ def _sharded_query_pruned(
             continue
         ij_all[s, :, : w.shape[1]] = w
         tv_all[s, : w.shape[1]] = True
-    out = _sharded_query(
-        Qp, index.corpus, jnp.asarray(ij_all), jnp.asarray(tv_all),
-        mesh=index.mesh, axis_name=index.axis_name, kind=index.kind,
-        threshold=float(threshold), k=k, block_q=block_q, grid_q=grid_q,
-        block_rows=index.block_rows, nb_loc=index.nb_local,
-        n_valid=index.n, use_kernel=use_kernel, interpret=interpret,
-    )
+    with trace.span("query/dispatch", early_exit=False):
+        out = _sharded_query(
+            Qp, index.corpus, jnp.asarray(ij_all), jnp.asarray(tv_all),
+            mesh=index.mesh, axis_name=index.axis_name, kind=index.kind,
+            threshold=float(threshold), k=k, block_q=block_q, grid_q=grid_q,
+            block_rows=index.block_rows, nb_loc=index.nb_local,
+            n_valid=index.n, use_kernel=use_kernel, interpret=interpret,
+        )
     parts = [jax.tree.map(lambda x: x[i], out) for i in range(p)]
     mm = functools.reduce(merge_matches, parts)
     return Matches(mm.values[:B], mm.indices[:B], mm.counts[:B])

@@ -321,10 +321,7 @@ class RetrievalServer:
             for _ in range(min(self.max_batch, len(self._pending)))
         ]
         trace.event("batch", size=len(batch), queued=len(self._pending))
-        if metrics.enabled():
-            metrics.observe(
-                "serving.batch_occupancy", len(batch) / self.max_batch
-            )
+        self._observe_batch(batch, now)
         Q = np.zeros((self.max_batch, self.index.m), np.float32)
         for slot, (_, q, _, _, _) in enumerate(batch):
             Q[slot] = q
@@ -375,6 +372,17 @@ class RetrievalServer:
             latch(rid, born, res)
             self._cache_put(key, res)
         return len(batch) + shed_count
+
+    def _observe_batch(self, batch, start: float) -> None:
+        """Record a batch that starts scoring at ``start``: its occupancy
+        and each request's queue wait (admission to ``start``), the
+        longest as ``max_wait_ms`` on the current span."""
+        waits = [start - born for *_, born in batch]
+        trace.annotate(max_wait_ms=1e3 * max(waits))
+        if metrics.enabled():
+            metrics.observe("serving.batch_occupancy", len(batch) / self.max_batch)
+            for w in waits:
+                metrics.observe("serving.queue_wait_s", w)
 
     def result(self, rid: int) -> RetrievalResult:
         """Pop a finished request's result (steps until it is ready)."""
@@ -469,9 +477,10 @@ class ContinuousRetrievalServer(RetrievalServer):
 
     Threading contract: one lock guards the queue/results/cache/counters;
     scoring runs OUTSIDE the lock (concurrent jit dispatch is safe — the
-    compiled executable is shared). Workers emit ``trace.event``\\ s only
-    (``admit``/``slot``/``exit``), never spans: the tracer keeps one open-
-    span stack, which cross-thread spans would interleave. Deadline sheds
+    compiled executable is shared). Each worker scores a batch inside its
+    own ``serving/step`` and ``serving/score`` spans (the tracer keeps one
+    open-span stack per thread) and emits ``slot``/``exit`` events; admission
+    emits ``admit`` on the submitting thread. Deadline sheds
     happen at batch ASSEMBLY, same as the step server — a straggler never
     wastes scoring work on answers nobody is waiting for. ``step()`` is a
     no-op here (workers drain continuously); use ``result()``/``serve()``,
@@ -622,30 +631,35 @@ class ContinuousRetrievalServer(RetrievalServer):
                 if taken is None:
                     return
                 batch, seq = taken
+                start = time.monotonic()
                 trace.event(
                     "slot", seq=seq, size=len(batch),
                     queued=len(self._pending),
                 )
-                if metrics.enabled():
-                    metrics.observe(
-                        "serving.batch_occupancy",
-                        len(batch) / self.max_batch,
-                    )
-            # Scoring runs UNLOCKED: a straggling batch (chaos delay, slow
-            # tier) must not stop sibling workers from draining arrivals.
-            if self.fault_plan is not None:
-                self.fault_plan.delay("serving", step=seq)
-            Q = np.zeros((self.max_batch, self.index.m), np.float32)
-            for slot, (_, q, _, _, _) in enumerate(batch):
-                Q[slot] = q
-            Qj = jnp.asarray(Q)
-            if self.normalize:
-                Qj = normalize_rows(Qj)
+            with trace.span("serving/step", step=seq):
+                with self._lock:
+                    self._observe_batch(batch, start)
+                self._run_batch(batch, seq)
+
+    def _run_batch(self, batch, seq: int) -> None:
+        """Score a claimed batch and latch its results. Scoring runs
+        UNLOCKED: a straggling batch (chaos delay, slow tier) must not stop
+        sibling workers from draining arrivals."""
+        if self.fault_plan is not None:
+            self.fault_plan.delay("serving", step=seq)
+        Q = np.zeros((self.max_batch, self.index.m), np.float32)
+        for slot, (_, q, _, _, _) in enumerate(batch):
+            Q[slot] = q
+        Qj = jnp.asarray(Q)
+        if self.normalize:
+            Qj = normalize_rows(Qj)
+        with trace.span("serving/score", batch=len(batch)):
             m, tier = self._score_batch(Qj)
-            with self._lock:
-                self._steps += 1
-                self._latch_batch(batch, m, tier, seq)
-                self._done.notify_all()
+            trace.annotate(tier=tier)
+        with self._lock:
+            self._steps += 1
+            self._latch_batch(batch, m, tier, seq)
+            self._done.notify_all()
 
     def _latch_batch(self, batch, m, tier, seq) -> None:
         now = time.monotonic()

@@ -33,13 +33,6 @@ def test_mark_counts_and_snapshot_is_a_copy():
     assert mon.counts["a"] == 2  # snapshot is detached
 
 
-def test_serving_trace_counts_aliases_the_public_registry():
-    """Back-compat: the legacy TRACE_COUNTS name IS the monitor's Counter."""
-    from repro.serving import query
-
-    assert query.TRACE_COUNTS is obs_compile.MONITOR.counts
-
-
 def test_registered_groups_resolve_to_entry_points():
     mon = CompileMonitor()
     mon.register_entry_points("grp", "x", "y")
@@ -80,8 +73,8 @@ def test_contract_ignores_unwatched_names():
 
 
 def test_contract_exit_catches_direct_counter_bumps():
-    """Legacy `TRACE_COUNTS[x] += 1` bypasses mark(); the exit check
-    still catches it via the shared Counter object."""
+    """A direct `counts[x] += 1` bypasses mark(); the exit check still
+    catches it through the shared Counter object."""
     mon = CompileMonitor()
     with pytest.raises(RetraceError):
         with mon.assert_no_retrace("legacy"):
