@@ -14,8 +14,10 @@ indexing):
    — inverted-index candidacy ∧ maxweight ∧ exact minsize — computed from
    CSR only.
 3. Per live tile ``(I, J)``: block ``J``'s CSR rows are gathered onto
-   ``bdims[I]`` (binary search + scatter, XLA) giving ``yg (bn, S)``; tile
-   scores are then the **dense** matmul ``bx[I] · ygᵀ`` — exact, because
+   ``bdims[I]`` (XLA) giving ``yg (bn, S)``: each CSR index is looked up in
+   block ``I``'s dimension→slot table (one gather; the table is built once
+   a join from ``bdims``, see :func:`_slot_table`), then scatter-added.
+   Tile scores are the **dense** matmul ``bx[I] · ygᵀ`` — exact, because
    every nonzero of block ``I`` lies inside its own support and dimensions
    outside it contribute zero. MXU work per tile drops from ``O(bm·bn·m)``
    to ``O(bm·bn·S)``.
@@ -96,19 +98,33 @@ def block_support_gather(
     return bdims, bx
 
 
-def _gather_block(bd: jax.Array, idx: jax.Array, val: jax.Array) -> jax.Array:
-    """Gather one CSR block onto a support list ``bd (S,)`` → ``(bn, S)``.
+def _slot_table(bdims: jax.Array, m: int) -> jax.Array:
+    """Per-row-block dimension→slot table ``(nb, m + 1) int32``.
 
-    Binary search into the sorted support; misses (dims outside ``bd``,
-    padding slots) contribute 0; duplicate coordinates accumulate.
+    ``slot[b, d]`` is the position of dimension ``d`` in ``bdims[b]``, or
+    ``S`` (a miss) where ``d`` is outside block ``b``'s support. ``bdims``
+    pads with the sentinel ``m``, hence the extra column: no CSR index
+    equals ``m``, so which padded slot lands there does not matter.
     """
-    S = bd.shape[0]
-    pos = jnp.searchsorted(bd, idx)  # (bn, cap), in [0, S]
-    in_range = jnp.minimum(pos, S - 1)
-    hit = jnp.take(bd, in_range) == idx
-    contrib = jnp.where(hit, val.astype(jnp.float32), 0.0)
+    nb, S = bdims.shape
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (nb, S))
+    b = jnp.arange(nb, dtype=jnp.int32)[:, None]
+    return jnp.full((nb, m + 1), S, jnp.int32).at[b, bdims].set(pos)
+
+
+def _gather_block(
+    slot: jax.Array, idx: jax.Array, val: jax.Array, S: int
+) -> jax.Array:
+    """Gather one CSR block onto a row block's support → ``(bn, S)``.
+
+    ``slot`` is the row block's slot-table row ``(m + 1,)``. Misses (dims
+    outside the support, padding slots) read ``S`` and are dropped;
+    duplicate coordinates accumulate.
+    """
+    pos = jnp.take(slot, idx)  # (bn, cap), S on a miss
     r = jnp.arange(idx.shape[0], dtype=jnp.int32)[:, None]
-    return jnp.zeros((idx.shape[0], S), jnp.float32).at[r, in_range].add(contrib)
+    out = jnp.zeros((idx.shape[0], S), jnp.float32)
+    return out.at[r, pos].add(val.astype(jnp.float32), mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -346,23 +362,26 @@ def rect_sparse_tile_candidates_pallas(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "threshold", "k", "block_m", "n_valid", "grid_m", "use_kernel",
+        "threshold", "k", "block_m", "n_valid", "grid_m", "m", "use_kernel",
         "interpret",
     ),
 )
 def _sparse_compacted_inner(
     bx, bdims, idxb, valb, ij, *,
-    threshold, k, block_m, n_valid, grid_m, use_kernel, interpret,
+    threshold, k, block_m, n_valid, grid_m, m, use_kernel, interpret,
 ):
     T = ij.shape[1]
+    S = bdims.shape[1]
+    with jax.named_scope("support_gather"):
+        slot = _slot_table(bdims, m)
 
     def gather_t(t):
-        return _gather_block(bdims[ij[0, t]], idxb[ij[1, t]], valb[ij[1, t]])
+        return _gather_block(slot[ij[0, t]], idxb[ij[1, t]], valb[ij[1, t]], S)
 
     if use_kernel:
         # The kernel consumes per-tile gathered operands as a streamed
-        # input, so the (T, bm, S) buffer is materialized; moving the
-        # binary-search gather in-kernel would remove it (ROADMAP).
+        # input, so the (T, bm, S) buffer is materialized; gathering
+        # in-kernel would remove it (ROADMAP S4).
         with jax.named_scope("support_gather"):
             _, yg = lax.scan(lambda _, t: (_, gather_t(t)), 0, jnp.arange(T))
         fv, fi, fc, bv, bi, bc = sparse_tile_candidates_pallas(
@@ -451,7 +470,11 @@ def apss_sparse_compacted(
     with trace.span("apss/support_gather"):
         bdims, bx = block_support_gather(spp, block_m, pad_to=lane_pad)
         S = bdims.shape[1]
-        trace.annotate(blocks=grid_m, block_rows=block_m, support=S)
+        # lookup_bytes: device memory of the dimension→slot table
+        trace.annotate(
+            blocks=grid_m, block_rows=block_m, support=S,
+            support_lookup="table", lookup_bytes=grid_m * (spp.m + 1) * 4,
+        )
         if use_kernel:
             trace.annotate(support_chunk=_support_tile(S))
     with trace.span("apss/upload", bytes=bx.nbytes + bdims.nbytes):
@@ -462,6 +485,7 @@ def apss_sparse_compacted(
         values, indices, counts = _sparse_compacted_inner(
             bx_d, bdims_d, idxb, valb, ij,
             threshold=float(threshold), k=k, block_m=block_m, n_valid=n,
-            grid_m=grid_m, use_kernel=use_kernel, interpret=interpret,
+            grid_m=grid_m, m=spp.m, use_kernel=use_kernel,
+            interpret=interpret,
         )
     return Matches(values=values[:n], indices=indices[:n], counts=counts[:n])

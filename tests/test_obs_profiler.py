@@ -18,7 +18,11 @@ import pytest
 from jax.profiler import ProfileData, TraceAnnotation
 
 from repro.core.apss import apss_blocked
-from repro.data.sparse import perturbed_queries, sparse_clustered_corpus
+from repro.data.sparse import (
+    perturbed_queries,
+    sparse_clustered_corpus,
+    sparse_zipfian_corpus,
+)
 from repro.kernels.apss_block import ops, sparse
 from repro.obs import MetricsRegistry, Tracer, trace
 from repro.planner import telemetry
@@ -149,6 +153,19 @@ def test_profiler_session_records_program_spans_with_counts(
     assert stats["total"] == (-(-Q.shape[0] // 16)) * (-(-index.n // 64))
 
 
+def test_support_gather_span_names_its_lookup(tmp_path):
+    # 300 rows in two 256-row blocks: a 2 × (m + 1) dimension→slot table
+    m = 256
+    sp = sparse_zipfian_corpus(300, m, 8.0, seed=4)
+    events = _session_events(
+        tmp_path,
+        lambda: jax.block_until_ready(sparse.apss_sparse_compacted(sp, T, K)),
+    )
+    (ev,) = [e for e in events if e[0] == "apss/support_gather"]
+    assert ev[4]["support_lookup"] == "table"
+    assert ev[4]["lookup_bytes"] == 2 * (m + 1) * 4
+
+
 def test_profiler_alone_enters_no_commlog_and_changes_no_jaxpr(
     corpus, tmp_path, monkeypatch
 ):
@@ -209,8 +226,8 @@ def test_inners_name_their_gather_fold_and_mask_scopes(corpus):
         jnp.zeros((2, bm, S)), jnp.zeros((2, S), jnp.int32),
         jnp.zeros((2, bm, sp.cap), jnp.int32), jnp.zeros((2, bm, sp.cap)),
         jnp.zeros((2, T_), jnp.int32),
-        threshold=T, k=K, block_m=bm, n_valid=200, grid_m=2, use_kernel=False,
-        interpret=True,
+        threshold=T, k=K, block_m=bm, n_valid=200, grid_m=2, m=sp.m,
+        use_kernel=False, interpret=True,
     )
     assert "/support_gather/" in text and "/fold/" in text
     text = _hlo(

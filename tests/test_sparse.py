@@ -35,6 +35,7 @@ from repro.core.sparse import (
     to_dense,
 )
 from repro.data.sparse import sparse_clustered_corpus, sparse_zipfian_corpus
+from repro.kernels.apss_block import sparse as sparse_kernels
 from repro.kernels.apss_block.sparse import apss_sparse_compacted
 
 T, K = 0.3, 16
@@ -171,6 +172,95 @@ def test_sparse_compacted_ragged_shapes(n):
     ref = apss_reference(jnp.asarray(D), T, K)
     _check(apss_sparse_compacted(sp, T, K, block_m=16, lane_pad=8), ref)
     _check(sparse_similarity_topk(sp, sp, T, K, block_rows=16, exclude_self=True), ref)
+
+
+# -- support gather: dimension→slot table against the binary search ----------
+
+
+def _lookup_case(name):
+    """``bdims (nb, S)`` (sorted supports padded with the sentinel ``m``) and
+    one CSR block ``(idx, val)`` for an equivalence case."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    m, nb, bn, cap, S = 600, 3, 16, 12, 128
+    sizes = [100, 60, 128]
+    if name == "empty_support":
+        sizes[1] = 0
+    if name == "S_384":
+        S, sizes = 384, [300, 130, 384]
+    bdims = np.full((nb, S), m, np.int32)
+    for b, k in enumerate(sizes):
+        bdims[b, :k] = np.sort(rng.choice(m, k, replace=False))
+    # draw from every block's support plus some dims outside all of them
+    pool = np.unique(bdims[bdims < m])
+    outside = np.setdiff1d(np.arange(m), pool)
+    idx = rng.choice(pool, (bn, cap))
+    val = rng.random((bn, cap)).astype(np.float32) + 0.05
+    nnz = rng.integers(0, cap + 1, bn)
+    if name == "duplicates":
+        idx[:, 1] = idx[:, 0]
+        idx[:, 2] = idx[:, 0]
+    if name == "outside_support":
+        miss = rng.random((bn, cap)) < 0.4
+        idx = np.where(miss, rng.choice(outside, (bn, cap)), idx)
+    if name == "csr_padding":
+        nnz[::3] = 0  # empty rows: all padding
+    else:
+        nnz[:] = cap
+    valid = np.arange(cap)[None, :] < nnz[:, None]
+    idx, val = np.where(valid, idx, 0), np.where(valid, val, 0.0)
+    if name == "sentinel":
+        # every block pads with the sentinel m (the table's last column
+        # holds a padded slot), and m - 1, the last real dimension, is read
+        idx[:, -1] = m - 1
+        bdims[:, -1] = m
+    return m, bdims, idx.astype(np.int32), val.astype(np.float32)
+
+
+def _search_gather(bd, idx, val):
+    """NumPy oracle: the binary-search gather the slot table replaced.
+    Each index is searched in the sorted support ``bd (S,)``; a miss adds
+    0.0 to its clamped slot."""
+    S = bd.shape[0]
+    pos = np.minimum(np.searchsorted(bd, idx), S - 1)
+    contrib = np.where(bd[pos] == idx, val, 0.0).astype(np.float32)
+    rows = np.broadcast_to(np.arange(idx.shape[0])[:, None], idx.shape)
+    out = np.zeros((idx.shape[0], S), np.float32)
+    np.add.at(out, (rows, pos), contrib)
+    return out
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["duplicates", "outside_support", "csr_padding", "sentinel",
+     "empty_support", "S_384"],
+)
+def test_slot_table_gather_equals_binary_search_gather(case):
+    m, bdims, idx, val = _lookup_case(case)
+    nb, S = bdims.shape
+    slot = np.asarray(sparse_kernels._slot_table(jnp.asarray(bdims), m))
+    assert slot.shape == (nb, m + 1)
+    for b in range(nb):
+        real = bdims[b][bdims[b] < m]
+        np.testing.assert_array_equal(slot[b, real], np.arange(len(real)))
+        assert (np.delete(slot[b, :m], real) == S).all()
+        table = sparse_kernels._gather_block(
+            jnp.asarray(slot[b]), jnp.asarray(idx), jnp.asarray(val), S
+        )
+        assert table.shape == (idx.shape[0], S)
+        search = _search_gather(bdims[b], idx, val)
+        assert np.array_equal(np.asarray(table), search)
+        if len(real):  # the case gathers something onto every non-empty block
+            assert search.any()
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_sparse_compacted_slot_table_exact(use_kernel):
+    sp = random_csr(5, 70, 300, 10, dup_prob=1.0)
+    ref = apss_reference(to_dense(sp), 0.2, K)
+    got = apss_sparse_compacted(
+        sp, 0.2, K, block_m=16, lane_pad=128, use_kernel=use_kernel
+    )
+    _check(got, ref)
 
 
 def test_sparse_compacted_all_pruned_returns_empty():
