@@ -33,9 +33,12 @@ Paper → TPU mapping (see DESIGN.md §2 for the full table):
 Row blocks (``block_rows``) are the paper's §5.1.9 block-processing knob: all
 variants process a block of query rows per collective step.
 
-Every variant is exact (validated against ``apss_reference``); the compressed
-variants carry explicit overflow counters so capacity truncation is visible,
-never silent.
+Every variant is exact (validated against ``apss_reference``). The 1-D
+compressed and recursive accumulations score a query block whose Lemma-1
+candidates overflow the capacity on any shard by the all-reduce of its whole
+partial tile instead, and count the blocks each route took; the 2-D
+composition carries an overflow counter, so its capacity truncation is
+visible, never silent.
 """
 
 from __future__ import annotations
@@ -45,8 +48,9 @@ from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compat import pvary, shard_map
 from repro.core.apss import similarity_topk
@@ -66,13 +70,24 @@ from repro.core.sparse import (
     shard_dims,
     sparse_similarity_topk,
 )
+from repro.obs import trace
 from repro.planner import telemetry
 
 
 class ApssStats(NamedTuple):
-    """Exactness accounting for capacity-bounded candidate sets."""
+    """Exactness accounting for capacity-bounded candidate sets.
 
-    overflow_rows: jax.Array  # i32 scalar: rows whose candidate set was truncated
+    ``overflow_rows`` counts rows whose candidates overflowed the capacity:
+    the 1-D vertical accumulations scored their blocks exactly instead, the
+    2-D composition truncated them. ``blocks_pruned`` / ``blocks_exact``
+    count the query blocks a 1-D vertical accumulation scored through its
+    Lemma-1 candidates and through the all-reduce of the whole partial
+    tile; ``None`` where a path does not count routes (the 2-D sweep).
+    """
+
+    overflow_rows: jax.Array  # i32 scalar
+    blocks_pruned: jax.Array | None = None  # i32 scalar
+    blocks_exact: jax.Array | None = None   # i32 scalar
 
 
 def _matches_specs(axis) -> Matches:
@@ -622,11 +637,14 @@ def apss_vertical(
     are then accumulated (paper's score-accumulation phase).
 
     ``D`` may be a :class:`~repro.core.sparse.SparseCorpus`: dimension
-    sharding then splits the **inverted index** — each device owns a
-    contiguous slice of posting lists (host-side ``shard_dims``, so the
-    sparse entry is not traceable) and computes partials with the sparse
-    gather-dot primitive. All four accumulations apply unchanged: they
-    only ever see the ``(block, n)`` partial-score tiles.
+    sharding then splits the **inverted index** — each device owns the
+    posting lists dealt to it by frequency (dealt and packed on the mesh,
+    sized on the host, so the sparse entry is not traceable), rows are
+    padded with empty rows to a
+    multiple of ``block_rows`` (never matched, sliced off the answer), and
+    partials come from the sparse gather-dot primitive. All four
+    accumulations apply unchanged: they only ever see the ``(block, n)``
+    partial-score tiles.
     """
     if isinstance(D, SparseCorpus):
         return _apss_vertical_sparse(
@@ -663,39 +681,57 @@ def apss_vertical(
 def _vertical_dispatch(
     args, make_partials, n, threshold, k, mesh, axis_name, *,
     accumulation, block_rows, candidate_capacity, return_stats, in_specs,
-    strict_vma,
+    strict_vma, n_valid=None,
 ):
     """Shared accumulation dispatch for dense and sparse vertical inputs.
 
     ``make_partials(*local_args) -> (blk -> (block_rows, n) partials)``
     builds the per-device partial-score closure; everything downstream
     (Lemma-1 compaction, flat/recursive accumulation) is representation-
-    agnostic.
+    agnostic. Columns from ``n_valid`` on are padding rows: their partials
+    read ``-inf``, so they never match or count.
     """
     p = mesh.shape[axis_name]
     C = candidate_capacity or default_candidate_capacity(k)
     if n % block_rows != 0:
         raise ValueError(f"n={n} must be a multiple of block_rows={block_rows}")
     args = args if isinstance(args, tuple) else (args,)
+    nb = n // block_rows
+    n_valid = n if n_valid is None else n_valid
 
+    def local_partials(*local):
+        partials = make_partials(*local)
+        real = jnp.arange(n) < n_valid
+
+        def scoped(blk):
+            with jax.named_scope("vertical/partials"):
+                A = partials(blk)
+                return A if n_valid == n else jnp.where(real, A, NEG_INF)
+
+        return scoped
+
+    replicated = Matches(values=P(), indices=P(), counts=P())
+    all_exact = ApssStats(
+        overflow_rows=jnp.int32(0), blocks_pruned=jnp.int32(0),
+        blocks_exact=jnp.int32(nb),
+    )
     if accumulation == "allreduce":
         def fn(*local):
             return _vertical_allreduce(
-                make_partials(*local), n, threshold=threshold, k=k,
+                local_partials(*local), n, threshold=threshold, k=k,
                 axis_name=axis_name, block_rows=block_rows,
             )
         out = shard_map(
-            fn, mesh=mesh, in_specs=in_specs,
-            out_specs=Matches(values=P(), indices=P(), counts=P()),
+            fn, mesh=mesh, in_specs=in_specs, out_specs=replicated,
             check_vma=strict_vma,
         )(*args)
-        stats = ApssStats(overflow_rows=jnp.int32(0))
+        stats = all_exact
     elif accumulation == "scatter":
         if block_rows % p != 0:
             raise ValueError("scatter accumulation needs block_rows % p == 0")
         def fn(*local):
             return _vertical_scatter(
-                make_partials(*local), n, threshold=threshold, k=k,
+                local_partials(*local), n, threshold=threshold, k=k,
                 axis_name=axis_name, p=p, block_rows=block_rows,
             )
         stacked = shard_map(
@@ -708,11 +744,17 @@ def _vertical_dispatch(
             check_vma=strict_vma,
         )(*args)
         out = jax.tree.map(lambda x: x.reshape(n, *x.shape[2:]), stacked)
-        stats = ApssStats(overflow_rows=jnp.int32(0))
-    elif accumulation == "compressed":
+        stats = all_exact
+    elif accumulation in ("compressed", "recursive"):
+        if accumulation == "recursive" and p & (p - 1):
+            raise ValueError("recursive accumulation needs power-of-two shards")
+        accumulate = (
+            _vertical_compressed if accumulation == "compressed"
+            else _vertical_recursive
+        )
         def fn(*local):
-            return _vertical_compressed(
-                make_partials(*local), n, threshold=threshold, k=k,
+            return accumulate(
+                local_partials(*local), n, threshold=threshold, k=k,
                 axis_name=axis_name, p=p, block_rows=block_rows, capacity=C,
             )
         # NOTE: outputs are value-replicated (all devices compute the same
@@ -721,26 +763,7 @@ def _vertical_dispatch(
         # numerically by tests instead.
         out, stats = shard_map(
             fn, mesh=mesh, in_specs=in_specs,
-            out_specs=(
-                Matches(values=P(), indices=P(), counts=P()),
-                ApssStats(overflow_rows=P()),
-            ),
-            check_vma=False,
-        )(*args)
-    elif accumulation == "recursive":
-        if p & (p - 1):
-            raise ValueError("recursive accumulation needs power-of-two shards")
-        def fn(*local):
-            return _vertical_recursive(
-                make_partials(*local), n, threshold=threshold, k=k,
-                axis_name=axis_name, p=p, block_rows=block_rows, capacity=C,
-            )
-        out, stats = shard_map(
-            fn, mesh=mesh, in_specs=in_specs,
-            out_specs=(
-                Matches(values=P(), indices=P(), counts=P()),
-                ApssStats(overflow_rows=P()),
-            ),
+            out_specs=(replicated, ApssStats(P(), P(), P())),
             check_vma=False,
         )(*args)
     else:
@@ -751,44 +774,192 @@ def _vertical_dispatch(
     return out
 
 
-def _vertical_sparse_post_split(
-    idx_s, val_s, *, n, m_loc, threshold, k, mesh, axis_name,
-    accumulation, block_rows, candidate_capacity, return_stats,
-):
-    """Everything AFTER the host ``shard_dims`` split — pure array-in
-    computation, so it is jit-lowerable (the compile audit AOT-compiles
-    the sparse vertical family through this seam; the public entry stays
-    host-staged because the split itself shapes by data)."""
-    ncb = n // block_rows  # divisibility validated by _vertical_dispatch
-    cap_loc = idx_s.shape[-1]
+# All but this share of the rows fit a shard's row width; the entries past
+# it, of the few longest rows, ride a spill list instead of widening every
+# row (the partial tile's gathers scale with the width).
+SPILL_SHARE = 1e-3
+_GATHER_CHUNK = 32  # the partial tiles' gather_dot chunk; widths are multiples
+_SPILL_QUANTUM = 128
 
-    def make_partials(idxL, valL):
-        idxL, valL = idxL[0], valL[0]  # shard dim (1, n, cap_loc) → local
+
+@functools.partial(jax.jit, static_argnames=("m", "p"))
+def _deal_on_device(idx, nnz, *, m, p):
+    """Deal the dimensions to ``p`` shards as :func:`~repro.core.sparse.deal_dims`
+    does (round-robin by posting-list length, ties by id) and count each
+    row's entries on each shard. Returns the ``(n, cap)`` shard of every
+    slot (``p`` for padding slots), its shard-local dimension id, and the
+    ``(n, p)`` counts."""
+    n, cap = idx.shape
+    valid = jnp.arange(cap)[None, :] < nnz[:, None]
+    postings = jnp.zeros((m,), jnp.int32).at[jnp.where(valid, idx, m)].add(
+        1, mode="drop"
+    )
+    rank = jnp.zeros((m,), jnp.int32).at[jnp.argsort(-postings, stable=True)].set(
+        jnp.arange(m, dtype=jnp.int32)
+    )
+    shard = jnp.where(valid, rank[idx] % p, p)
+    counts = jnp.stack([(shard == d).sum(axis=1) for d in range(p)], axis=1)
+    return shard, rank[idx] // p, counts
+
+
+def _cut_width(counts: np.ndarray) -> tuple[int, int]:
+    """The shards' row width ``W`` and spill length ``E`` from the ``(n, p)``
+    counts. ``W`` fits all but the longest ``SPILL_SHARE`` of the rows,
+    rounded up to ``gather_dot``'s chunk, so that it does not follow the
+    longest row: that moves from corpus to corpus, and across a chunk
+    boundary adds a third to the gathers. ``E`` holds the most entries any
+    shard has past ``W``, rounded up to ``_SPILL_QUANTUM``."""
+    q = int(np.quantile(counts.max(axis=1), 1.0 - SPILL_SHARE, method="higher"))
+    width = max(1, -(-q // _GATHER_CHUNK) * _GATHER_CHUNK)
+    most = int(np.maximum(counts - width, 0).sum(axis=0).max())
+    return width, _SPILL_QUANTUM * max(1, -(-most // _SPILL_QUANTUM))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("width", "spill", "n_pad", "mesh", "axis_name")
+)
+def _pack_on_device(shard, local, val, *, width, spill, n_pad, mesh, axis_name):
+    """Each chip packs its own shard from the replicated slots. One stable
+    sort moves a row's slots of the chip's shard to its front in stored
+    order; the first ``width`` form the ``(n_pad, width)`` rows (rows past
+    ``n`` empty), and the rest go to the ``(spill,)`` list ``(row, idx,
+    val)`` in row-major order, padded with inert ``(0, 0, 0.0)``. Returns
+    the ``(p, n_pad, width)`` stacks and the three ``(p, spill)`` lists,
+    sharded over ``axis_name``."""
+
+    def pack(shard, local, val):
+        n, cap = shard.shape
+        mine = shard == lax.axis_index(axis_name)
+        _, idx, val = lax.sort(
+            ((~mine).astype(jnp.int32), local, val.astype(jnp.float32)),
+            dimension=1, is_stable=True, num_keys=1,
+        )
+        grow = ((0, 0), (0, max(width - cap, 0)))
+        idx, val = jnp.pad(idx, grow), jnp.pad(val, grow)
+        count = mine.sum(axis=1)[:, None]
+        slot = jnp.arange(idx.shape[1])[None, :]
+        kept = slot[:, :width] < count
+        rows = ((0, n_pad - n), (0, 0))
+        out_idx = jnp.pad(jnp.where(kept, idx[:, :width], 0), rows)
+        out_val = jnp.pad(jnp.where(kept, val[:, :width], 0.0), rows)
+        past = (slot >= width) & (slot < count)
+        r, c = jnp.nonzero(past, size=spill, fill_value=0)
+        live = jnp.arange(spill) < past.sum()
+        spilled = (
+            jnp.where(live, r, 0), jnp.where(live, idx[r, c], 0),
+            jnp.where(live, val[r, c], 0.0),
+        )
+        return tuple(a[None] for a in (out_idx, out_val, *spilled))
+
+    shards, spills, everywhere = P(axis_name, None, None), P(axis_name, None), P()
+    return shard_map(
+        pack, mesh=mesh, in_specs=(everywhere,) * 3,
+        out_specs=(shards, shards, spills, spills, spills),
+    )(shard, local, val)
+
+
+def _vertical_sparse_split(D: SparseCorpus, block_rows: int, mesh, axis_name):
+    """Split stage of the sparse vertical path, on the mesh. The corpus,
+    replicated over the mesh (put there if it is not), is dealt and counted
+    on the devices (:func:`_deal_on_device`); the host reads the ``(n, p)``
+    counts to fix the width and the spill length (:func:`_cut_width`); each
+    chip packs its own shard, rows padded with empty rows to a multiple of
+    ``block_rows`` (:func:`_pack_on_device`). The stacks equal
+    :func:`~repro.core.sparse.shard_dims`' cut to the width, with the cut
+    entries in the spill. Returns ``(idx_s, val_s, spill, shard_nnz,
+    m_loc)``: ``(p, n_pad, W)`` local indices and values and the three
+    ``(p, E)`` spill arrays, sharded over ``axis_name``, and the nonzeros
+    of each shard."""
+    p = mesh.shape[axis_name]
+    m_loc = -(-D.m // p)
+    n_pad = D.n + (-D.n) % block_rows
+    everywhere = NamedSharding(mesh, P())
+    idx, val, nnz = (
+        jax.device_put(a, everywhere) for a in (D.indices, D.values, D.nnz)
+    )
+    with trace.span("vertical/shard", p=p):
+        shard, local, counts = _deal_on_device(idx, nnz, m=D.m, p=p)
+        counts = np.asarray(counts)  # the host's one read of the split
+        width, spill = _cut_width(counts)
+        shard_nnz = counts.sum(axis=0)
+        trace.annotate(
+            m_loc=m_loc, cap_loc=width,
+            spilled=int(np.maximum(counts - width, 0).sum()),
+            nnz_max=int(shard_nnz.max()), nnz_mean=float(shard_nnz.mean()),
+        )
+    with trace.span("vertical/pad", n=D.n, n_pad=n_pad):  # the pack pads
+        idx_s, val_s, *spilled = _pack_on_device(
+            shard, local, val, width=width, spill=spill, n_pad=n_pad,
+            mesh=mesh, axis_name=axis_name,
+        )
+    return idx_s, val_s, tuple(spilled), shard_nnz, m_loc
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "n_valid", "m_loc", "threshold", "k", "mesh", "axis_name",
+        "accumulation", "block_rows", "candidate_capacity", "return_stats",
+    ),
+)
+def _vertical_sparse_post_split(
+    idx_s, val_s, spill_row, spill_idx, spill_val, *, n_valid, m_loc,
+    threshold, k, mesh, axis_name, accumulation, block_rows,
+    candidate_capacity, return_stats,
+):
+    """Everything AFTER the split (:func:`_vertical_sparse_split`) — one
+    jitted program, so a join of the same shapes compiles once (the
+    compile audit AOT-compiles the sparse vertical family through this
+    seam; the public entry stays staged because the host sizes the split
+    from the data). Returns the ``n_valid`` real rows' matches.
+
+    A partial tile is ``gather_dot`` over the shard's ``(n, W)`` rows plus
+    the spill list's entries, which are added to the query block's dense
+    rows and, gathered, to their columns.
+    """
+    n, width = idx_s.shape[1:]
+    ncb = n // block_rows  # divisibility validated by _vertical_dispatch
+
+    def make_partials(idxL, valL, rowS, idxS, valS):
+        # shard dim (1, ...) → local
+        idxL, valL, rowS, idxS, valS = (
+            a[0] for a in (idxL, valL, rowS, idxS, valS)
+        )
         sp_loc = SparseCorpus(idxL, valL, jnp.zeros((n,), jnp.int32), m_loc)
-        Ci = idxL.reshape(ncb, block_rows, cap_loc)
-        Cv = valL.reshape(ncb, block_rows, cap_loc)
+        Ci = idxL.reshape(ncb, block_rows, width)
+        Cv = valL.reshape(ncb, block_rows, width)
 
         def partials(blk):
-            qd = densify_rows(sp_loc, blk * block_rows, block_rows)
+            base = blk * block_rows
+            qd = densify_rows(sp_loc, base, block_rows)
+            here = (rowS >= base) & (rowS < base + block_rows)
+            qd = qd.at[jnp.where(here, rowS - base, block_rows), idxS].add(
+                valS, mode="drop"
+            )
 
             def chunk(_, ci):
-                return _, gather_dot(qd, Ci[ci], Cv[ci])
+                return _, gather_dot(qd, Ci[ci], Cv[ci], chunk=_GATHER_CHUNK)
 
             _, ss = lax.scan(chunk, 0, jnp.arange(ncb))  # (ncb, b, block)
-            return jnp.moveaxis(ss, 0, 1).reshape(block_rows, n)
+            A = jnp.moveaxis(ss, 0, 1).reshape(block_rows, n)
+            return A.at[:, rowS].add(qd[:, idxS] * valS)
 
         return partials
 
-    return _vertical_dispatch(
-        (jnp.asarray(idx_s), jnp.asarray(val_s)), make_partials, n,
+    shards = P(axis_name, None, None)
+    spills = P(axis_name, None)
+    out, stats = _vertical_dispatch(
+        (idx_s, val_s, spill_row, spill_idx, spill_val), make_partials, n,
         threshold, k, mesh, axis_name,
         accumulation=accumulation, block_rows=block_rows,
-        candidate_capacity=candidate_capacity, return_stats=return_stats,
-        in_specs=(P(axis_name, None, None), P(axis_name, None, None)),
+        candidate_capacity=candidate_capacity, return_stats=True,
+        in_specs=(shards, shards, spills, spills, spills),
         # The VMA checker has no rule for the scatter/gather ops inside the
         # sparse partial-score primitive; verified numerically by tests.
-        strict_vma=False,
+        strict_vma=False, n_valid=n_valid,
     )
+    out = jax.tree.map(lambda x: x[:n_valid], out)
+    return (out, stats) if return_stats else out
 
 
 def _apss_vertical_sparse(
@@ -797,27 +968,44 @@ def _apss_vertical_sparse(
 ):
     p = mesh.shape[axis_name]
     n = D.n
-    idx_s, val_s, nnz_s, m_loc = shard_dims(D, p)  # host split: not traceable
-    del nnz_s  # scoring needs only the 0-padded (idx, val) slots
-    cap_loc = idx_s.shape[-1]
-
-    out = _vertical_sparse_post_split(
-        idx_s, val_s, n=n, m_loc=m_loc, threshold=threshold, k=k,
-        mesh=mesh, axis_name=axis_name, accumulation=accumulation,
-        block_rows=block_rows, candidate_capacity=candidate_capacity,
-        return_stats=return_stats,
+    C = candidate_capacity or default_candidate_capacity(k)
+    idx_s, val_s, spill, shard_nnz, m_loc = _vertical_sparse_split(
+        D, block_rows, mesh, axis_name
     )
+    n_pad, width = idx_s.shape[1:]
+    with trace.span(
+        "vertical/dispatch", accumulation=accumulation, capacity=C,
+        block_rows=block_rows,
+    ):
+        out, stats = _vertical_sparse_post_split(
+            idx_s, val_s, *spill, n_valid=n, m_loc=m_loc, threshold=threshold, k=k, mesh=mesh,
+            axis_name=axis_name, accumulation=accumulation,
+            block_rows=block_rows, candidate_capacity=candidate_capacity,
+            return_stats=True,
+        )
+        routes = {}
+        if telemetry.enabled():
+            # waits for the join: the counts exist once it has run
+            routes = {
+                "blocks_pruned": int(stats.blocks_pruned),
+                "blocks_exact": int(stats.blocks_exact),
+            }
+            trace.annotate(**routes)
     if telemetry.enabled():
-        C = candidate_capacity or default_candidate_capacity(k)
         telemetry.record(telemetry.ApssStats(
             variant=f"vertical/{accumulation}",
             n=n, m=D.m, devices=p, block_rows=block_rows, sparse=True,
             hops=telemetry.vertical_hops(
-                accumulation, str(axis_name), p, n, block_rows, C
+                accumulation, str(axis_name), p, n_pad, block_rows, C
             ),
-            flops=telemetry.sparse_join_flops(n, n, cap_loc),
-            extra={"capacity": C, "cap_loc": cap_loc},
+            flops=telemetry.sparse_join_flops(n_pad, n_pad, width),
+            extra={
+                "capacity": C, "cap_loc": width, "n_pad": n_pad,
+                "shard_nnz": [int(x) for x in shard_nnz], **routes,
+            },
         ))
+    if return_stats:
+        return out, stats
     return out
 
 
@@ -831,16 +1019,26 @@ def _partial_scores(D_loc, blk, block_rows):
     )
 
 
+def _allreduce_block(A, blk, *, threshold, k, axis_name, block_rows):
+    """Exact matches of one query block: the all-reduce of its whole
+    ``(block_rows, n)`` partial tile."""
+    S = lax.psum(A, axis_name)
+    return extract_matches(
+        S, threshold, k, row_offset=blk * block_rows, exclude_self=True
+    )
+
+
 def _vertical_allreduce(partials_fn, n, *, threshold, k, axis_name, block_rows):
     """vertical-noopt: all-reduce the full dense score block (paper baseline)."""
     nb = n // block_rows
 
     def body(_, blk):
         A = partials_fn(blk)
-        S = lax.psum(A, axis_name)
-        m = extract_matches(
-            S, threshold, k, row_offset=blk * block_rows, exclude_self=True
-        )
+        with jax.named_scope("vertical/accumulate"):
+            m = _allreduce_block(
+                A, blk, threshold=threshold, k=k, axis_name=axis_name,
+                block_rows=block_rows,
+            )
         return _, m
 
     _, ms = lax.scan(body, None, jnp.arange(nb))
@@ -855,16 +1053,27 @@ def _vertical_scatter(partials_fn, n, *, threshold, k, axis_name, p, block_rows)
 
     def body(_, blk):
         A = partials_fn(blk)  # (b, n)
-        S_slice = lax.psum_scatter(A, axis_name, scatter_dimension=0, tiled=True)
-        m = extract_matches(
-            S_slice, threshold, k,
-            row_offset=blk * block_rows + me * rows_per_dev,
-            exclude_self=True,
-        )
+        with jax.named_scope("vertical/accumulate"):
+            S_slice = lax.psum_scatter(
+                A, axis_name, scatter_dimension=0, tiled=True
+            )
+            m = extract_matches(
+                S_slice, threshold, k,
+                row_offset=blk * block_rows + me * rows_per_dev,
+                exclude_self=True,
+            )
         return _, m
 
     _, ms = lax.scan(body, None, jnp.arange(nb))
     return ms  # stacked (nb, rows_per_dev, ...) per device
+
+
+def _overflow_rows(A, t_local, capacity):
+    """Rows of ``A`` with more Lemma-1 candidates (partials ``≥ t/p``)
+    than the capacity holds."""
+    cc = min(capacity, A.shape[-1])
+    n_cand = jnp.sum(A >= t_local, axis=-1, dtype=jnp.int32)
+    return jnp.sum(n_cand > cc, dtype=jnp.int32)
 
 
 def _local_candidates(A, t_local, capacity):
@@ -873,9 +1082,48 @@ def _local_candidates(A, t_local, capacity):
     cc = min(capacity, A.shape[-1])
     c_val, c_idx = lax.top_k(masked, cc)
     c_idx = jnp.where(c_val > NEG_INF, c_idx, -1).astype(jnp.int32)
-    n_cand = jnp.sum(masked > NEG_INF, axis=-1, dtype=jnp.int32)
-    overflow = jnp.sum(n_cand > cc, dtype=jnp.int32)
-    return c_val, c_idx, overflow
+    return c_val, c_idx, _overflow_rows(A, t_local, capacity)
+
+
+def _rescore_candidates(A, c_idx, blk, *, threshold, k, axis_name, block_rows):
+    """Exact matches at the union of every shard's candidate ids: a small
+    all-gather of the ids, then one psum of the partials there."""
+    all_idx = lax.all_gather(c_idx, axis_name, axis=1, tiled=True)  # (b, p*C)
+    safe = jnp.maximum(all_idx, 0)
+    mine = jnp.take_along_axis(A, safe, axis=1)
+    mine = jnp.where(all_idx >= 0, mine, 0.0)
+    total = lax.psum(mine, axis_name)  # exact scores at the union
+    return matches_from_candidates(
+        total, all_idx, threshold, k,
+        row_offset=blk * block_rows, exclude_self=True, dedupe=True,
+    )
+
+
+def _pruned_or_exact(A, overflow, candidates, blk, **kw):
+    """One query block's matches, and whether it took the exact route.
+
+    Lemma-1 pruning is exact only while no shard's candidates overflow the
+    capacity. Where any shard's do (``overflow`` is this shard's count of
+    such rows), the block is scored by the all-reduce of its whole partial
+    tile instead; every shard takes the same branch. ``candidates()``
+    gives this shard's candidate ids, on the pruned route only.
+    """
+    exact = lax.pmax(overflow, kw["axis_name"]) > 0
+    m = lax.cond(
+        exact,
+        lambda: _allreduce_block(A, blk, **kw),
+        lambda: _rescore_candidates(A, candidates(), blk, **kw),
+    )
+    return m, exact.astype(jnp.int32)
+
+
+def _route_stats(overflow, n_exact, nb, axis_name) -> ApssStats:
+    # Overflow counts are device-local; expose the global max.
+    return ApssStats(
+        overflow_rows=lax.pmax(overflow, axis_name),
+        blocks_pruned=nb - n_exact,
+        blocks_exact=n_exact,
+    )
 
 
 def _vertical_compressed(
@@ -887,32 +1135,30 @@ def _vertical_compressed(
     ``(idx, val)``; all-gather the candidate ids (volume p·C « n); every
     device contributes its partial at the union via one small psum; filter
     exactly at ``t``. Matches paper's two-step accumulate: candidate-set
-    union (Reduce-All ∪) then parallel score addition.
+    union (Reduce-All ∪) then parallel score addition. A block whose
+    candidates overflow C on any device is all-reduced whole instead
+    (:func:`_pruned_or_exact`); the overflow is counted before the top-C
+    selection, which only the pruned route runs.
     """
     nb = n // block_rows
     t_local = local_threshold(threshold, p)
+    kw = dict(threshold=threshold, k=k, axis_name=axis_name,
+              block_rows=block_rows)
 
     def body(carry, blk):
         A = partials_fn(blk)  # (b, n) partials
-        c_val, c_idx, overflow = _local_candidates(A, t_local, capacity)
-        # Union of candidate ids across dimension shards (small all-gather).
-        all_idx = lax.all_gather(c_idx, axis_name, axis=1, tiled=True)  # (b, p*C)
-        safe = jnp.maximum(all_idx, 0)
-        mine = jnp.take_along_axis(A, safe, axis=1)
-        mine = jnp.where(all_idx >= 0, mine, 0.0)
-        total = lax.psum(mine, axis_name)  # exact scores at the union
-        m = matches_from_candidates(
-            total, all_idx, threshold, k,
-            row_offset=blk * block_rows, exclude_self=True, dedupe=True,
-        )
-        return carry + overflow, m
+        with jax.named_scope("vertical/accumulate"):
+            overflow = _overflow_rows(A, t_local, capacity)
+            m, exact = _pruned_or_exact(
+                A, overflow, lambda: _local_candidates(A, t_local, capacity)[1],
+                blk, **kw,
+            )
+        return (carry[0] + overflow, carry[1] + exact), m
 
-    overflow, ms = lax.scan(body, _pvary(jnp.int32(0), axis_name), jnp.arange(nb))
+    zero = _pvary(jnp.int32(0), axis_name)
+    (overflow, n_exact), ms = lax.scan(body, (zero, zero), jnp.arange(nb))
     out = jax.tree.map(lambda x: x.reshape(n, *x.shape[2:]), ms)
-    # Overflow counts are device-local; expose the global max (any truncation
-    # anywhere invalidates the exactness guarantee for affected rows).
-    overflow = lax.pmax(overflow, axis_name)
-    return out, ApssStats(overflow_rows=overflow)
+    return out, _route_stats(overflow, n_exact, nb, axis_name)
 
 
 def _pairwise_merge_candidates(idx_a, val_a, ub_a, idx_b, val_b, ub_b, capacity):
@@ -968,63 +1214,61 @@ def _vertical_recursive(
     exact with one-sided candidate knowledge we track an *upper bound*
     ``ub = val + (missing half's threshold)`` and filter on ``ub`` — the
     paper's "completing partial scores" problem solved bound-side. A final
-    psum over the (replicated) top-level candidate set yields exact scores.
+    psum over the (replicated) top-level candidate set yields exact scores;
+    a block whose candidates overflowed C at any level on any device is
+    all-reduced whole instead (:func:`_pruned_or_exact`).
     """
     nb = n // block_rows
     t = jnp.float32(threshold)
     t_leaf = local_threshold(threshold, p)
     me = lax.axis_index(axis_name)
     levels = p.bit_length() - 1
+    kw = dict(threshold=threshold, k=k, axis_name=axis_name,
+              block_rows=block_rows)
 
     def body(carry, blk):
         A = partials_fn(blk)
-        c_val, c_idx, overflow = _local_candidates(A, t_leaf, capacity)
-        c_ub = jnp.where(c_idx >= 0, c_val, NEG_INF)
+        with jax.named_scope("vertical/accumulate"):
+            c_val, c_idx, overflow = _local_candidates(A, t_leaf, capacity)
+            c_ub = jnp.where(c_idx >= 0, c_val, NEG_INF)
 
-        for lvl in range(levels):
-            bit = 1 << lvl
-            sub_t = t * (2.0 * bit) / p      # threshold of the merged subcube
-            half_t = t * float(bit) / p      # missing-half bound
-            perm = [(i, i ^ bit) for i in range(p)]
-            o_idx, o_val, o_ub = (
-                lax.ppermute(x, axis_name, perm=perm)
-                for x in (c_idx, c_val, c_ub)
-            )
-            # One-sided candidates get the partner-half headroom added to ub.
-            c_ub_adj = jnp.where(c_idx >= 0, c_ub + half_t, NEG_INF)
-            o_ub_adj = jnp.where(o_idx >= 0, o_ub + half_t, NEG_INF)
-            # Two-sided duplicates: pairwise merge sums val and adjusted ub,
-            # double-counting the +half_t headroom — looser but still sound
-            # (ub only ever overestimates the true subcube partial).
-            m_idx, m_val, m_ub, merge_ovf = _pairwise_merge_candidates(
-                c_idx, c_val, c_ub_adj, o_idx, o_val, o_ub_adj, capacity
-            )
-            overflow = overflow + merge_ovf
-            # A summed pair has ub = ub_a + ub_b + 2*half_t but no missing
-            # half: we cannot tell pairs apart post-merge, so keep the looser
-            # bound (still sound: ub only ever overestimates).
-            keep = m_ub >= sub_t
-            c_idx = jnp.where(keep, m_idx, -1)
-            c_val = jnp.where(keep, m_val, 0.0)
-            c_ub = jnp.where(keep, m_ub, NEG_INF)
+            for lvl in range(levels):
+                bit = 1 << lvl
+                sub_t = t * (2.0 * bit) / p      # threshold of the merged subcube
+                half_t = t * float(bit) / p      # missing-half bound
+                perm = [(i, i ^ bit) for i in range(p)]
+                o_idx, o_val, o_ub = (
+                    lax.ppermute(x, axis_name, perm=perm)
+                    for x in (c_idx, c_val, c_ub)
+                )
+                # One-sided candidates get the partner-half headroom added to ub.
+                c_ub_adj = jnp.where(c_idx >= 0, c_ub + half_t, NEG_INF)
+                o_ub_adj = jnp.where(o_idx >= 0, o_ub + half_t, NEG_INF)
+                # Two-sided duplicates: pairwise merge sums val and adjusted ub,
+                # double-counting the +half_t headroom — looser but still sound
+                # (ub only ever overestimates the true subcube partial).
+                m_idx, m_val, m_ub, merge_ovf = _pairwise_merge_candidates(
+                    c_idx, c_val, c_ub_adj, o_idx, o_val, o_ub_adj, capacity
+                )
+                overflow = overflow + merge_ovf
+                # A summed pair has ub = ub_a + ub_b + 2*half_t but no missing
+                # half: we cannot tell pairs apart post-merge, so keep the looser
+                # bound (still sound: ub only ever overestimates).
+                keep = m_ub >= sub_t
+                c_idx = jnp.where(keep, m_idx, -1)
+                c_val = jnp.where(keep, m_val, 0.0)
+                c_ub = jnp.where(keep, m_ub, NEG_INF)
 
-        # Top level: candidate ids are level-merged but may still differ per
-        # device (capacity effects); take the union once, then exact-rescore.
-        all_idx = lax.all_gather(c_idx, axis_name, axis=1, tiled=True)
-        safe = jnp.maximum(all_idx, 0)
-        mine = jnp.take_along_axis(A, safe, axis=1)
-        mine = jnp.where(all_idx >= 0, mine, 0.0)
-        total = lax.psum(mine, axis_name)
-        m = matches_from_candidates(
-            total, all_idx, threshold, k,
-            row_offset=blk * block_rows, exclude_self=True, dedupe=True,
-        )
-        return carry + overflow, m
+            # Top level: candidate ids are level-merged but may still differ per
+            # device (capacity effects); take the union once, then exact-rescore
+            # — or all-reduce the whole tile where any level overflowed.
+            m, exact = _pruned_or_exact(A, overflow, lambda: c_idx, blk, **kw)
+        return (carry[0] + overflow, carry[1] + exact), m
 
-    overflow, ms = lax.scan(body, _pvary(jnp.int32(0), axis_name), jnp.arange(nb))
+    zero = _pvary(jnp.int32(0), axis_name)
+    (overflow, n_exact), ms = lax.scan(body, (zero, zero), jnp.arange(nb))
     out = jax.tree.map(lambda x: x.reshape(n, *x.shape[2:]), ms)
-    overflow = lax.pmax(overflow, axis_name)
-    return out, ApssStats(overflow_rows=overflow)
+    return out, _route_stats(overflow, n_exact, nb, axis_name)
 
 
 # ---------------------------------------------------------------------------
@@ -1595,8 +1839,6 @@ def apss(
     # Span wrap covers dispatch (trace time under jit, dispatch+execute in
     # eager callers); per-ring-step child spans arrive via the StepTicker
     # on the ApssStats record each entry point emits inside this span.
-    from repro.obs import trace
-
     with trace.span("apss", distribution=distribution):
         if distribution == "auto":
             from repro.planner.plan import plan_apss

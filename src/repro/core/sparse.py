@@ -24,9 +24,11 @@ Scoring never materializes ``(n, m)``; the two primitives are
   tile matmul.
 
 :func:`sparse_similarity_topk` composes them into the blocked join that
-backs ``apss_blocked`` / ``apss_horizontal`` / ``apss_vertical`` for sparse
-inputs. The maximally-pruned single-device path (inverted-index worklist +
-CSR tile kernel) lives in ``kernels/apss_block/sparse.py``.
+backs ``apss_blocked`` / ``apss_horizontal`` for sparse inputs; the 2-D
+checkerboard scores its cells with :func:`gather_dot`. The
+maximally-pruned single-device path (inverted-index worklist + CSR tile
+kernel) lives in ``kernels/apss_block/sparse.py``; the sparse vertical
+path scores its partial tiles with :func:`gather_dot` too.
 
 Duplicate coordinates within a row are legal and mean *summation* (the COO
 convention): ``to_dense`` scatter-adds and ``gather_dot`` sums every slot,
@@ -188,54 +190,79 @@ def density(sp: SparseCorpus) -> float:
     return float(np.asarray(sp.nnz).sum()) / float(sp.n * sp.m)
 
 
+def deal_dims(sp: SparseCorpus, p: int) -> tuple[np.ndarray, np.ndarray]:
+    """Host-side assignment of every dimension to one of ``p`` shards.
+
+    Dimensions are dealt round-robin in order of posting-list length (the
+    stored entries that name them, most first; ties by id): the one of
+    rank ``r`` goes to shard ``r % p`` as its local dimension ``r // p``.
+    On a Zipf vocabulary contiguous ranges would put nearly every nonzero
+    on the shard that holds the head; dealing spreads the head, so every
+    shard holds about ``nnz / p`` entries and ``⌈m/p⌉`` dimensions.
+
+    Returns ``owner (m,)`` (the shard of each dimension) and ``local
+    (m,)`` (its id inside that shard), both int64.
+    """
+    idx = np.asarray(sp.indices)
+    nnz = np.asarray(sp.nnz)
+    valid = np.arange(idx.shape[1])[None, :] < nnz[:, None]
+    postings = np.bincount(idx[valid], minlength=sp.m)
+    rank = np.empty(sp.m, np.int64)
+    rank[np.lexsort((np.arange(sp.m), -postings))] = np.arange(sp.m)
+    return rank % p, rank // p
+
+
 def shard_dims(
     sp: SparseCorpus, p: int
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-    """Host-side vertical (dimension) split into ``p`` contiguous slices.
+    """Host-side vertical (dimension) split into ``p`` shards.
 
     The paper's 1-D vertical distribution in its natural habitat: device
-    ``d`` owns dimensions ``[d·m/p, (d+1)·m/p)`` — a contiguous shard of
-    the inverted index — and sees every row restricted to that slice.
+    ``d`` owns the posting lists of the dimensions :func:`deal_dims` deals
+    it — a shard of the inverted index — and sees every row restricted to
+    them. Any ``m`` is accepted.
 
-    Returns stacked ``(p, n, cap_loc)`` indices (LOCAL, slice-relative) and
-    values, ``(p, n)`` local nnz, and ``m_loc = m // p``. ``cap_loc`` is
-    the max per-device per-row count (uniform so the stack is rectangular).
+    Returns stacked ``(p, n, cap_loc)`` indices (LOCAL, shard-relative, in
+    ``[0, m_loc)``) and values, ``(p, n)`` local nnz (so ``nnz.sum(1)`` is
+    each shard's nonzero count), and ``m_loc = ⌈m/p⌉``. ``cap_loc`` is the
+    max per-device per-row count (uniform so the stack is rectangular).
     """
-    if sp.m % p:
-        raise ValueError(f"m={sp.m} must be a multiple of p={p}")
-    m_loc = sp.m // p
+    owner, local = deal_dims(sp, p)
+    m_loc = -(-sp.m // p)
     idx = np.asarray(sp.indices)
     val = np.asarray(sp.values)
     nnz = np.asarray(sp.nnz)
     n, cap = idx.shape
     valid = np.arange(cap)[None, :] < nnz[:, None]
-    owner = idx // m_loc
-    counts = np.stack(
-        [(valid & (owner == d)).sum(axis=1) for d in range(p)]
-    )  # (p, n)
+    # Each row's slots in shard order (padding, shard p, last), stable, so
+    # that shard d's slots are one run per row, in their original order.
+    shard = np.where(valid, owner.astype(np.int16)[idx], np.int16(p))
+    order = np.argsort(shard, axis=1, kind="stable")
+    sorted_idx = local.astype(np.int32)[np.take_along_axis(idx, order, axis=1)]
+    sorted_val = np.take_along_axis(val, order, axis=1)
+    counts = np.stack([(shard == d).sum(axis=1) for d in range(p)])  # (p, n)
+    starts = np.cumsum(counts, axis=0) - counts  # (p, n): where each run begins
     cap_loc = max(1, int(counts.max(initial=1)))
+    slot = np.arange(cap_loc)[None, :]
     out_idx = np.zeros((p, n, cap_loc), np.int32)
     out_val = np.zeros((p, n, cap_loc), np.float32)
     for d in range(p):
-        sel = valid & (owner == d)
-        # Stable-pack selected slots to the front of each row.
-        order = np.argsort(~sel, axis=1, kind="stable")[:, :cap_loc]
-        packed = np.take_along_axis(sel, order, axis=1)
-        gi = np.take_along_axis(idx, order, axis=1)
-        gv = np.take_along_axis(val, order, axis=1)
-        out_idx[d] = np.where(packed, gi - d * m_loc, 0)
-        out_val[d] = np.where(packed, gv, 0.0)
+        at = np.minimum(starts[d][:, None] + slot, cap - 1)
+        mine = slot < counts[d][:, None]
+        out_idx[d] = np.where(mine, np.take_along_axis(sorted_idx, at, axis=1), 0)
+        out_val[d] = np.where(mine, np.take_along_axis(sorted_val, at, axis=1), 0.0)
     return out_idx, out_val, counts.astype(np.int32), m_loc
 
 
 def dim_slices(sp: SparseCorpus, p: int) -> list[SparseCorpus]:
     """The ``p`` per-slice corpora of :func:`shard_dims` as SparseCorpus views.
 
-    Slice ``d`` holds every row restricted to dimensions ``[d·m/p,
-    (d+1)·m/p)`` with SLICE-RELATIVE indices and ``m = m/p`` — exactly the
-    cell contents of one checkerboard column in ``apss_2d``. Used by the
-    per-cell pruning bounds (``core.pruning.checkerboard_live_mask``) and
-    tests; the distributed path consumes the stacked arrays directly.
+    Slice ``d`` holds every row restricted to the dimensions dealt to
+    shard ``d`` (:func:`deal_dims`) with SLICE-RELATIVE indices and ``m =
+    ⌈m/p⌉`` — exactly the cell contents of one checkerboard column in
+    ``apss_2d``. Used by the per-cell pruning bounds
+    (``core.pruning.checkerboard_live_mask``) and tests; the distributed
+    path consumes the stacked arrays directly.
     """
     idx_s, val_s, nnz_s, m_loc = shard_dims(sp, p)
     return [

@@ -26,9 +26,10 @@ vertical / hierarchical / 2-D checkerboard — plus the serving
 ``query_topk`` inners and the mutable delta join, captured from REAL call
 sites via ``obs.compile.capture_calls`` (their worklist arguments are
 built host-side, so the audit lowers the exact program the hot path
-runs). Host-staged sparse families (``shard_dims`` pre-split) lower
-through the post-split seams ``core.distributed._vertical_sparse_post_split``
-/ ``_2d_sparse_post_split``.
+runs). Host-staged sparse families (dimensions dealt by frequency: the
+vertical split sized on the host, the 2-D ``shard_dims`` pre-split) lower
+through the post-split seams
+``core.distributed._vertical_sparse_post_split`` / ``_2d_sparse_post_split``.
 
 Known, documented gaps (reported as entry notes, not failures):
 
@@ -261,18 +262,18 @@ def _lower_planned(cfg, data, threshold: float, k: int, mesh):
         )
     names = tuple(mesh.axis_names)
     if cfg.kind == "vertical":
-        p = mesh.shape[names[-1]]
-        idx_s, val_s, nnz_s, m_loc = shard_dims(data, p)
-        del nnz_s
+        idx_s, val_s, spill, _, m_loc = dist._vertical_sparse_split(
+            data, cfg.block_rows, mesh, names[-1]
+        )
         seam = functools.partial(
             dist._vertical_sparse_post_split,
-            n=data.n, m_loc=m_loc, threshold=float(threshold), k=k,
+            n_valid=data.n, m_loc=m_loc, threshold=float(threshold), k=k,
             mesh=mesh, axis_name=names[-1], accumulation=cfg.accumulation,
             block_rows=cfg.block_rows, candidate_capacity=None,
             return_stats=False,
         )
         return obs_compile.lower_and_compile(
-            jax.jit(seam), idx_s, val_s, name=name,
+            jax.jit(seam), idx_s, val_s, *spill, name=name,
         )
     if cfg.kind == "2d":
         r = mesh.shape[names[1]]

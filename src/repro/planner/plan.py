@@ -256,12 +256,12 @@ def candidate_configs(
                     cfgs.append(
                         VariantConfig("hierarchical", sparse, b, use_kernel=uk)
                     )
-        if len(names) == 1 and s.m % p == 0:
-            # both representations shard the dimension axis: dense as
-            # P(None, axis) columns, sparse as shard_dims posting slices —
-            # m must divide either way
+        if len(names) == 1 and (sparse or s.m % p == 0):
+            # dense shards P(None, axis) columns, so m and n must divide;
+            # sparse deals posting lists to the shards and pads rows to the
+            # block, so any m and n go
             for b in blocks:
-                if s.n % b:
+                if not sparse and s.n % b:
                     continue
                 for acc in ("allreduce", "scatter", "compressed", "recursive"):
                     if acc == "scatter" and b % p:
@@ -276,11 +276,13 @@ def candidate_configs(
     if len(names) == 2:
         q, r = sizes[names[0]], sizes[names[1]]
         # Both representations split the dimension axis r ways: dense as
-        # P(row, col) column shards, sparse as shard_dims posting slices —
-        # m must divide either way.
-        if s.n % q == 0 and s.m % r == 0:
+        # P(row, col) column shards (m must divide), sparse as shard_dims
+        # posting lists (any m).
+        if s.n % q == 0:
             n_loc = s.n // q
             for sparse in reps:
+                if not sparse and s.m % r:
+                    continue
                 for b in blocks:
                     for acc in ("allreduce", "compressed"):
                         cfgs.append(
@@ -362,11 +364,12 @@ def _dispatch(cfg: VariantConfig, data, threshold: float, k: int, mesh):
 
 def _has_host_stage(cfg: VariantConfig) -> bool:
     """Configs whose dispatch runs host-side stages (worklist compaction,
-    ``shard_dims``) and therefore cannot be traced under jit."""
+    the sizing of a dimension split) and therefore cannot be traced under
+    jit."""
     if cfg.kind == "blocked" and cfg.sparse and cfg.use_kernel:
         return True  # apss_sparse_compacted: host-compacted worklist
     if cfg.kind in ("vertical", "2d") and cfg.sparse:
-        return True  # shard_dims: host posting-list split
+        return True  # the dimension split is sized on the host
     return False
 
 

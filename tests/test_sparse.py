@@ -29,6 +29,7 @@ from repro.core.sparse import (
     density,
     from_dense,
     normalize_sparse,
+    deal_dims,
     pad_rows_sparse,
     shard_dims,
     sparse_similarity_topk,
@@ -105,14 +106,38 @@ def test_shard_dims_partition_is_lossless():
     sp = from_dense(D)
     idx_s, val_s, nnz_s, m_loc = shard_dims(sp, 4)
     assert m_loc == 12
+    owner, local = deal_dims(sp, 4)
     back = np.zeros_like(D)
     for d in range(4):
         loc = SparseCorpus(
             jnp.asarray(idx_s[d]), jnp.asarray(val_s[d]),
             jnp.asarray(nnz_s[d]), m_loc,
         )
-        back[:, d * m_loc:(d + 1) * m_loc] += np.asarray(to_dense(loc))
+        mine = np.nonzero(owner == d)[0]
+        back[:, mine] += np.asarray(to_dense(loc))[:, local[mine]]
     np.testing.assert_allclose(back, D, rtol=1e-6)
+
+
+@pytest.mark.parametrize("m", [1000, 1001, 1003])
+def test_deal_dims_covers_once_and_balances_zipf(m):
+    """Every dimension lies on exactly one shard at one local id below
+    ⌈m/p⌉, and dealing by posting-list length spreads a Zipf corpus's
+    nonzeros over the shards within 5 %, where contiguous ranges would
+    put most of them on the shard holding the head."""
+    p = 4
+    sp = sparse_zipfian_corpus(400, m, 40, seed=3)
+    owner, local = deal_dims(sp, p)
+    m_loc = -(-m // p)
+    pairs = owner * m_loc + local
+    assert owner.min() >= 0 and owner.max() < p and local.max() < m_loc
+    assert np.unique(pairs).size == m  # one (shard, local id) per dimension
+    idx_s, val_s, nnz_s, got_m_loc = shard_dims(sp, p)
+    assert got_m_loc == m_loc
+    per_shard = nnz_s.sum(axis=1)
+    assert per_shard.sum() == int(np.asarray(sp.nnz).sum())
+    assert per_shard.max() / per_shard.mean() <= 1.05
+    valid = np.arange(idx_s.shape[-1]) < nnz_s[..., None]
+    assert idx_s[valid].max() < m_loc
 
 
 # -- inverted-index candidate generation + sparse bounds ----------------------
@@ -502,3 +527,184 @@ def test_adversarial_csr_join_equals_dense_reference(seed):
         ref,
     )
     _check(apss_sparse_compacted(sp, 0.3, 32, block_m=16, lane_pad=8), ref)
+
+
+# -- the sparse vertical path at awkward shapes -------------------------------
+
+
+def _quoted_zipf(n, m, avg_nnz, seed):
+    """A Zipf corpus in which a quarter of the rows quote a share of a
+    random parent's entries, so that the join has matches; unit rows."""
+    D = np.array(to_dense(sparse_zipfian_corpus(n, m, avg_nnz, seed=seed)))
+    rng = np.random.default_rng(seed)
+    for r in rng.choice(n, n // 4, replace=False):
+        parent = (r + rng.integers(1, n)) % n
+        cols = np.nonzero(D[parent])[0]
+        quoted = max(1, int(rng.uniform(0.2, 0.8) * cols.size))
+        take = rng.choice(cols, quoted, replace=False)
+        D[r, take] = D[parent, take]
+    return D / np.linalg.norm(D, axis=1, keepdims=True)
+
+
+TQ = 0.5  # a threshold at which no row of quoted200 has more than K matches
+
+
+@pytest.fixture(scope="module")
+def quoted200():
+    """200 rows (not a multiple of the 64-row block), m = 1001 (odd)."""
+    D = _quoted_zipf(200, 1001, 10, seed=21)
+    return from_dense(D), apss_reference(jnp.asarray(D), TQ, K)
+
+
+@pytest.mark.parametrize("capacity", [256, 2])
+@pytest.mark.parametrize(
+    "accumulation", ["compressed", "recursive", "scatter", "allreduce"]
+)
+def test_sparse_vertical_matches_oracle_at_awkward_shapes(
+    quoted200, accumulation, capacity
+):
+    """``apss(SparseCorpus, distribution="vertical")`` on 4 devices agrees
+    with the dense oracle: match sets, ids and counts exactly, values to
+    float32 rounding. A capacity of 256 holds every column of the padded
+    join, so the pruned accumulations prune every block; with 2 the Lemma-1
+    candidates overflow and they score those blocks exactly instead."""
+    import jax
+
+    from repro.core.distributed import apss
+
+    sp, ref = quoted200
+    assert int(np.asarray(ref.counts).max()) <= K  # no row truncated at k
+    assert (np.asarray(ref.counts) > 0).mean() > 0.2
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("model",))
+    got, stats = apss(
+        sp, TQ, K, mesh, distribution="vertical", accumulation=accumulation,
+        block_rows=64, candidate_capacity=capacity, return_stats=True,
+    )
+    _check(got, ref)
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(got.indices), axis=1),
+        np.sort(np.asarray(ref.indices), axis=1),
+    )
+    np.testing.assert_allclose(
+        np.sort(np.asarray(got.values), axis=1),
+        np.sort(np.asarray(ref.values), axis=1),
+        rtol=1e-6, atol=1e-6,
+    )
+    blocks = 256 // 64
+    assert int(stats.blocks_pruned) + int(stats.blocks_exact) == blocks
+    if accumulation in ("scatter", "allreduce"):
+        assert int(stats.blocks_exact) == blocks
+    elif capacity == 2:
+        assert int(stats.overflow_rows) > 0 and int(stats.blocks_exact) > 0
+    else:
+        assert int(stats.overflow_rows) == 0 and int(stats.blocks_pruned) == blocks
+
+
+def test_sparse_vertical_padding_rows_never_match(quoted200):
+    """At a negative threshold every real pair matches; the 56 empty rows
+    that pad 200 rows to the block multiple must neither appear nor count."""
+    import jax
+
+    from repro.core.distributed import apss
+
+    sp, _ = quoted200
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("model",))
+    got = apss(sp, -0.5, 8, mesh, distribution="vertical", block_rows=64)
+    assert got.counts.shape == (200,)
+    np.testing.assert_array_equal(np.asarray(got.counts), 199)
+    idx = np.asarray(got.indices)
+    assert idx.min() >= 0 and idx.max() < 200
+
+
+@pytest.fixture(scope="module")
+def one_long_row():
+    """1,200 short Zipf rows and one of 200 nonzeros: the shards' width is
+    set by the short rows, and the long row's excess entries spill."""
+    D = np.array(to_dense(sparse_zipfian_corpus(1201, 2003, 8, seed=23)))
+    rng = np.random.default_rng(23)
+    D[600] = 0.0
+    D[600, rng.choice(2003, 200, replace=False)] = rng.random(200) + 0.05
+    # row 601 quotes the long row's last 100 dimensions, which hold its
+    # spilled entries: a match of about 0.71 that the spill alone carries
+    D[601] = 0.0
+    D[601, np.nonzero(D[600])[0][-100:]] = D[600, np.nonzero(D[600])[0][-100:]]
+    D /= np.linalg.norm(D, axis=1, keepdims=True)
+    return from_dense(D), apss_reference(jnp.asarray(D), TQ, K)
+
+
+@pytest.mark.parametrize("accumulation", ["compressed", "allreduce"])
+def test_sparse_vertical_spills_the_longest_row_exactly(one_long_row, accumulation):
+    """The partial tile over the cut rows plus the spill list equals the
+    oracle's scores, the long row's own matches included."""
+    import jax
+
+    from repro.core import distributed as dist
+
+    sp, ref = one_long_row
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("model",))
+    idx_s, _, (rows, _, vals), _, _ = dist._vertical_sparse_split(
+        sp, 256, mesh, "model"
+    )
+    assert idx_s.shape[-1] == 32  # the width the short rows need
+    rows, vals = np.asarray(rows), np.asarray(vals)
+    assert set(rows[vals != 0].tolist()) == {600}  # only the long row spills
+    got = dist.apss(
+        sp, TQ, K, mesh, distribution="vertical", accumulation=accumulation,
+        block_rows=256,
+    )
+    _check(got, ref)
+    assert int(np.asarray(ref.counts)[600]) > 0  # the long row has a match
+    assert 601 in np.asarray(got.indices)[600].tolist()
+
+
+def _host_vertical_split(sp, p, block_rows):
+    """NumPy oracle of the sparse vertical split: ``shard_dims``' packing
+    cut to the width ``_cut_width`` gives, the entries past it listed per
+    shard in row-major order, rows padded to the block."""
+    from repro.core import distributed as dist
+
+    idx_s, val_s, nnz_s, m_loc = shard_dims(sp, p)
+    width, E = dist._cut_width(nnz_s.T)
+    grow = ((0, 0), (0, 0), (0, max(width - idx_s.shape[-1], 0)))
+    idx_s, val_s = np.pad(idx_s, grow), np.pad(val_s, grow)
+    slots = np.arange(idx_s.shape[-1])
+    past = (slots >= width) & (slots < nnz_s[..., None])
+    spill = [np.zeros((p, E), t) for t in (np.int32, np.int32, np.float32)]
+    for d in range(p):
+        r, c = np.nonzero(past[d])
+        spill[0][d, : r.size] = r
+        spill[1][d, : r.size] = idx_s[d, r, c]
+        spill[2][d, : r.size] = val_s[d, r, c]
+    rows = ((0, 0), (0, (-sp.n) % block_rows), (0, 0))
+    cut = [np.pad(a[..., :width], rows) for a in (idx_s, val_s)]
+    return cut, spill, nnz_s.sum(axis=1), m_loc
+
+
+@pytest.mark.parametrize("corpus", ["quoted200", "one_long_row", "adversarial"])
+def test_sparse_vertical_split_on_the_mesh_equals_host_packing(corpus, request):
+    """The split dealt, counted and packed on the devices equals the host
+    packing cut to the same width, bit for bit: stacks, spill lists, shard
+    counts. ``adversarial`` has duplicate coordinates, empty rows and rows
+    narrower than the width, which the pack widens."""
+    import jax
+
+    from repro.core import distributed as dist
+
+    if corpus == "adversarial":
+        sp = random_csr(5, 90, 37, 6, dup_prob=1.0)
+    else:
+        sp, _ = request.getfixturevalue(corpus)
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("model",))
+    idx_s, val_s, spill, shard_nnz, m_loc = dist._vertical_sparse_split(
+        sp, 64, mesh, "model"
+    )
+    (want_idx, want_val), want_spill, want_nnz, want_m_loc = (
+        _host_vertical_split(sp, 4, 64)
+    )
+    assert m_loc == want_m_loc and idx_s.shape[1] % 64 == 0
+    np.testing.assert_array_equal(shard_nnz, want_nnz)
+    np.testing.assert_array_equal(np.asarray(idx_s), want_idx)
+    np.testing.assert_array_equal(np.asarray(val_s), want_val)
+    for got, want in zip(spill, want_spill):
+        np.testing.assert_array_equal(np.asarray(got), want)
+    assert idx_s.sharding.spec[0] == "model"  # each chip holds its own shard

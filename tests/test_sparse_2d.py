@@ -18,7 +18,13 @@ from repro.core.apss import apss_reference, normalize_rows
 from repro.core.distributed import apss, apss_2d, apss_horizontal
 from repro.core.graph import match_set
 from repro.core.pruning import checkerboard_live_mask
-from repro.core.sparse import dim_slices, from_dense, shard_dims, to_dense
+from repro.core.sparse import (
+    deal_dims,
+    dim_slices,
+    from_dense,
+    shard_dims,
+    to_dense,
+)
 from repro.data.sparse import sparse_clustered_corpus
 
 T, K = 0.3, 16
@@ -86,10 +92,13 @@ def test_sparse_2d_overflow_reported():
 
 
 def test_sparse_2d_divisibility_errors():
-    """Host pre-split constraints fail loudly: m % r and n % q both raise."""
+    """Host pre-split constraints fail loudly: n % q raises. Any m splits
+    (the dealt dimension split pads each shard to ⌈m/r⌉ local ids), and
+    the join over an odd m stays exact."""
     mesh = make_mesh((4, 2), ("data", "model"))
-    with pytest.raises(ValueError, match="multiple"):
-        apss_2d(from_dense(_dense_corpus(64, 99, 0.2, seed=6)), T, K, mesh)
+    D = _dense_corpus(64, 99, 0.2, seed=6)
+    got = apss_2d(from_dense(D), T, K, mesh, block_rows=16)
+    _check(got, apss_reference(jnp.asarray(D), T, K))
     with pytest.raises(ValueError, match="multiple"):
         apss_2d(from_dense(_dense_corpus(66, 96, 0.2, seed=6)), T, K, mesh)
 
@@ -120,7 +129,11 @@ def test_checkerboard_live_mask_sound_and_prunes():
 def test_dim_slices_partition_is_lossless():
     sp = from_dense(_dense_corpus(32, 64, 0.3, seed=8))
     cells = dim_slices(sp, 4)
-    back = np.concatenate([np.asarray(to_dense(c)) for c in cells], axis=1)
+    owner, local = deal_dims(sp, 4)
+    back = np.zeros((32, 64), np.float32)
+    for d, c in enumerate(cells):
+        mine = np.nonzero(owner == d)[0]
+        back[:, mine] = np.asarray(to_dense(c))[:, local[mine]]
     np.testing.assert_allclose(back, np.asarray(to_dense(sp)), rtol=1e-6)
 
 
