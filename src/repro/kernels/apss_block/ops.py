@@ -174,6 +174,10 @@ def apss_fused(
 def _merge_packet(cv, ci, cc, blk, pv, pi, pc, k: int):
     """Fold one tile candidate packet into the per-row-block running top-k.
 
+    The early-exit fold (``serving.query._ee_fold``) merges packets one at
+    a time with it, to read the running k-th value as it goes; the folds
+    below give the same answer with every block at once.
+
     Packet ids are disjoint from the buffer's (each column block is visited
     once per row block; forward/backward packets for the same row block come
     from disjoint column ranges), so a plain top-k over the concat is exact.
@@ -260,72 +264,137 @@ def pad_worklist(wl: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return np.concatenate([wl, pad], axis=1), valid
 
 
-def fold_rect_packets(ij, tvalid, fv, fi, fc, *, grid_q, block_q, k):
-    """XLA scan folding rectangular forward packets into flat buffers.
+def fold_ranks(n_packets: int, per_block: int) -> int:
+    """Static bound on the packets one row block receives in a fold.
+
+    ``per_block`` is what the worklist's shape allows a block: the corpus
+    blocks a rect worklist can list once each (``grid_c``), or
+    ``grid_m + 1`` forward and mirror packets in an upper-triangular one.
+    The fold lays out ``grid · fold_ranks(...)`` slots (``fold_slots`` on
+    the ``query/worklist`` and ``apss/worklist`` spans).
+    """
+    return min(n_packets, per_block)
+
+
+def _fold_blocks(key, pv, pi, pc, *, grid: int, ranks: int, k: int):
+    """Merge packets into every row block's top-k at once. Exact.
+
+    ``key (P,)`` is each packet's row block (``grid`` drops the packet);
+    the packets ``pv, pi (P, block, k)``, ``pc (P, block)`` lie in merge
+    order. One stable argsort of the keys gives each packet its slot
+    ``(block, rank)``, rank counted in merge order; a gather lays out the
+    slots ``(grid, R, block, k)``, empty ones ``-∞`` with id −1, and one
+    stable ``lax.top_k`` over each row's ``R · k`` candidates keeps the k
+    best. That is the sequential merge's answer bit for bit — values, ids
+    and ties: a stable top-k of ``concat(buffer, packet)`` keeps the first
+    occurrence, and so does one top-k over every packet in merge order.
+    (One top-k over the whole row beat a two-stage one, a top-k within
+    chunks of packets and then over the chunk winners, on a v5e at both
+    benchmark shapes.)
+
+    ``ranks`` bounds the packets a block receives (:func:`fold_ranks`).
+    The slots of one pass are capped near the packets' own number,
+    ``R = min(ranks, ⌈P / grid⌉)``, so the slab never outgrows the packets
+    by more than one packet a block: ``grid · R · block · k · 8`` bytes,
+    at most ``grid · ranks · block · k · 8`` — the kernel's packets when
+    every tile is live. Where ``R < ranks`` (a worklist far sparser than
+    the grid), ``⌈max count / R⌉`` passes each merge R ranks, the running
+    top-k taken as rank −1 of the next pass.
+    """
+    P, block = pv.shape[0], pv.shape[1]
+    order = jnp.argsort(key, stable=True)
+    count = jnp.bincount(key, length=grid + 1)[:grid]
+    start = jnp.cumsum(count) - count
+    R = min(ranks, -(-P // grid))
+
+    def gather(r0):
+        rank = r0 + jnp.arange(R, dtype=jnp.int32)
+        live = rank[None, :] < count[:, None]                  # (grid, R)
+        src = order[jnp.minimum(start[:, None] + rank[None, :], P - 1)]
+        v = jnp.where(live[..., None, None], pv[src], -jnp.inf)
+        i = jnp.where(live[..., None, None], pi[src], -1)
+        c = jnp.sum(jnp.where(live[..., None], pc[src], 0), axis=1)
+        # (grid, R, block, k) → rows (grid, block) of R · k candidates
+        rows = lambda x: x.transpose(0, 2, 1, 3).reshape(grid, block, R * k)
+        return rows(v), rows(i), c
+
+    def merge(v, i):
+        v, sel = jax.lax.top_k(v, k)
+        return v, jnp.take_along_axis(i, sel, axis=-1)
+
+    if R == ranks:
+        v, i, cc = gather(0)
+        cv, ci = merge(v, i)
+    else:
+        def body(state):
+            r0, cv, ci, cc = state
+            v, i, c = gather(r0)
+            cv, ci = merge(
+                jnp.concatenate([cv, v], axis=-1),
+                jnp.concatenate([ci, i], axis=-1),
+            )
+            return r0 + R, cv, ci, cc + c
+
+        _, cv, ci, cc = jax.lax.while_loop(
+            lambda s: s[0] < jnp.max(count), body,
+            (
+                jnp.int32(0),
+                jnp.full((grid, block, k), -jnp.inf, jnp.float32),
+                jnp.full((grid, block, k), -1, jnp.int32),
+                jnp.zeros((grid, block), jnp.int32),
+            ),
+        )
+    ci = jnp.where(cv > _VALID, ci, -1)
+    values = jnp.where(ci >= 0, cv, NEG_INF).reshape(grid * block, k)
+    return values, ci.reshape(grid * block, k), cc.reshape(grid * block)
+
+
+def fold_rect_packets(ij, tvalid, fv, fi, fc, *, grid_q, grid_c, block_q, k):
+    """Fold rectangular forward packets into flat buffers, every query
+    block at once (:func:`_fold_blocks`).
 
     The serving twin of :func:`fold_packets`: forward packets only (no
     mirror — queries aren't corpus rows), plus a ``(T,)`` validity mask for
-    bucket padding (``pad_worklist``): invalid entries are neutralized
-    (values → −∞, ids → −1, counts → 0) BEFORE the merge so a padding
-    entry that aliases a real tile can never double-count.
+    bucket padding (``pad_worklist``): an invalid entry is keyed past the
+    last block, so a padding entry that aliases tile ``(0, 0)`` never
+    reaches a slot. A rect worklist lists each ``(query block, corpus
+    block)`` once, so a block receives at most ``min(T, grid_c)`` packets;
+    the slab is at most ``grid_q · grid_c · block_q · k · 8`` bytes.
     """
     with jax.named_scope("fold"):
-        dead = ~tvalid
-        fv = jnp.where(dead[:, None, None], NEG_INF, fv)
-        fi = jnp.where(dead[:, None, None], -1, fi)
-        fc = jnp.where(dead[:, None], 0, fc)
-
-        def step(carry, inp):
-            cv, ci, cc = carry
-            ib, fv_t, fi_t, fc_t = inp
-            cv, ci, cc = _merge_packet(cv, ci, cc, ib, fv_t, fi_t, fc_t, k)
-            return (cv, ci, cc), None
-
-        carry0 = (
-            jnp.full((grid_q, block_q, k), -jnp.inf, jnp.float32),
-            jnp.full((grid_q, block_q, k), -1, jnp.int32),
-            jnp.zeros((grid_q, block_q), jnp.int32),
+        key = jnp.where(tvalid, ij[0], grid_q).astype(jnp.int32)
+        return _fold_blocks(
+            key, fv, fi, fc, grid=grid_q,
+            ranks=fold_ranks(ij.shape[1], grid_c), k=k,
         )
-        (cv, ci, cc), _ = jax.lax.scan(step, carry0, (ij[0], fv, fi, fc))
-        values = jnp.where(ci >= 0, cv, NEG_INF).reshape(grid_q * block_q, k)
-        indices = ci.reshape(grid_q * block_q, k)
-        counts = cc.reshape(grid_q * block_q)
-        return values, indices, counts
 
 
 def fold_packets(ij, fv, fi, fc, bv, bi, bc, *, grid_m, block_m, k):
-    """XLA scan folding per-live-tile candidate packets into flat buffers.
+    """Fold per-live-tile candidate packets into flat buffers, every row
+    block at once (:func:`_fold_blocks`).
 
     ``ij (2, T)`` worklist of upper-triangular tile coordinates; ``f*`` are
     the forward packets (rows of block ``ij[0, t]``), ``b*`` the mirror
     packets (rows of block ``ij[1, t]``; empty on diagonal tiles). Counts
-    are ``(T, block_m)``. Exactness relies on the worklist contract: packet
-    ids entering one row block come from disjoint column ranges. Shared by
-    the dense (:func:`apss_fused_compacted`) and sparse
-    (``kernels.apss_block.sparse``) worklist paths.
+    are ``(T, block_m)``. Merge order is entry t's forward packet, then its
+    mirror. A row block receives at most ``grid_m + 1`` packets, so the
+    slab is at most ``grid_m · (grid_m + 1) · block_m · k · 8`` bytes.
+    Exactness relies on the worklist contract: packet ids entering one row
+    block come from disjoint column ranges. Shared by the dense
+    (:func:`apss_fused_compacted`) and sparse (``kernels.apss_block.sparse``)
+    worklist paths.
     """
     with jax.named_scope("fold"):
+        T = ij.shape[1]
 
-        def step(carry, inp):
-            cv, ci, cc = carry
-            ib, jb, fv_t, fi_t, fc_t, bv_t, bi_t, bc_t = inp
-            cv, ci, cc = _merge_packet(cv, ci, cc, ib, fv_t, fi_t, fc_t, k)
-            # Mirror packet (empty for diagonal tiles): rows of block jb.
-            cv, ci, cc = _merge_packet(cv, ci, cc, jb, bv_t, bi_t, bc_t, k)
-            return (cv, ci, cc), None
+        def interleave(f, b):
+            return jnp.stack([f, b], axis=1).reshape(2 * T, *f.shape[1:])
 
-        carry0 = (
-            jnp.full((grid_m, block_m, k), -jnp.inf, jnp.float32),
-            jnp.full((grid_m, block_m, k), -1, jnp.int32),
-            jnp.zeros((grid_m, block_m), jnp.int32),
+        return _fold_blocks(
+            interleave(ij[0], ij[1]).astype(jnp.int32),
+            interleave(fv, bv), interleave(fi, bi), interleave(fc, bc),
+            grid=grid_m, ranks=fold_ranks(2 * T, grid_m + 1), k=k,
         )
-        (cv, ci, cc), _ = jax.lax.scan(
-            step, carry0, (ij[0], ij[1], fv, fi, fc, bv, bi, bc)
-        )
-        values = jnp.where(ci >= 0, cv, NEG_INF).reshape(grid_m * block_m, k)
-        indices = ci.reshape(grid_m * block_m, k)
-        counts = cc.reshape(grid_m * block_m)
-        return values, indices, counts
 
 
 @functools.partial(

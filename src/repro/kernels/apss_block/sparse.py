@@ -56,7 +56,12 @@ from repro.kernels.apss_block.fused import (
     _tile_packets,
     _topk_sort,
 )
-from repro.kernels.apss_block.ops import _on_tpu, compact_worklist, fold_packets
+from repro.kernels.apss_block.ops import (
+    _on_tpu,
+    compact_worklist,
+    fold_packets,
+    fold_ranks,
+)
 from repro.obs import trace
 
 
@@ -459,9 +464,11 @@ def apss_sparse_compacted(
     with trace.span("apss/worklist"):
         wl = compact_worklist(mask, ub)
         live = 0 if wl is None else int(wl.shape[1])
-        # the worklist is not padded: every entry is a live tile
+        # the worklist is not padded: every entry is a live tile, and
+        # each gives the fold two packets (forward and mirror)
         trace.annotate(
-            live=live, total=grid_m * (grid_m + 1) // 2, entries=live
+            live=live, total=grid_m * (grid_m + 1) // 2, entries=live,
+            fold_slots=grid_m * fold_ranks(2 * live, grid_m + 1),
         )
         if wl is None:
             return empty_matches(n, k)

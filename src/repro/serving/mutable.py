@@ -248,7 +248,8 @@ def _mut_dense_inner(
 
     _, (fv, fi, fc) = lax.scan(tile, 0, jnp.arange(ij.shape[1]))
     return fold_rect_packets(
-        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q, block_q=block_q, k=k
+        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q,
+        grid_c=ncap // block_c, block_q=block_q, k=k,
     )
 
 
@@ -288,7 +289,8 @@ def _mut_sparse_inner(
 
     _, (fv, fi, fc) = lax.scan(tile, 0, jnp.arange(ij.shape[1]))
     return fold_rect_packets(
-        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q, block_q=block_q, k=k
+        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q,
+        grid_c=ncap // block_c, block_q=block_q, k=k,
     )
 
 
@@ -323,7 +325,8 @@ def _mut_sparse_self_inner(
 
     _, (fv, fi, fc) = lax.scan(tile, 0, jnp.arange(ij.shape[1]))
     return fold_rect_packets(
-        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q, block_q=block_c, k=k
+        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q,
+        grid_c=ncap // block_c, block_q=block_c, k=k,
     )
 
 

@@ -77,6 +77,7 @@ from repro.kernels.apss_block.ops import (
     _on_tpu,
     _pick_bk,
     compact_rect_worklist,
+    fold_ranks,
     fold_rect_packets,
     pad_worklist,
 )
@@ -214,9 +215,10 @@ def _query_topk_impl(
         live = 0 if wl is None else int(wl.shape[1])
         if wl is not None:
             ij_np, tv_np = pad_worklist(wl)
+        entries = 0 if wl is None else int(tv_np.shape[0])
         trace.annotate(
-            live=live, total=int(mk.size),
-            entries=0 if wl is None else int(tv_np.shape[0]),
+            live=live, total=int(mk.size), entries=entries,
+            fold_slots=grid_q * fold_ranks(entries, mk.shape[1]),
         )
     if telemetry.enabled() or metrics.enabled():
         depth = (
@@ -398,7 +400,8 @@ def _rect_dense_inner(
 
         _, (fv, fi, fc) = lax.scan(tile, 0, jnp.arange(ij.shape[1]))
     return fold_rect_packets(
-        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q, block_q=block_q, k=k
+        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q,
+        grid_c=C.shape[0] // block_c, block_q=block_q, k=k,
     )
 
 
@@ -454,7 +457,8 @@ def _rect_sparse_inner(
 
         _, (fv, fi, fc) = lax.scan(tile, 0, jnp.arange(ij.shape[1]))
     return fold_rect_packets(
-        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q, block_q=block_q, k=k
+        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q,
+        grid_c=bx.shape[0], block_q=block_q, k=k,
     )
 
 
@@ -466,10 +470,11 @@ def _rect_sparse_inner(
 def _ee_fold(score_tile, ij, tvalid, ub, nq_valid, *, grid_q, block_q, k):
     """Fused score+fold with early exit (the traced half of ``early_exit``).
 
-    Replays ``fold_rect_packets``'s exact merge (same ``_merge_packet``,
-    same worklist order) inside a ``lax.while_loop`` that carries the
-    running top-k buffers, and adds two sound skips derived from the
-    worklist's upper-bound-descending order:
+    Merges one packet at a time (``_merge_packet``, in worklist order),
+    which ``fold_rect_packets`` matches bit for bit with every block at
+    once, inside a ``lax.while_loop`` that carries the running top-k
+    buffers, and adds two sound skips derived from the worklist's
+    upper-bound-descending order:
 
     - tile skip — every live row of the tile's query block already holds
       k real values ≥ this tile's bound, so no candidate in it (value ≤
@@ -638,7 +643,8 @@ def _rect_dense_ee_kernel(
         nc_valid=nc_valid, nq_valid=nq_valid, interpret=interpret,
     )
     values, indices, counts = fold_rect_packets(
-        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q, block_q=block_q, k=k
+        ij, tvalid, fv, fi, fc[..., 0], grid_q=grid_q,
+        grid_c=C.shape[0] // block_c, block_q=block_q, k=k,
     )
     counts = jnp.minimum(counts, k)
     scored = jnp.sum(jnp.where(tvalid, 1 - sk, 0).astype(jnp.int32))
@@ -691,7 +697,10 @@ def _sharded_query_pruned(
         Tmax = max((int(w.shape[1]) for w in wls if w is not None), default=0)
         Tb = 1 << max(0, (Tmax - 1).bit_length())
         trace.annotate(
-            live=live, total=int(mk.size), entries=p * Tb if live else 0
+            live=live, total=int(mk.size), entries=p * Tb if live else 0,
+            fold_slots=(
+                p * grid_q * fold_ranks(Tb, index.nb_local) if live else 0
+            ),
         )
     if telemetry.enabled() or metrics.enabled():
         depth = (
@@ -797,7 +806,7 @@ def _sharded_query(
             _, (fv, fi, fc) = lax.scan(tile, 0, jnp.arange(ij_l.shape[1]))
         v, i, c = fold_rect_packets(
             ij_l, tv, fv, fi, fc[..., 0],
-            grid_q=grid_q, block_q=block_q, k=k,
+            grid_q=grid_q, grid_c=nb_loc, block_q=block_q, k=k,
         )
         return Matches(v[None], i[None], c[None])
 
@@ -822,7 +831,7 @@ def _sharded_query(
         _, (fv, fi, fc) = lax.scan(tile, 0, jnp.arange(ij_l.shape[1]))
         v, i, c = fold_rect_packets(
             ij_l, tv, fv, fi, fc[..., 0],
-            grid_q=grid_q, block_q=block_q, k=k,
+            grid_q=grid_q, grid_c=nb_loc, block_q=block_q, k=k,
         )
         return Matches(v[None], i[None], c[None])
 

@@ -140,6 +140,7 @@ def test_profiler_session_records_program_spans_with_counts(
     nb = -(-300 // 256)
     assert by_name["apss/worklist"][0][4] == {
         "live": wl.shape[1], "total": nb * (nb + 1) // 2, "entries": wl.shape[1],
+        "fold_slots": nb * ops.fold_ranks(2 * wl.shape[1], nb + 1),
     }
     support = by_name["apss/support_gather"][0][4]
     assert support["blocks"] == nb and support["block_rows"] == 256
@@ -151,6 +152,9 @@ def test_profiler_session_records_program_spans_with_counts(
     assert stats["live"] == rwl.shape[1] == valid.sum()
     assert stats["entries"] == valid.size and stats["batch"] == Q.shape[0]
     assert stats["total"] == (-(-Q.shape[0] // 16)) * (-(-index.n // 64))
+    assert stats["fold_slots"] == (-(-Q.shape[0] // 16)) * ops.fold_ranks(
+        valid.size, -(-index.n // 64)
+    )
 
 
 def test_support_gather_span_names_its_lookup(tmp_path):
