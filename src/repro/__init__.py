@@ -1,24 +1,28 @@
-"""repro: a multi-pod JAX framework for the All-Pairs Similarity Problem.
+"""repro: an exact all-pairs-similarity (APSS) engine for TPU, in JAX and
+Pallas.
 
 Reproduction (and TPU-native extension) of:
     Özkural & Aykanat, "1-D and 2-D Parallel Algorithms for All-Pairs
     Similarity Problem" (CS.IR 2014).
 
+It runs batch self-joins over a corpus, top-k retrieval against a built
+index, and keeps a corpus and its top-k graph current under appends and
+deletes.
+
 Subpackages:
 
 - :mod:`repro.core`        — the paper's contribution (APSS + distributions)
-- :mod:`repro.kernels`     — Pallas TPU kernels (apss_block, flash_attention,
-                             decode_attention)
-- :mod:`repro.models`      — transformer / recsys / GNN model zoo
-- :mod:`repro.data`        — data pipeline (synthetic corpora, LM batches,
-                             APSS dedup)
-- :mod:`repro.optim`       — optimizers, schedules, gradient compression
-- :mod:`repro.checkpoint`  — sharded checkpointing + fault tolerance
-- :mod:`repro.distributed` — mesh/collective helpers, elastic re-mesh
-- :mod:`repro.configs`     — assigned architecture configs
-- :mod:`repro.launch`      — mesh, dry-run, train and serve entry points
-- :mod:`repro.serving`     — build-once APSS index + batched query-time
-                             top-k retrieval server
+- :mod:`repro.kernels`     — Pallas TPU kernels (apss_block)
+- :mod:`repro.serving`     — build-once APSS index, batched query-time
+                             top-k retrieval servers, mutable index
+- :mod:`repro.planner`     — cost models, calibration, variant planner
+- :mod:`repro.data`        — synthetic dense and sparse corpora, APSS dedup
+- :mod:`repro.obs`         — spans, metrics, compile and HLO audits
+- :mod:`repro.robust`      — fault injection and resilient sweeps
+- :mod:`repro.checkpoint`  — checkpointing (mutable index, sweeps)
+- :mod:`repro.distributed` — elastic re-mesh, straggler tracking
+- :mod:`repro.configs`     — the paper-scale APSS cells for the dry run
+- :mod:`repro.launch`      — mesh, dry-run and serve entry points
 
 NOTE: this module is import-side-effect free (no jax import at package
 import time) so that ``launch/dryrun.py`` can set

@@ -1,5 +1,5 @@
-"""Architecture registry: importing this package registers every assigned
-architecture (+ the paper's own APSS workload) into ``configs.base.REGISTRY``.
+"""Config registry: importing this package registers the paper's own APSS
+workload (``configs/apss_paper.py``) into ``configs.base.REGISTRY``.
 """
 
 from repro.configs.base import (  # noqa: F401
@@ -7,34 +7,6 @@ from repro.configs.base import (  # noqa: F401
     ArchDef,
     CellBuild,
     ShapeCell,
-    all_arch_names,
     get_arch,
 )
-
-# Assigned architectures (10) — importing registers them.
-from repro.configs import (  # noqa: F401
-    qwen3_1_7b,
-    minicpm3_4b,
-    qwen3_8b,
-    arctic_480b,
-    deepseek_moe_16b,
-    gat_cora,
-    two_tower_retrieval,
-    bert4rec,
-    din,
-    bst,
-    apss_paper,
-)
-
-ASSIGNED = [
-    "qwen3-1.7b",
-    "minicpm3-4b",
-    "qwen3-8b",
-    "arctic-480b",
-    "deepseek-moe-16b",
-    "gat-cora",
-    "two-tower-retrieval",
-    "bert4rec",
-    "din",
-    "bst",
-]
+from repro.configs import apss_paper  # noqa: F401

@@ -45,10 +45,6 @@ def config() -> dict:
     }
 
 
-def smoke_config() -> dict:
-    return {"synthetic": dict(n=256, m=192, t=0.35)}
-
-
 def _row_axes(mesh):
     return tuple(a for a in ("pod", "data", "model") if a in mesh.shape)
 
@@ -157,10 +153,8 @@ def _run_2d(D, *, mesh, t, k, block_rows=512):
 
 ARCH = register(ArchDef(
     name="apss",
-    family="apss",
     source="this paper (Özkural & Aykanat 2014)",
     make_config=config,
-    make_smoke_config=smoke_config,
     shapes={
         "h_allgather": ShapeCell(
             kind="apss", desc="1-D horizontal Alg.6 (paper-faithful), "

@@ -1,10 +1,10 @@
-"""Config/arch registry plumbing: every assigned architecture registers an
-``ArchDef`` whose shape cells build ``(fn, args, in_shardings, out_shardings)``
-lowering specs for the dry-run (DESIGN.md §e).
+"""Config registry plumbing: the APSS workload registers an ``ArchDef``
+whose shape cells build ``(fn, args, in_shardings, out_shardings)``
+lowering specs for the dry-run (DESIGN.md §4).
 
 Builders return abstract ``jax.ShapeDtypeStruct`` argument trees — no host
 allocation ever happens for the full configs (they are exercised ONLY via
-``launch/dryrun.py``); smoke tests use each arch's ``smoke_config``.
+``launch/dryrun.py``).
 """
 
 from __future__ import annotations
@@ -37,17 +37,8 @@ def shardings_for(mesh: Mesh, specs):
     )
 
 
-def data_axes_of(mesh: Mesh) -> tuple:
-    return tuple(a for a in ("pod", "data") if a in mesh.shape)
-
-
 def sds(shape, dtype) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
-
-
-def sds_like(tree):
-    """eval_shape result → plain ShapeDtypeStruct pytree."""
-    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,7 +54,7 @@ class CellBuild:
 
 @dataclasses.dataclass(frozen=True)
 class ShapeCell:
-    kind: str                   # train | prefill | decode | serve | retrieval
+    kind: str                   # apss
     desc: str
     build: Callable[[Any, Mesh], CellBuild]   # (full_config, mesh) -> CellBuild
 
@@ -71,10 +62,8 @@ class ShapeCell:
 @dataclasses.dataclass(frozen=True)
 class ArchDef:
     name: str
-    family: str                 # lm | gnn | recsys
     source: str                 # public-literature citation tag
     make_config: Callable[[], Any]
-    make_smoke_config: Callable[[], Any]
     shapes: dict
 
     def cell(self, shape: str) -> ShapeCell:
@@ -94,9 +83,3 @@ def get_arch(name: str) -> ArchDef:
         # Import side effects populate the registry lazily.
         import repro.configs  # noqa: F401
     return REGISTRY[name]
-
-
-def all_arch_names() -> list:
-    import repro.configs  # noqa: F401
-
-    return sorted(REGISTRY)

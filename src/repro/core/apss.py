@@ -323,8 +323,3 @@ def _apss_blocked_sparse(
         if not with_prune_stats:
             return m
         return m, prune_stats(mask)
-
-
-@functools.partial(jax.jit, static_argnames=("threshold", "k", "block_rows"))
-def apss_blocked_jit(D, threshold: float, k: int = 32, block_rows: int = 512):
-    return apss_blocked(D, threshold, k, block_rows=block_rows)

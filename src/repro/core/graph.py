@@ -3,8 +3,7 @@
 The paper positions APSS output as an undirected similarity graph
 ``G_S(V, t) = (V, M)`` consumed by transduction / clustering / k-nn algorithms.
 These helpers convert the fixed-capacity :class:`Matches` representation into
-COO edge lists (host-side numpy — graph consumers are host programs) and into
-the padded edge arrays our GNN models take as input.
+COO edge lists (host-side numpy — graph consumers are host programs).
 """
 
 from __future__ import annotations
@@ -31,39 +30,6 @@ def matches_to_coo(
     if undirected:
         keep &= rows < cols
     return rows[keep], cols[keep], w[keep].astype(np.float32)
-
-
-def coo_to_padded_edges(
-    rows: np.ndarray,
-    cols: np.ndarray,
-    weights: np.ndarray,
-    max_edges: int,
-    *,
-    add_reverse: bool = True,
-    add_self_loops_n: int | None = None,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Pad a COO edge list to a static size for jit'd GNN consumption.
-
-    Returns ``(src, dst, weight, edge_mask)`` each of length ``max_edges``.
-    Padding edges point at node 0 with mask 0 (GNN segment ops weight them 0).
-    """
-    if add_reverse:
-        rows, cols = np.concatenate([rows, cols]), np.concatenate([cols, rows])
-        weights = np.concatenate([weights, weights])
-    if add_self_loops_n is not None:
-        loop = np.arange(add_self_loops_n, dtype=rows.dtype)
-        rows = np.concatenate([rows, loop])
-        cols = np.concatenate([cols, loop])
-        weights = np.concatenate([weights, np.ones_like(loop, dtype=weights.dtype)])
-    e = len(rows)
-    if e > max_edges:
-        raise ValueError(f"{e} edges exceed static capacity {max_edges}")
-    pad = max_edges - e
-    src = np.pad(rows.astype(np.int32), (0, pad))
-    dst = np.pad(cols.astype(np.int32), (0, pad))
-    w = np.pad(weights.astype(np.float32), (0, pad))
-    mask = np.pad(np.ones(e, np.float32), (0, pad))
-    return src, dst, w, mask
 
 
 def match_set(m: Matches) -> set[tuple[int, int]]:

@@ -8,10 +8,4 @@ from repro.data.sparse import (  # noqa: F401
     sparse_clustered_corpus,
     sparse_zipfian_corpus,
 )
-from repro.data.pipeline import (  # noqa: F401
-    LMDataPipeline,
-    RecsysPipeline,
-    GraphPipeline,
-)
-from repro.data.sampler import neighbor_sample  # noqa: F401
 from repro.data.dedup import dedup_corpus  # noqa: F401

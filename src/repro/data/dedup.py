@@ -1,10 +1,10 @@
-"""Near-duplicate detection for the data pipeline — the paper's own
+"""Near-duplicate detection — the paper's own
 application ("near-duplicate detection by using a high threshold").
 
 Documents (as normalized feature vectors) are self-joined with the APSS
 core at a high threshold; of every duplicate cluster only the lowest-index
-row survives. Plugged in front of LM training data to scrub duplicated
-crawl content — APSS as a first-class pipeline stage.
+row survives: a crawl is scrubbed of duplicated content with APSS as a
+pipeline stage.
 """
 
 from __future__ import annotations

@@ -1,13 +1,4 @@
-"""Distributed runtime helpers: sharding context, elastic re-mesh,
-straggler tracking."""
+"""Distributed runtime helpers: elastic re-mesh, straggler tracking."""
 
-from repro.distributed.sharding import (  # noqa: F401
-    active_mesh,
-    use_mesh,
-    shard,
-    shard_params,
-    data_axes,
-    model_axis,
-)
 from repro.distributed.elastic import reshard_tree, ElasticPlan  # noqa: F401
 from repro.distributed.straggler import StepTimer, StragglerReport  # noqa: F401

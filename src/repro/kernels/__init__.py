@@ -1,28 +1,16 @@
-"""Pallas TPU kernels for the performance-critical compute layers.
+"""Pallas TPU kernels for the APSS engine.
 
-Kernels (each subpackage has ``<name>.py`` with the ``pl.pallas_call`` +
-BlockSpec implementation, ``ops.py`` with the jit'd public wrapper, and
-``ref.py`` with the pure-jnp oracle):
+:mod:`repro.kernels.apss_block` is the paper's kernel family: blocked
+``X·Yᵀ`` tiles with threshold filtering and tile skipping driven by the
+maxweight block-bound mask (partial-indexing/minsize pruning at MXU tile
+granularity). ``apss_block.py`` is the seed dense-output kernel,
+``fused.py`` the streaming match-extraction kernels, ``sparse.py`` the CSR
+tile kernels, ``ops.py`` the jit'd public wrappers and ``ref.py`` the
+pure-jnp oracle.
 
-- :mod:`repro.kernels.apss_block` — the paper's kernel: fused blocked
-  ``X·Yᵀ`` with threshold filtering and ``@pl.when`` tile skipping driven by
-  the maxweight block-bound mask (partial-indexing/minsize pruning at MXU
-  tile granularity).
-- :mod:`repro.kernels.flash_attention` — causal GQA flash attention
-  (prefill path of the LM architectures).
-- :mod:`repro.kernels.decode_attention` — flash-decode partials
-  (local max / sum-exp / weighted accumulator) for sequence-sharded KV
-  caches; the cross-device combine mirrors the paper's vertical partial-score
-  accumulation with the softmax monoid instead of (+).
-
-The container is CPU-only, so kernels are *validated* with
-``interpret=True`` (Python/CPU execution of the kernel body) and *targeted*
-at TPU v5e (MXU-aligned 128-multiple tiles, VMEM-sized working sets).
+On the CPU the kernels are validated with ``interpret=True`` (Python/CPU
+execution of the kernel body); on a TPU v5e they compile with Mosaic
+(MXU-aligned 128-multiple tiles, VMEM-sized working sets).
 """
 
 from repro.kernels.apss_block.ops import apss_block_matmul  # noqa: F401
-from repro.kernels.flash_attention.ops import flash_attention  # noqa: F401
-from repro.kernels.decode_attention.ops import (  # noqa: F401
-    decode_attention,
-    combine_partials,
-)

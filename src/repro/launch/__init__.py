@@ -1,1 +1,1 @@
-"""Launch layer: production mesh, dry-run driver, train/serve entry points."""
+"""Launch layer: production mesh, dry-run driver, serve entry point."""

@@ -2,8 +2,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 os.environ["JAX_PLATFORMS"] = "cpu"  # placeholder devices; never the chip
 
-"""Multi-pod dry-run: lower + compile every (arch × shape) on the production
-mesh and extract the roofline terms from the compiled artifact.
+"""Multi-pod dry-run: lower + compile every APSS cell (``configs/apss_paper.py``)
+on the production mesh and extract the roofline terms from the compiled
+artifact.
 
 MUST be executed as its own process (``python -m repro.launch.dryrun``): the
 two environment lines above run before any other import so jax initializes
@@ -22,8 +23,8 @@ Per cell it records (JSON under --out):
 
 Usage:
   python -m repro.launch.dryrun --list
-  python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k
-  python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k --multi-pod
+  python -m repro.launch.dryrun --arch apss --shape grid_2d
+  python -m repro.launch.dryrun --arch apss --shape grid_2d --multi-pod
   python -m repro.launch.dryrun --all [--multi-pod] [--subprocess]
 """
 
@@ -190,7 +191,6 @@ def run_cell(
 ) -> dict:
     import jax
     from repro.configs import get_arch
-    from repro.distributed.sharding import use_mesh
     from repro.launch.mesh import make_production_mesh
 
     arch = get_arch(arch_name)
@@ -201,17 +201,16 @@ def run_cell(
     cfg = apply_overrides(arch.make_config(), list(overrides))
     t0 = time.time()
     build = cell.build(cfg, mesh)
-    with use_mesh(mesh):
-        jitted = jax.jit(
-            build.fn,
-            in_shardings=build.in_shardings,
-            out_shardings=build.out_shardings,
-        )
-        lowered = jitted.lower(*build.args)
-        t_lower = time.time() - t0
-        t0 = time.time()
-        compiled = lowered.compile()
-        t_compile = time.time() - t0
+    jitted = jax.jit(
+        build.fn,
+        in_shardings=build.in_shardings,
+        out_shardings=build.out_shardings,
+    )
+    lowered = jitted.lower(*build.args)
+    t_lower = time.time() - t0
+    t0 = time.time()
+    compiled = lowered.compile()
+    t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
@@ -273,15 +272,10 @@ def run_cell(
     return result
 
 
-def cell_list(arch_names=None) -> list:
-    from repro.configs import ASSIGNED, get_arch
+def cell_list() -> list:
+    from repro.configs import get_arch
 
-    names = arch_names or (ASSIGNED + ["apss"])
-    cells = []
-    for a in names:
-        for s in get_arch(a).shapes:
-            cells.append((a, s))
-    return cells
+    return [("apss", s) for s in get_arch("apss").shapes]
 
 
 def main() -> None:

@@ -2,7 +2,7 @@
 
 XLA's ``compiled.cost_analysis()`` counts ``while`` bodies ONCE (verified
 empirically: a scan of 10 matmuls reports the flops of 1), which silently
-underestimates every scanned-layer transformer and every ring-step loop by
+underestimates every scanned block sweep and every ring-step loop by
 the trip count. This analyzer re-derives per-device costs from the HLO text
 with loop multipliers:
 

@@ -3,7 +3,7 @@
 Defined as a FUNCTION (not module-level state) so importing this module never
 touches jax device initialization — critical because the dry-run must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init,
-while smoke tests must see the 1 real device.
+while tests must see their own devices.
 
 All mesh construction routes through ``repro.compat.make_mesh`` so the
 ``axis_types`` / ``AxisType`` API drift is absorbed in one place.
@@ -17,14 +17,9 @@ from repro.compat import make_mesh
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips) mesh.
 
-    Axes: ``pod`` (DCN between pods), ``data`` (DP / batch / APSS rows),
-    ``model`` (TP / EP / APSS dims).
+    Axes: ``pod`` (DCN between pods), ``data`` (APSS rows), ``model``
+    (APSS dimensions).
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for multi-device unit tests (8 forced host devices)."""
     return make_mesh(shape, axes)

@@ -1,10 +1,8 @@
-"""Serving entry point: batched request loops with per-request latching.
+"""Serving entry point: the APSS retrieval server and the execution planner.
 
-Three modes:
+Two modes:
 
-- ``--mode lm`` (default): continuous-batch LM decode over the transformer
-  stack (:class:`LMServer`).
-- ``--mode retrieval``: the APSS serving path — build a
+- ``--mode retrieval`` (default): the APSS serving path — build a
   :class:`~repro.serving.index.APSSIndex` ONCE over a synthetic sparse
   corpus, then stream query batches through a
   :class:`~repro.serving.server.RetrievalServer` (one jit'd ``query_topk``
@@ -16,8 +14,7 @@ Three modes:
   it and report predicted vs measured.
 
 CPU-scale demos (reduced configs):
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --requests 4
-    PYTHONPATH=src python -m repro.launch.serve --mode retrieval \\
+    PYTHONPATH=src python -m repro.launch.serve \\
         --corpus-n 4096 --corpus-m 2048 --requests 64 --batch 8
     PYTHONPATH=src python -m repro.launch.serve --mode auto \\
         --corpus-n 2048 --corpus-m 8192 --threshold 0.5
@@ -30,63 +27,8 @@ import contextlib
 import time
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.cache import enable_compile_cache
-from repro.models.transformer import (
-    decode_step,
-    init_transformer,
-    make_cache,
-)
-
-
-class LMServer:
-    """Minimal batched LM server: continuous batch of decode slots.
-
-    Requests join the running batch at the next step boundary; finished
-    slots are recycled. Decode is one jit'd step for the whole batch —
-    the production pattern behind the decode_32k / long_500k shapes.
-    """
-
-    def __init__(self, cfg, *, max_batch: int = 8, max_len: int = 256, seed: int = 0):
-        self.cfg = cfg
-        self.params = init_transformer(jax.random.key(seed), cfg)
-        self.max_batch = max_batch
-        self.max_len = max_len
-        self.cache = make_cache(cfg, max_batch, max_len)
-        self.active = np.zeros(max_batch, bool)
-        self.outputs: list = [[] for _ in range(max_batch)]
-        self._decode = jax.jit(
-            lambda p, c, t: decode_step(p, cfg, c, t)
-        )
-
-    def add_request(self, prompt_tokens: np.ndarray) -> int:
-        slot = int(np.argmin(self.active))
-        assert not self.active[slot], "server full"
-        self.active[slot] = True
-        self.outputs[slot] = []
-        # feed the prompt through decode steps (simple; a production server
-        # would run a batched prefill into the cache region)
-        for tok in prompt_tokens:
-            self.step_token(slot, int(tok))
-        return slot
-
-    def step_token(self, slot: int, token: int) -> int:
-        tokens = np.zeros(self.max_batch, np.int32)
-        tokens[slot] = token
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(tokens)
-        )
-        nxt = int(jnp.argmax(logits[slot]))
-        self.outputs[slot].append(nxt)
-        return nxt
-
-    def generate(self, slot: int, n: int) -> list:
-        tok = self.outputs[slot][-1]
-        for _ in range(n):
-            tok = self.step_token(slot, tok)
-        return self.outputs[slot][-n:]
 
 
 def run_retrieval(args) -> None:
@@ -221,10 +163,9 @@ def run_auto(args) -> None:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=["lm", "retrieval", "auto"], default="lm")
-    ap.add_argument("--arch", default="qwen3-1.7b")
-    ap.add_argument("--requests", type=int, default=2)
-    ap.add_argument("--gen-tokens", type=int, default=8)
+    ap.add_argument("--mode", choices=["retrieval", "auto"], default="retrieval")
+    ap.add_argument("--requests", type=int, default=64,
+                    help="retrieval mode: number of queries served")
     ap.add_argument("--corpus-n", type=int, default=4096)
     ap.add_argument("--corpus-m", type=int, default=2048)
     ap.add_argument("--avg-nnz", type=float, default=16.0)
@@ -278,29 +219,10 @@ def main() -> None:
 
 
 def _run_mode(args) -> None:
-    if args.mode == "retrieval":
-        run_retrieval(args)
-        return
     if args.mode == "auto":
         run_auto(args)
-        return
-
-    from repro.configs import get_arch
-
-    arch = get_arch(args.arch)
-    if arch.family != "lm":
-        raise SystemExit("serve demo supports LM archs; use examples/ for recsys")
-    cfg = arch.make_smoke_config()
-    srv = LMServer(cfg, max_batch=max(2, args.requests))
-    rng = np.random.default_rng(0)
-    t0 = time.time()
-    for r in range(args.requests):
-        slot = srv.add_request(rng.integers(0, cfg.vocab_size, size=4))
-        out = srv.generate(slot, args.gen_tokens)
-        print(f"[serve] request {r} slot {slot} → {out}")
-    dt = time.time() - t0
-    total = args.requests * (args.gen_tokens + 4)
-    print(f"[serve] {total} tokens in {dt:.2f}s ({total/dt:.1f} tok/s)")
+    else:
+        run_retrieval(args)
 
 
 if __name__ == "__main__":

@@ -1,6 +1,6 @@
 """RetrievalServer: batched query-time APSS over a build-once index.
 
-Modeled on ``launch.serve.LMServer``'s slot/latch pattern: requests join a
+A slot/latch pattern, as in continuous-batch decode servers: requests join a
 padded query batch at the next ``step()`` boundary, ONE jit'd
 :func:`~repro.serving.query.query_topk` call serves the whole batch (the
 batch is always padded to ``max_batch`` rows, so the compiled executable is
@@ -466,8 +466,8 @@ class ContinuousRetrievalServer(RetrievalServer):
     keeps the whole request lifecycle — admission control, deadlines,
     version-keyed LRU, the kernel→XLA→stale degradation ladder — and
     replaces only the latch: ``workers`` background threads pull up to
-    ``max_batch`` requests the moment any are pending (LMServer's
-    slot-recycling idea applied to retrieval batches), so
+    ``max_batch`` requests the moment any are pending (slot recycling
+    applied to retrieval batches), so
 
     - a request's service time starts at SUBMIT, not at the next step
       boundary, and

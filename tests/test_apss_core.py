@@ -8,12 +8,13 @@ from repro.core.apss import (
     apss_blocked,
     apss_reference,
     normalize_rows,
+    pad_rows,
     similarity_topk,
 )
 from repro.core.graph import match_set, matches_to_coo
 from repro.core.matches import (
-    Matches,
     dedupe_candidates,
+    empty_matches,
     extract_matches,
     matches_from_candidates,
     merge_matches,
@@ -41,7 +42,7 @@ def test_reference_matches_bruteforce(corpus):
     assert (w >= T).all()
 
 
-@pytest.mark.parametrize("block_rows", [16, 32, 128])
+@pytest.mark.parametrize("block_rows", [16, 32, 128, 24, 48, 100])
 def test_blocked_equals_reference(corpus, block_rows):
     ref = apss_reference(jnp.asarray(corpus), T, K)
     blk = apss_blocked(jnp.asarray(corpus), T, K, block_rows=block_rows)
@@ -88,6 +89,34 @@ def test_extract_matches_capacity_overflow_visible():
     m = extract_matches(S, 0.5, 3, exclude_self=False)
     assert (np.asarray(m.counts) == 10).all()
     assert bool(m.overflowed().all())
+
+
+@pytest.mark.parametrize("shape,multiple", [
+    ((10, 3), 4), ((8, 3), 4), ((5,), 8), ((3, 2, 2), 1),
+])
+def test_pad_rows_zero_pads_axis_0_to_a_multiple(shape, multiple):
+    x = jnp.arange(1, 1 + np.prod(shape), dtype=jnp.float32).reshape(shape)
+    got, n = pad_rows(x, multiple)
+    assert n == shape[0]
+    assert got.shape[0] % multiple == 0 and got.shape[0] - n < multiple
+    assert got.shape[1:] == shape[1:]
+    np.testing.assert_array_equal(got[:n], x)
+    assert (np.asarray(got[n:]) == 0).all()
+
+
+@pytest.mark.parametrize("rows,k", [(4, 3), (2, 8)])
+def test_empty_matches_is_the_merge_identity(rows, k):
+    e = empty_matches(rows, k)
+    assert e.values.shape == e.indices.shape == (rows, k)
+    assert (np.asarray(e.values) == -np.inf).all()
+    assert (np.asarray(e.indices) == -1).all() and int(e.counts.sum()) == 0
+    rng = np.random.default_rng(rows)
+    S = jnp.asarray(rng.random((rows, 12)), jnp.float32)
+    m = extract_matches(S, 0.4, k, col_offset=5, exclude_self=False)
+    for merged in (merge_matches(e, m), merge_matches(m, e)):
+        np.testing.assert_array_equal(merged.values, m.values)
+        np.testing.assert_array_equal(merged.indices, m.indices)
+        np.testing.assert_array_equal(merged.counts, m.counts)
 
 
 def test_merge_matches_disjoint_columns():

@@ -1,4 +1,4 @@
-"""Every (arch × shape) cell must BUILD (abstract shapes + shardings) on a
+"""Every APSS dry-run cell must BUILD (abstract shapes + shardings) on a
 mesh — catches config/spec regressions in seconds without the 512-device
 compile (which lives in launch/dryrun.py)."""
 
@@ -6,19 +6,16 @@ import jax
 import pytest
 
 from repro.compat import make_mesh
-from repro.configs import ASSIGNED, get_arch
-from repro.distributed.sharding import use_mesh
+from repro.configs import get_arch
 
 
 @pytest.fixture(scope="module")
 def small_mesh():
-    # axis names match production; sizes divide all assigned shapes
+    # axis names match production; sizes divide every cell's shapes
     return make_mesh((2, 2), ("data", "model"))
 
 
-ALL_CELLS = [
-    (a, s) for a in ASSIGNED + ["apss"] for s in get_arch(a).shapes
-]
+ALL_CELLS = [("apss", s) for s in get_arch("apss").shapes]
 
 
 @pytest.mark.parametrize("arch_name,shape_name", ALL_CELLS)
@@ -26,8 +23,7 @@ def test_cell_builds(arch_name, shape_name, small_mesh):
     arch = get_arch(arch_name)
     cell = arch.cell(shape_name)
     cfg = arch.make_config()
-    with use_mesh(small_mesh):
-        build = cell.build(cfg, small_mesh)
+    build = cell.build(cfg, small_mesh)
     # args are abstract (no allocation), shardings present, fn callable
     assert callable(build.fn)
     leaves = jax.tree.leaves(build.args)

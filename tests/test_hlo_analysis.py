@@ -6,11 +6,8 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 

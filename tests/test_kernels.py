@@ -7,17 +7,6 @@ import pytest
 from repro.core.apss import normalize_rows
 from repro.kernels.apss_block.ops import apss_block_matmul
 from repro.kernels.apss_block.ref import apss_block_reference
-from repro.kernels.decode_attention.ops import (
-    combine_partials,
-    decode_attention,
-    decode_attention_partials,
-)
-from repro.kernels.decode_attention.ref import (
-    combine_partials_reference,
-    decode_attention_reference,
-)
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_reference
 
 RNG = np.random.default_rng(42)
 
@@ -85,93 +74,3 @@ def test_apss_block_auto_mask_exact(corpus):
     a = apss_block_matmul(Xp, Xp, 0.4, auto_mask=True, **blocks)
     b = apss_block_matmul(Xp, Xp, 0.4, auto_mask=False, **blocks)
     np.testing.assert_allclose(a, b, atol=1e-5)
-
-
-# -- flash_attention ----------------------------------------------------------
-
-
-@pytest.mark.parametrize(
-    "B,Hq,Hkv,S,D",
-    [
-        (2, 4, 2, 256, 64),
-        (1, 8, 8, 256, 128),   # MHA
-        (2, 16, 4, 128, 64),   # GQA 4:1
-        (1, 4, 1, 512, 64),    # MQA
-        (1, 2, 2, 300, 32),    # ragged seq → causal padding
-    ],
-)
-def test_flash_attention_shapes(B, Hq, Hkv, S, D):
-    q = jnp.asarray(RNG.standard_normal((B, Hq, S, D)), jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((B, Hkv, S, D)), jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((B, Hkv, S, D)), jnp.float32)
-    got = flash_attention(q, k, v, block_q=128, block_k=128)
-    want = attention_reference(q, k, v)
-    np.testing.assert_allclose(got, want, atol=2e-5)
-
-
-@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)])
-def test_flash_attention_dtypes(dtype, atol):
-    q = jnp.asarray(RNG.standard_normal((1, 4, 128, 64)), dtype)
-    k = jnp.asarray(RNG.standard_normal((1, 2, 128, 64)), dtype)
-    v = jnp.asarray(RNG.standard_normal((1, 2, 128, 64)), dtype)
-    got = flash_attention(q, k, v, block_q=128, block_k=128)
-    want = attention_reference(
-        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32)
-    )
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want), atol=atol
-    )
-
-
-def test_flash_attention_noncausal():
-    q = jnp.asarray(RNG.standard_normal((1, 2, 256, 32)), jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((1, 2, 256, 32)), jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((1, 2, 256, 32)), jnp.float32)
-    got = flash_attention(q, k, v, causal=False, block_q=128, block_k=128)
-    want = attention_reference(q, k, v, causal=False)
-    np.testing.assert_allclose(got, want, atol=2e-5)
-
-
-# -- decode_attention -----------------------------------------------------------
-
-
-@pytest.mark.parametrize(
-    "B,Hq,Hkv,L,D",
-    [(2, 4, 2, 512, 64), (3, 8, 8, 1000, 128), (1, 8, 1, 256, 64)],
-)
-def test_decode_attention_shapes(B, Hq, Hkv, L, D):
-    q = jnp.asarray(RNG.standard_normal((B, Hq, D)), jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((B, Hkv, L, D)), jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((B, Hkv, L, D)), jnp.float32)
-    lens = jnp.asarray(RNG.integers(1, L + 1, size=(B,)), jnp.int32)
-    got = decode_attention(q, k, v, lens, block_k=256)
-    want = decode_attention_reference(q, k, v, lens)
-    np.testing.assert_allclose(got, want, atol=2e-5)
-
-
-@pytest.mark.parametrize("P", [2, 4, 8])
-def test_decode_sharded_combine_exact(P):
-    """Sequence-sharded partials combine to the monolithic answer — the
-    long_500k decode path's correctness core."""
-    B, Hq, Hkv, L, D = 2, 8, 4, 1024, 64
-    q = jnp.asarray(RNG.standard_normal((B, Hq, D)), jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((B, Hkv, L, D)), jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((B, Hkv, L, D)), jnp.float32)
-    lens = np.asarray([L, L // 3], np.int32)
-    accs, ms, ls = [], [], []
-    shard = L // P
-    for s in range(P):
-        loc_len = np.clip(lens - s * shard, 0, shard).astype(np.int32)
-        a, m, l = decode_attention_partials(
-            q, k[:, :, s * shard:(s + 1) * shard],
-            v[:, :, s * shard:(s + 1) * shard],
-            jnp.asarray(loc_len), block_k=128,
-        )
-        accs.append(a), ms.append(m), ls.append(l)
-    got = combine_partials(jnp.stack(accs), jnp.stack(ms), jnp.stack(ls))
-    want = decode_attention_reference(q, k, v, jnp.asarray(lens))
-    np.testing.assert_allclose(got, want, atol=2e-5)
-    ref_comb = combine_partials_reference(
-        jnp.stack(accs), jnp.stack(ms), jnp.stack(ls)
-    )
-    np.testing.assert_allclose(got, ref_comb, atol=1e-6)

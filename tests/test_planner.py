@@ -16,7 +16,6 @@ from repro.core.graph import match_set
 from repro.core.sparse import to_dense
 from repro.data.sparse import sparse_zipfian_corpus
 from repro.planner import (
-    CalibrationProfile,
     VariantConfig,
     default_profile,
     estimate_cost,

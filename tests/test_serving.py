@@ -1,135 +1,22 @@
-"""Serving-path tests.
-
-LM half: decode == full forward (all attention/FFN variants), cache
-bookkeeping, the LM server loop, and sequence-sharded decode on a mesh.
-
-Retrieval half (``repro.serving``): APSSIndex build-once/reuse (trace
-counters prove the second query rebuilds nothing), rectangular exactness
+"""Retrieval serving tests (``repro.serving``): APSSIndex build-once/reuse
+(trace counters prove the second query rebuilds nothing), rectangular exactness
 vs the brute-force oracle across densities/thresholds/k, batched-server ==
 one-shot calls, LRU cache behaviour, and sharded partial-merge correctness
 on 8 virtual devices."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.models.transformer import (
-    TransformerConfig,
-    cache_specs,
-    decode_step,
-    init_transformer,
-    make_cache,
-    transformer_logits,
-)
-
-COMMON = dict(
-    n_layers=3, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
-    vocab_size=256, dtype=jnp.float32, q_chunk=16, kv_chunk=16, loss_chunk=16,
-)
-
-
-def _decode_all(cfg, params, tokens, max_len):
-    cache = make_cache(cfg, tokens.shape[0], max_len)
-    dec = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t))
-    logits = None
-    for i in range(tokens.shape[1]):
-        logits, cache = dec(params, cache, tokens[:, i])
-    return logits, cache
-
-
-@pytest.mark.parametrize(
-    "name,extra",
-    [
-        ("gqa", dict(qk_norm=True)),
-        ("mla", dict(attention="mla", n_kv_heads=4, q_lora_rank=32,
-                     kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8,
-                     v_head_dim=16)),
-        ("moe", dict(moe=True, n_experts=8, top_k=2, d_ff_expert=32,
-                     n_shared_experts=2, dense_residual=True,
-                     first_k_dense=1, capacity_factor=8.0)),
-    ],
-)
-def test_decode_matches_full_forward(name, extra):
-    cfg = TransformerConfig(name=f"t-{name}", **{**COMMON, **extra})
-    params = init_transformer(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (2, 24), 0, 256)
-    logits, cache = _decode_all(cfg, params, tokens, 32)
-    full = jax.jit(lambda p, t: transformer_logits(p, cfg, t))(params, tokens)
-    np.testing.assert_allclose(
-        logits, full[:, -1, :], atol=5e-4, rtol=5e-3
-    )
-    np.testing.assert_array_equal(np.asarray(cache["length"]), 24)
-
-
-def test_decode_cache_isolated_between_sequences():
-    cfg = TransformerConfig(name="t", **COMMON)
-    params = init_transformer(jax.random.key(0), cfg)
-    t1 = jax.random.randint(jax.random.key(1), (1, 16), 0, 256)
-    t2 = jax.random.randint(jax.random.key(2), (1, 16), 0, 256)
-    both = jnp.concatenate([t1, t2], axis=0)
-    logits_b, _ = _decode_all(cfg, params, both, 24)
-    logits_1, _ = _decode_all(cfg, params, t1, 24)
-    np.testing.assert_allclose(logits_b[0], logits_1[0], atol=1e-4, rtol=1e-4)
-
-
-def test_sequence_sharded_decode_on_mesh(mesh4x2):
-    """long_500k pattern at toy scale: KV cache sequence dim sharded over
-    the mesh; GSPMD-partitioned decode must equal the single-device one."""
-    cfg = TransformerConfig(name="t", **COMMON)
-    params = init_transformer(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (2, 16), 0, 256)
-
-    # single-device truth
-    want, _ = _decode_all(cfg, params, tokens, 32)
-
-    # sequence-sharded: cache seq dim over ("data",) (batch 2 not shardable)
-    cache = make_cache(cfg, 2, 32)
-    specs = cache_specs(cfg, seq_axes=("data",), batch_axes=())
-    c_sh = jax.tree.map(
-        lambda s: NamedSharding(mesh4x2, s), specs,
-        is_leaf=lambda x: isinstance(x, P),
-    )
-    cache = jax.tree.map(
-        lambda x, s: jax.device_put(x, s), cache, c_sh,
-        is_leaf=lambda x: hasattr(x, "shape") and not isinstance(x, P),
-    )
-    dec = jax.jit(
-        lambda p, c, t: decode_step(p, cfg, c, t),
-        in_shardings=(None, c_sh, None),
-        out_shardings=(None, c_sh),
-    )
-    logits = None
-    for i in range(tokens.shape[1]):
-        logits, cache = dec(params, cache, tokens[:, i])
-    np.testing.assert_allclose(logits, want, atol=5e-4, rtol=5e-3)
-
-
-def test_lm_server_generates():
-    from repro.launch.serve import LMServer
-
-    cfg = TransformerConfig(name="t", **COMMON)
-    srv = LMServer(cfg, max_batch=2, max_len=64)
-    slot = srv.add_request(np.asarray([3, 5, 7]))
-    out = srv.generate(slot, 5)
-    assert len(out) == 5
-    assert all(0 <= t < cfg.padded_vocab for t in out)
-
-
-# ===========================================================================
-# Retrieval serving: build-once APSSIndex + batched query-time top-k
-# ===========================================================================
-
-from repro.core.apss import normalize_rows  # noqa: E402
-from repro.core.matches import extract_matches  # noqa: E402
-from repro.core.sparse import from_dense  # noqa: E402
-from repro.serving import (  # noqa: E402
+from repro.core.apss import normalize_rows
+from repro.core.matches import extract_matches
+from repro.core.sparse import from_dense
+from repro.obs import compile as obs_compile
+from repro.serving import (
     RetrievalServer,
     build_index,
     query_topk,
 )
-from repro.obs import compile as obs_compile  # noqa: E402
 
 
 def _corpus_queries(n, m, density, nq, seed=0):
@@ -194,6 +81,31 @@ def test_query_topk_kernel_matches_xla(kind):
         index, jnp.asarray(Qn), 0.3, 8, block_q=128, use_kernel=True
     )
     _check_rect(got, ref, 8)
+
+
+@pytest.mark.parametrize("kind", ["dense", "sparse"])
+def test_index_nbytes_counts_every_leaf(kind):
+    """Corpus, block stats and (sparse) the support lists and their
+    densified blocks, at 4 bytes an entry. A dense corpus is lane-padded
+    to the kernel tile, and its stats are taken at that width."""
+    from repro.serving.index import index_nbytes
+
+    Cn, _ = _corpus_queries(150, 96, 0.1, 1, seed=2)
+    corpus = Cn if kind == "dense" else from_dense(Cn)
+    index = build_index(corpus, block_rows=64, normalize=False)
+    ncp, nb, m = index.n_padded, index.n_blocks, index.m
+    assert (ncp, nb) == (192, 3)
+    if kind == "dense":
+        width = index.corpus.shape[1]
+        assert width == 128  # 96 lane-padded
+        want = 4 * ncp * width + 4 * (nb * width + nb + nb)
+    else:
+        stats = 4 * (nb * m + nb + nb)  # maxw, mw, max_nnz
+        cap = corpus.cap
+        S = index.bdims.shape[1]
+        assert index.bx.shape == (nb, 64, S)
+        want = 4 * (2 * ncp * cap + ncp) + stats + 4 * (nb * S + nb * 64 * S)
+    assert index_nbytes(index) == want
 
 
 def test_index_built_once_and_reused_no_retrace():

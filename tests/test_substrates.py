@@ -1,5 +1,5 @@
-"""Substrate tests: optimizer, checkpoint/fault-tolerance, compression,
-elastic re-mesh, data determinism, dedup, straggler ledger."""
+"""Substrate tests: checkpoint/fault-tolerance, elastic re-mesh, synthetic
+corpora and their statistics, dedup, straggler ledger."""
 
 import os
 import tempfile
@@ -10,57 +10,18 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.checkpoint import CheckpointManager, load_checkpoint, save_checkpoint
+from repro.core.pruning import block_prune_mask
 from repro.data import (
-    GraphPipeline,
-    LMDataPipeline,
-    RecsysPipeline,
+    PAPER_DATASETS,
     corpus_stats,
     dedup_corpus,
+    paper_like_corpus,
     synthetic_corpus,
 )
-from repro.data.sampler import neighbor_sample, sampled_shape
+from repro.data.synthetic import clustered_corpus
 from repro.distributed import StepTimer
 from repro.distributed.elastic import make_elastic_mesh, reshard_tree
-from repro.optim import (
-    adamw_init,
-    adamw_update,
-    compress_tree,
-    compression_init,
-    cosine_schedule,
-)
-
-
-# -- optimizer ----------------------------------------------------------------
-
-
-def test_adamw_converges_on_quadratic():
-    params = {"w": jnp.asarray([5.0, -3.0])}
-    opt = adamw_init(params)
-    def loss(p):
-        return jnp.sum(p["w"] ** 2)
-    for _ in range(200):
-        g = jax.grad(loss)(params)
-        params, opt, _ = adamw_update(
-            g, opt, params, lr=0.1, weight_decay=0.0
-        )
-    assert float(loss(params)) < 1e-2
-
-
-def test_cosine_schedule_shape():
-    assert float(cosine_schedule(0, 1.0, 10, 100)) < 0.2          # warmup
-    assert float(cosine_schedule(10, 1.0, 10, 100)) == pytest.approx(1.0)
-    assert float(cosine_schedule(100, 1.0, 10, 100)) == pytest.approx(0.1)
-
-
-def test_bf16_params_f32_moments():
-    params = {"w": jnp.ones((4,), jnp.bfloat16)}
-    opt = adamw_init(params)
-    assert opt.m["w"].dtype == jnp.float32
-    g = {"w": jnp.ones((4,), jnp.bfloat16)}
-    p2, opt2, _ = adamw_update(g, opt, params, lr=1e-2)
-    assert p2["w"].dtype == jnp.bfloat16
 
 
 # -- checkpoint / fault tolerance ----------------------------------------------
@@ -104,53 +65,6 @@ def test_checkpoint_atomicity_no_tmp_left():
         assert mgr.latest_step() == 1
 
 
-def test_train_loop_resume(tmp_path):
-    """Auto-resume: a second train_loop continues from the checkpoint."""
-    from repro.launch.train import train_loop
-
-    d = str(tmp_path / "ck")
-    train_loop(arch="gat-cora", steps=4, ckpt_dir=d, ckpt_every=2, log_every=100)
-    mgr = CheckpointManager(d)
-    assert mgr.latest_step() == 4
-    out = train_loop(arch="gat-cora", steps=6, ckpt_dir=d, ckpt_every=2, log_every=100)
-    assert np.isfinite(out["loss"])
-    assert mgr.latest_step() == 6
-
-
-# -- gradient compression -------------------------------------------------------
-
-
-def test_compression_error_feedback_invariant(mesh8):
-    rng = np.random.default_rng(0)
-    grads = {"w": jnp.asarray(rng.standard_normal((8, 8192)), jnp.float32)}
-    state = compression_init({"w": grads["w"][0]})
-
-    def f(g, err):
-        synced, new = compress_tree(g, state._replace(error=err), "data", ratio=0.05)
-        recon = jax.tree.map(
-            lambda s, e: s + jax.lax.pmean(e, "data"), synced, new.error
-        )
-        return recon
-
-    recon = jax.jit(
-        shard_map(
-            f, mesh=mesh8, in_specs=(P("data"), P()), out_specs=P(),
-            check_vma=False,
-        )
-    )(grads, state.error)
-    np.testing.assert_allclose(
-        np.asarray(recon["w"]).reshape(-1), grads["w"].mean(0), atol=1e-5
-    )
-
-
-def test_compression_volume_accounting():
-    from repro.optim.compression import compression_comm_bytes
-
-    g = {"big": jnp.zeros((1 << 20,)), "small": jnp.zeros((64,))}
-    acc = compression_comm_bytes(g, ratio=0.01, p=16)
-    assert acc["compressed_bytes"] < acc["dense_bytes"]
-
-
 # -- elastic ---------------------------------------------------------------------
 
 
@@ -174,16 +88,6 @@ def test_elastic_mesh_keeps_model_parallel():
 # -- data ------------------------------------------------------------------------
 
 
-def test_pipelines_deterministic_and_step_dependent():
-    lm = LMDataPipeline(vocab_size=1000, batch_size=4, seq_len=16, seed=3)
-    assert (lm.get_batch(7)["tokens"] == lm.get_batch(7)["tokens"]).all()
-    assert (lm.get_batch(7)["tokens"] != lm.get_batch(8)["tokens"]).any()
-    rs = RecsysPipeline(n_items=100, batch_size=4, kind="seq")
-    b = rs.get_batch(0)
-    assert b["item_ids"].shape == (4, 50)
-    assert (b["item_ids"][b["mask"]] == 100).all()  # [MASK] token rows
-
-
 def test_corpus_stats_match_construction():
     D = synthetic_corpus(100, 400, 12.0, seed=1)
     st = corpus_stats(D)
@@ -202,16 +106,39 @@ def test_dedup_finds_planted_duplicates():
     np.testing.assert_array_equal(dup_of[64:], np.arange(8))
 
 
-def test_sampler_static_shapes():
-    pipe = GraphPipeline(n_nodes=300, n_edges=2400, d_feat=8)
-    indptr, idx = pipe.csr()
-    g = pipe.full_graph()
-    seeds = np.arange(16)
-    batch = neighbor_sample(indptr, idx, seeds, (4, 3), g["features"], g["labels"])
-    n, e = sampled_shape(16, (4, 3))
-    assert batch["features"].shape == (n, 8)
-    assert batch["edge_src"].shape == (e,)
-    assert batch["label_mask"][:16].all() and not batch["label_mask"][16:].any()
+@pytest.mark.parametrize("name", sorted(PAPER_DATASETS))
+def test_paper_like_corpus_scales_a_table_1_dataset(name):
+    spec = PAPER_DATASETS[name]
+    scale = 0.002
+    D, t = paper_like_corpus(name, scale=scale, seed=5)
+    assert t == spec["t"]
+    assert D.shape == (
+        max(64, int(spec["n"] * scale)), max(128, int(spec["m"] * scale))
+    )
+    assert D.dtype == np.float32 and (D >= 0).all()
+    np.testing.assert_allclose(np.linalg.norm(D, axis=1), 1.0, rtol=1e-5)
+    D2, _ = paper_like_corpus(name, scale=scale, seed=5)
+    np.testing.assert_array_equal(D, D2)  # a seed gives one corpus
+
+
+@pytest.mark.parametrize("n_clusters", [2, 4, 8])
+def test_clustered_corpus_bands_share_no_dimension(n_clusters):
+    n, m = 64, 128
+    D = clustered_corpus(n, m, 5.0, n_clusters=n_clusters, seed=1)
+    np.testing.assert_allclose(np.linalg.norm(D, axis=1), 1.0, rtol=1e-5)
+    rows_per, band = n // n_clusters, m // n_clusters
+    cluster = np.arange(n) // rows_per
+    # each row lives in its cluster's band of dimensions ...
+    for i in range(n):
+        dims = np.flatnonzero(D[i])
+        assert (dims // band == cluster[i]).all(), i
+    # ... so rows of different clusters never meet, and block bounds at
+    # the cluster size prove every off-diagonal tile dead for any t > 0
+    S = D @ D.T
+    assert (S[cluster[:, None] != cluster[None, :]] == 0).all()
+    Dj = jnp.asarray(D)
+    live = np.asarray(block_prune_mask(Dj, Dj, 1e-3, rows_per))
+    np.testing.assert_array_equal(live, np.eye(n_clusters, dtype=bool))
 
 
 # -- straggler --------------------------------------------------------------------
