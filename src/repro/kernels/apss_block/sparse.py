@@ -6,9 +6,13 @@ densities. This module adds the sparse twin, built on **per-block support
 compaction** (the gather-densify-per-tile form of the paper's partial
 indexing):
 
-1. Host side, each row block ``B`` gets its sorted unique dimension list
-   ``bdims[B] (S,)`` (``S`` = max support size over blocks, lane-padded)
-   and its rows densified onto that list: ``bx[B] (bm, S)``. For
+1. On the device, each row block ``B`` gets its sorted unique dimension
+   list ``bdims[B] (S,)`` (``S`` = max support size over blocks,
+   lane-padded) and its rows densified onto that list: ``bx[B] (bm, S)``
+   (:func:`block_support_gather`). One program marks the dimensions each
+   block holds and counts them; the host reads back only the largest
+   count, which fixes the static ``S``; a second program ranks the marks
+   into ``bdims`` and gathers each block onto its own support. For
    support-coherent corpora ``S « m``.
 2. The live-tile worklist comes from ``core.pruning.sparse_block_prune_mask``
    — inverted-index candidacy ∧ maxweight ∧ exact minsize — computed from
@@ -67,39 +71,82 @@ from repro.obs import trace
 
 def block_support_gather(
     sp: SparseCorpus, block_m: int, *, pad_to: int = 128
-) -> tuple[np.ndarray, np.ndarray]:
-    """Host-side per-block support compaction.
+) -> tuple[jax.Array, jax.Array]:
+    """Per-block support compaction, built on the device.
 
     Returns ``bdims (nb, S)`` — sorted unique dims per row block, padded
     with the sentinel ``m`` (sorts last, matches nothing) — and
     ``bx (nb, bm, S)`` — the block's rows densified onto its own support.
-    ``S`` is lane-padded (``pad_to``) for MXU alignment.
+    ``S`` is the largest block support, lane-padded (``pad_to``) for MXU
+    alignment. The static ``S`` is the one value the host reads back.
     """
-    idx = np.asarray(sp.indices)
-    val = np.asarray(sp.values)
-    nnz = np.asarray(sp.nnz)
-    n, cap = idx.shape
+    n = sp.indices.shape[0]
     assert n % block_m == 0, (n, block_m)
-    nb = n // block_m
-    valid = np.arange(cap)[None, :] < nnz[:, None]
-    uniq = []
-    for b in range(nb):
-        sl = slice(b * block_m, (b + 1) * block_m)
-        uniq.append(np.unique(idx[sl][valid[sl]]))
-    S = max(1, max((len(u) for u in uniq), default=1))
-    S = -(-S // pad_to) * pad_to
-    bdims = np.full((nb, S), sp.m, np.int32)
-    bx = np.zeros((nb, block_m, S), np.float32)
-    rows = np.arange(block_m)[:, None]
-    for b, u in enumerate(uniq):
-        if len(u) == 0:
-            continue
-        bdims[b, : len(u)] = u
-        sl = slice(b * block_m, (b + 1) * block_m)
-        pos = np.searchsorted(u, idx[sl])
-        pos = np.minimum(pos, len(u) - 1)
-        hit = (u[pos] == idx[sl]) & valid[sl]
-        np.add.at(bx[b], (rows, pos), np.where(hit, val[sl], 0.0))
+    present, widest = _support_presence(
+        sp.indices, sp.nnz, block_m=block_m, m=sp.m
+    )
+    S = -(-max(1, int(widest)) // pad_to) * pad_to
+    return _support_build(
+        present, sp.indices, sp.values, sp.nnz, block_m=block_m, S=S
+    )
+
+
+def _valid_keys(indices, nnz, m):
+    """CSR indices with the slots past each row's ``nnz`` keyed to the
+    sentinel ``m``, which no slot table maps into a support."""
+    valid = jnp.arange(indices.shape[1])[None, :] < nnz[:, None]
+    return jnp.where(valid, indices, m)
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "m"))
+def _support_presence(indices, nnz, *, block_m, m):
+    """Which dimensions each row block holds, ``(nb, m) bool``, and the
+    largest count over the blocks (an int32 scalar).
+
+    One block at a time (``lax.map``), each an int32 scatter into a
+    ``(1, m + 1)`` row: the TPU compiler takes seconds over the same
+    scatter into a bool or a 1-D operand, or vmapped over the blocks.
+    """
+    with jax.named_scope("support_gather"):
+        keys = _valid_keys(indices, nnz, m)
+        cap = keys.shape[1]
+
+        def marks(k):
+            row = jnp.zeros((1, m + 1), jnp.int32)
+            return row.at[jnp.zeros_like(k), k].set(1)[0, :m] > 0
+
+        present = lax.map(marks, keys.reshape(-1, block_m, cap))
+        widest = jnp.max(present.sum(1, dtype=jnp.int32), initial=0)
+    return present, widest
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "S"))
+def _support_build(present, indices, values, nnz, *, block_m, S):
+    """``bdims (nb, S)`` and ``bx (nb, bm, S)`` from the presence table.
+
+    A dimension's slot is its rank among the block's present dimensions
+    (an inclusive count less one); ``bdims[b, s]`` is the first dimension
+    whose count passes ``s``, or ``m`` past the block's support. ``bx`` is
+    each block gathered onto its own support by :func:`_gather_block`, one
+    block at a time like the tile gather.
+    """
+    nb, m = present.shape
+    with jax.named_scope("support_gather"):
+        count = jnp.cumsum(present, axis=1, dtype=jnp.int32)
+        bdims = jax.vmap(
+            lambda c: jnp.searchsorted(c, jnp.arange(1, S + 1, dtype=jnp.int32))
+        )(count).astype(jnp.int32)
+        slot = jnp.pad(
+            jnp.where(present, count - 1, S), ((0, 0), (0, 1)),
+            constant_values=S,
+        )
+        idx = _valid_keys(indices, nnz, m)
+        cap = idx.shape[1]
+        bx = lax.map(
+            lambda a: _gather_block(*a, S),
+            (slot, idx.reshape(nb, block_m, cap),
+             values.reshape(nb, block_m, cap)),
+        )
     return bdims, bx
 
 
@@ -478,19 +525,19 @@ def apss_sparse_compacted(
         bdims, bx = block_support_gather(spp, block_m, pad_to=lane_pad)
         S = bdims.shape[1]
         # lookup_bytes: device memory of the dimension→slot table
+        # host_read_bytes: what the build reads back (the widest support)
         trace.annotate(
             blocks=grid_m, block_rows=block_m, support=S,
             support_lookup="table", lookup_bytes=grid_m * (spp.m + 1) * 4,
+            host_read_bytes=4,
         )
         if use_kernel:
             trace.annotate(support_chunk=_support_tile(S))
-    with trace.span("apss/upload", bytes=bx.nbytes + bdims.nbytes):
-        bx_d, bdims_d = jnp.asarray(bx), jnp.asarray(bdims)
     idxb = spp.indices.reshape(grid_m, block_m, spp.cap)
     valb = spp.values.reshape(grid_m, block_m, spp.cap)
     with trace.span("apss/dispatch"):
         values, indices, counts = _sparse_compacted_inner(
-            bx_d, bdims_d, idxb, valb, ij,
+            bx, bdims, idxb, valb, ij,
             threshold=float(threshold), k=k, block_m=block_m, n_valid=n,
             grid_m=grid_m, m=spp.m, use_kernel=use_kernel,
             interpret=interpret,

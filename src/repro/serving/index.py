@@ -298,8 +298,7 @@ def _build_sparse(
         stats = jax.device_put(stats, NamedSharding(mesh, P()))
         bdims = bx = None
     else:
-        bd, bxx = block_support_gather(spp, block_rows, pad_to=lane_pad)
-        bdims, bx = jnp.asarray(bd), jnp.asarray(bxx)
+        bdims, bx = block_support_gather(spp, block_rows, pad_to=lane_pad)
         triple = (spp.indices, spp.values, spp.nnz)
     return APSSIndex(
         triple, stats, bdims, bx,

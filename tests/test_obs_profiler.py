@@ -31,8 +31,7 @@ from repro.serving.server import ContinuousRetrievalServer, RetrievalServer
 
 T, K = 0.2, 8
 SELFJOIN_SPANS = (
-    "apss/bounds", "apss/worklist", "apss/support_gather", "apss/upload",
-    "apss/dispatch",
+    "apss/bounds", "apss/worklist", "apss/support_gather", "apss/dispatch",
 )
 QUERY_SPANS = ("query/mask", "query/worklist", "query/dispatch")
 
@@ -145,6 +144,8 @@ def test_profiler_session_records_program_spans_with_counts(
     support = by_name["apss/support_gather"][0][4]
     assert support["blocks"] == nb and support["block_rows"] == 256
     assert support["support"] % 128 == 0 and support["support_chunk"] > 0
+    # the device build reads back one int32 (the widest support), no corpus
+    assert support["host_read_bytes"] == 4 and "apss/upload" not in by_name
 
     (rwl,) = kept["compact_rect_worklist"]
     _, valid = ops.pad_worklist(rwl)

@@ -8,6 +8,7 @@ identical ``match_set`` and exact ``counts`` as ``apss_reference`` on the
 densified corpus.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -276,6 +277,112 @@ def test_slot_table_gather_equals_binary_search_gather(case):
         assert np.array_equal(np.asarray(table), search)
         if len(real):  # the case gathers something onto every non-empty block
             assert search.any()
+
+
+# -- support gather: the device build of bdims / bx against the host loop ----
+
+
+def _host_support_gather(sp, block_m, pad_to):
+    """NumPy oracle: the host loop the device build replaced. Per row block,
+    ``np.unique`` of the valid indices, then ``np.add.at`` of each row onto
+    them (a miss adds 0.0 to its clamped slot)."""
+    idx, val, nnz = (np.asarray(a) for a in (sp.indices, sp.values, sp.nnz))
+    n, cap = idx.shape
+    nb = n // block_m
+    valid = np.arange(cap)[None, :] < nnz[:, None]
+    blocks = [slice(b * block_m, (b + 1) * block_m) for b in range(nb)]
+    uniq = [np.unique(idx[sl][valid[sl]]) for sl in blocks]
+    S = max(1, max((len(u) for u in uniq), default=1))
+    S = -(-S // pad_to) * pad_to
+    bdims = np.full((nb, S), sp.m, np.int32)
+    bx = np.zeros((nb, block_m, S), np.float32)
+    rows = np.arange(block_m)[:, None]
+    for b, (u, sl) in enumerate(zip(uniq, blocks)):
+        if len(u) == 0:
+            continue
+        bdims[b, : len(u)] = u
+        pos = np.minimum(np.searchsorted(u, idx[sl]), len(u) - 1)
+        hit = (u[pos] == idx[sl]) & valid[sl]
+        np.add.at(bx[b], (rows, pos), np.where(hit, val[sl], 0.0))
+    return bdims, bx
+
+
+def _support_case(name):
+    """``(corpus padded to the block, block_m, pad_to, duplicates)``."""
+    block_m, pad_to = {
+        "b8_p8": (8, 8), "b16_p128_duplicates": (16, 128),
+        "b128_p128_row_padding": (128, 128), "b16_p8_empty_block": (16, 8),
+        "b8_p8_whole_vocabulary": (8, 8), "b16_p8_stale_padding": (16, 8),
+        "b8_p128_all_empty": (8, 128),
+    }[name]
+    dups = name.endswith("duplicates")
+    sp = random_csr(
+        sum(map(ord, name)), 300 if "row_padding" in name else 64, 200, 12,
+        dup_prob=0.0,
+    )
+    idx, val, nnz = (np.array(a) for a in (sp.indices, sp.values, sp.nnz))
+    m = sp.m
+    if dups:  # three entries of one coordinate in every row, which sum
+        idx[:, 1] = idx[:, 2] = idx[:, 0]
+    if name == "b16_p8_empty_block":
+        nnz[16:32] = 0
+        idx[16:32], val[16:32] = 0, 0.0
+    if name == "b8_p8_whole_vocabulary":
+        # block 0's 8 rows × 10 entries name every one of m = 40 dims
+        m = 40
+        idx = idx % m
+        idx[:8, :10] = np.arange(80).reshape(8, 10) % m
+        nnz[:8] = np.maximum(nnz[:8], 10)
+    if name == "b16_p8_stale_padding":
+        # slots past nnz hold stale dims and values: only nnz decides
+        rng = np.random.default_rng(1)
+        pad = np.arange(idx.shape[1])[None, :] >= nnz[:, None]
+        idx = np.where(pad, rng.integers(0, m, idx.shape), idx)
+        val = np.where(pad, 1.0, val)
+    if name == "b8_p128_all_empty":
+        nnz[:] = 0
+        idx[:], val[:] = 0, 0.0
+    sp = SparseCorpus(
+        jnp.asarray(idx, jnp.int32), jnp.asarray(val, jnp.float32),
+        jnp.asarray(nnz, jnp.int32), m,
+    )
+    return pad_rows_sparse(sp, block_m)[0], block_m, pad_to, dups
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["b8_p8", "b16_p128_duplicates", "b128_p128_row_padding",
+     "b16_p8_empty_block", "b8_p8_whole_vocabulary", "b16_p8_stale_padding",
+     "b8_p128_all_empty"],
+)
+def test_device_support_gather_equals_host_loop(case):
+    spp, block_m, pad_to, dups = _support_case(case)
+    bdims, bx = sparse_kernels.block_support_gather(spp, block_m, pad_to=pad_to)
+    assert isinstance(bdims, jax.Array) and isinstance(bx, jax.Array)
+    want_dims, want_x = _host_support_gather(spp, block_m, pad_to)
+    assert bdims.shape == want_dims.shape and bx.shape == want_x.shape
+    np.testing.assert_array_equal(np.asarray(bdims), want_dims)
+    if dups:  # the sums may round in another order: one ulp
+        np.testing.assert_array_max_ulp(np.asarray(bx), want_x, maxulp=1)
+    else:
+        np.testing.assert_array_equal(np.asarray(bx), want_x)
+    if case == "b8_p8_whole_vocabulary":
+        np.testing.assert_array_equal(np.asarray(bdims)[0], np.arange(40))
+    if case == "b16_p8_empty_block":
+        assert (np.asarray(bdims)[1] == spp.m).all() and not np.asarray(bx)[1].any()
+
+
+def test_benchmark_spy_reads_block_rows_from_the_support_gather():
+    # bench/kinds/selfjoin.py counts the kernel's work with the block rows
+    # it keeps from this call; routing the join around the name loses them
+    from bench.spy import CallSpy, support_block
+
+    sp = sparse_clustered_corpus(96, 512, 8, n_clusters=8, seed=8)
+    with CallSpy(
+        sparse_kernels, "block_support_gather", keep=support_block
+    ) as spy:
+        apss_sparse_compacted(sp, 0.4, K, block_m=16, lane_pad=8, use_kernel=False)
+    assert spy.kept == [16]
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])

@@ -126,3 +126,24 @@ def test_kernel_compiles_for_v5e(one_chip, build):
     compiled = jax.jit(fn).lower(*args).compile()
     # A Mosaic kernel, not the interpreter's emulation of one.
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_device_support_build_compiles_for_v5e(one_chip):
+    # the radikal CSR corpus: 6,912 padded rows of width 204, m = 136,447
+    n, cap, m = SP_NB * 256, 204, 136447
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    presence = sparse._support_presence.lower(
+        shape((n, cap), jnp.int32), shape((n,), jnp.int32), block_m=256, m=m
+    ).compile()
+    marks, widest = presence.out_info
+    assert marks.shape == (SP_NB, m) and widest.shape == ()
+    build = sparse._support_build.lower(
+        shape((SP_NB, m), jnp.bool_), shape((n, cap), jnp.int32),
+        shape((n, cap), jnp.float32), shape((n,), jnp.int32),
+        block_m=256, S=SP_S,
+    ).compile()
+    bdims, bx = build.out_info
+    assert bdims.shape == (SP_NB, SP_S) and bx.shape == (SP_NB, 256, SP_S)
