@@ -33,12 +33,11 @@ Paper → TPU mapping (see DESIGN.md §2 for the full table):
 Row blocks (``block_rows``) are the paper's §5.1.9 block-processing knob: all
 variants process a block of query rows per collective step.
 
-Every variant is exact (validated against ``apss_reference``). The 1-D
-compressed and recursive accumulations score a query block whose Lemma-1
-candidates overflow the capacity on any shard by the all-reduce of its whole
-partial tile instead, and count the blocks each route took; the 2-D
-composition carries an overflow counter, so its capacity truncation is
-visible, never silent.
+Every variant is exact (validated against ``apss_reference``). The
+compressed and recursive accumulations, 1-D and 2-D, score a query block
+whose Lemma-1 candidates overflow the capacity on any shard by the
+all-reduce of its whole partial tile instead, and count the blocks each
+route took.
 """
 
 from __future__ import annotations
@@ -67,7 +66,6 @@ from repro.core.sparse import (
     SparseCorpus,
     densify_rows,
     gather_dot,
-    shard_dims,
     sparse_similarity_topk,
 )
 from repro.obs import trace
@@ -77,12 +75,12 @@ from repro.planner import telemetry
 class ApssStats(NamedTuple):
     """Exactness accounting for capacity-bounded candidate sets.
 
-    ``overflow_rows`` counts rows whose candidates overflowed the capacity:
-    the 1-D vertical accumulations scored their blocks exactly instead, the
-    2-D composition truncated them. ``blocks_pruned`` / ``blocks_exact``
-    count the query blocks a 1-D vertical accumulation scored through its
-    Lemma-1 candidates and through the all-reduce of the whole partial
-    tile; ``None`` where a path does not count routes (the 2-D sweep).
+    ``overflow_rows`` counts rows whose candidates overflowed the capacity;
+    their blocks were scored exactly instead. ``blocks_pruned`` /
+    ``blocks_exact`` count the query blocks an accumulation scored through
+    its Lemma-1 candidates and through the all-reduce of the whole partial
+    tile (on the 2-D sweep, one per query block and ring step, summed over
+    the row shards); ``None`` where a path does not count routes.
     """
 
     overflow_rows: jax.Array  # i32 scalar
@@ -802,34 +800,55 @@ def _deal_on_device(idx, nnz, *, m, p):
     return shard, rank[idx] // p, counts
 
 
-def _cut_width(counts: np.ndarray) -> tuple[int, int]:
+def _cut_width(
+    counts: np.ndarray, q: int = 1, n_pad: int | None = None
+) -> tuple[int, int]:
     """The shards' row width ``W`` and spill length ``E`` from the ``(n, p)``
     counts. ``W`` fits all but the longest ``SPILL_SHARE`` of the rows,
     rounded up to ``gather_dot``'s chunk, so that it does not follow the
     longest row: that moves from corpus to corpus, and across a chunk
     boundary adds a third to the gathers. ``E`` holds the most entries any
-    shard has past ``W``, rounded up to ``_SPILL_QUANTUM``."""
-    q = int(np.quantile(counts.max(axis=1), 1.0 - SPILL_SHARE, method="higher"))
-    width = max(1, -(-q // _GATHER_CHUNK) * _GATHER_CHUNK)
-    most = int(np.maximum(counts - width, 0).sum(axis=0).max())
+    cell has past ``W``, rounded up to ``_SPILL_QUANTUM``; a cell is a
+    shard's slice of one of ``q`` row shards of ``n_pad / q`` rows each, as
+    :func:`_pack_on_device` cuts them (the rows padded with empty rows to
+    ``n_pad``, by default ``n``)."""
+    n = counts.shape[0]
+    n_pad = n if n_pad is None else n_pad
+    if n_pad < n or n_pad % q:
+        raise ValueError(f"n_pad={n_pad} must be ≥ n={n} and a multiple of q={q}")
+    top = int(np.quantile(counts.max(axis=1), 1.0 - SPILL_SHARE, method="higher"))
+    width = max(1, -(-top // _GATHER_CHUNK) * _GATHER_CHUNK)
+    over = np.maximum(counts - width, 0)
+    over = np.pad(over, ((0, n_pad - n), (0, 0))).reshape(q, -1, counts.shape[1])
+    most = int(over.sum(axis=1).max())
     return width, _SPILL_QUANTUM * max(1, -(-most // _SPILL_QUANTUM))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("width", "spill", "n_pad", "mesh", "axis_name")
+    jax.jit,
+    static_argnames=("width", "spill", "n_pad", "mesh", "row_axis", "col_axis"),
 )
-def _pack_on_device(shard, local, val, *, width, spill, n_pad, mesh, axis_name):
-    """Each chip packs its own shard from the replicated slots. One stable
-    sort moves a row's slots of the chip's shard to its front in stored
-    order; the first ``width`` form the ``(n_pad, width)`` rows (rows past
-    ``n`` empty), and the rest go to the ``(spill,)`` list ``(row, idx,
-    val)`` in row-major order, padded with inert ``(0, 0, 0.0)``. Returns
-    the ``(p, n_pad, width)`` stacks and the three ``(p, spill)`` lists,
-    sharded over ``axis_name``."""
+def _pack_on_device(
+    shard, local, val, *, width, spill, n_pad, mesh, row_axis, col_axis
+):
+    """Each chip packs its own cell from the replicated slots: chip ``(i,
+    j)`` the entries of dimension shard ``j`` (over ``col_axis``) in row
+    shard ``i`` (over ``row_axis``; ``None``: every row, the 1-D split).
+    One stable sort moves a row's slots of the chip's shard to its front in
+    stored order; the first ``width`` form the cell's rows (rows past ``n``
+    empty), and the rest go to the ``(spill,)`` list ``(row, idx, val)`` in
+    row-major order, row ids local to the row shard, padded with inert
+    ``(0, 0, 0.0)``. Returns the ``(r, n_pad, width)`` stacks and the three
+    ``(r, q * spill)`` lists, sharded ``(col_axis, row_axis)``."""
+    q = 1 if row_axis is None else mesh.shape[row_axis]
+    if q > 1:  # row shard i holds padded rows [i, i + 1) · n_pad / q
+        rows = ((0, n_pad - shard.shape[0]), (0, 0))
+        shard = jnp.pad(shard, rows, constant_values=mesh.shape[col_axis])
+        local, val = jnp.pad(local, rows), jnp.pad(val, rows)
 
     def pack(shard, local, val):
         n, cap = shard.shape
-        mine = shard == lax.axis_index(axis_name)
+        mine = shard == lax.axis_index(col_axis)
         _, idx, val = lax.sort(
             ((~mine).astype(jnp.int32), local, val.astype(jnp.float32)),
             dimension=1, is_stable=True, num_keys=1,
@@ -839,7 +858,7 @@ def _pack_on_device(shard, local, val, *, width, spill, n_pad, mesh, axis_name):
         count = mine.sum(axis=1)[:, None]
         slot = jnp.arange(idx.shape[1])[None, :]
         kept = slot[:, :width] < count
-        rows = ((0, n_pad - n), (0, 0))
+        rows = ((0, n_pad // q - n), (0, 0))
         out_idx = jnp.pad(jnp.where(kept, idx[:, :width], 0), rows)
         out_val = jnp.pad(jnp.where(kept, val[:, :width], 0.0), rows)
         past = (slot >= width) & (slot < count)
@@ -851,48 +870,82 @@ def _pack_on_device(shard, local, val, *, width, spill, n_pad, mesh, axis_name):
         )
         return tuple(a[None] for a in (out_idx, out_val, *spilled))
 
-    shards, spills, everywhere = P(axis_name, None, None), P(axis_name, None), P()
+    slots = P(row_axis, None)
+    cells, spills = P(col_axis, row_axis, None), P(col_axis, row_axis)
     return shard_map(
-        pack, mesh=mesh, in_specs=(everywhere,) * 3,
-        out_specs=(shards, shards, spills, spills, spills),
+        pack, mesh=mesh, in_specs=(slots,) * 3,
+        out_specs=(cells, cells, spills, spills, spills),
     )(shard, local, val)
 
 
-def _vertical_sparse_split(D: SparseCorpus, block_rows: int, mesh, axis_name):
-    """Split stage of the sparse vertical path, on the mesh. The corpus,
+def _split_on_mesh(D: SparseCorpus, n_pad, mesh, row_axis, col_axis, prefix):
+    """The dimension split of a sparse corpus, on the mesh. The corpus,
     replicated over the mesh (put there if it is not), is dealt and counted
-    on the devices (:func:`_deal_on_device`); the host reads the ``(n, p)``
+    on the devices (:func:`_deal_on_device`); the host reads the ``(n, r)``
     counts to fix the width and the spill length (:func:`_cut_width`); each
-    chip packs its own shard, rows padded with empty rows to a multiple of
-    ``block_rows`` (:func:`_pack_on_device`). The stacks equal
+    chip packs its own cell, rows padded with empty rows to ``n_pad``
+    (:func:`_pack_on_device`). The stacks equal
     :func:`~repro.core.sparse.shard_dims`' cut to the width, with the cut
-    entries in the spill. Returns ``(idx_s, val_s, spill, shard_nnz,
-    m_loc)``: ``(p, n_pad, W)`` local indices and values and the three
-    ``(p, E)`` spill arrays, sharded over ``axis_name``, and the nonzeros
-    of each shard."""
-    p = mesh.shape[axis_name]
-    m_loc = -(-D.m // p)
-    n_pad = D.n + (-D.n) % block_rows
+    entries in the spill. Spans ``<prefix>/shard`` and ``<prefix>/pad``.
+    Returns ``(idx_s, val_s, spill, cell_nnz, m_loc)``: ``(r, n_pad, W)``
+    local indices and values and the three ``(r, q · E)`` spill arrays,
+    sharded ``(col_axis, row_axis)``, and the nonzeros of each cell, row
+    shard major."""
+    q = 1 if row_axis is None else mesh.shape[row_axis]
+    r = mesh.shape[col_axis]
+    m_loc = -(-D.m // r)
     everywhere = NamedSharding(mesh, P())
     idx, val, nnz = (
         jax.device_put(a, everywhere) for a in (D.indices, D.values, D.nnz)
     )
-    with trace.span("vertical/shard", p=p):
-        shard, local, counts = _deal_on_device(idx, nnz, m=D.m, p=p)
+    shape = {"p": r} if row_axis is None else {"q": q, "r": r}
+    with trace.span(f"{prefix}/shard", **shape):
+        shard, local, counts = _deal_on_device(idx, nnz, m=D.m, p=r)
         counts = np.asarray(counts)  # the host's one read of the split
-        width, spill = _cut_width(counts)
-        shard_nnz = counts.sum(axis=0)
+        width, spill = _cut_width(counts, q, n_pad)
+        rows = np.pad(counts, ((0, n_pad - D.n), (0, 0)))
+        cell_nnz = rows.reshape(q, -1, r).sum(axis=1).ravel()
         trace.annotate(
             m_loc=m_loc, cap_loc=width,
             spilled=int(np.maximum(counts - width, 0).sum()),
-            nnz_max=int(shard_nnz.max()), nnz_mean=float(shard_nnz.mean()),
+            nnz_max=int(cell_nnz.max()), nnz_mean=float(cell_nnz.mean()),
         )
-    with trace.span("vertical/pad", n=D.n, n_pad=n_pad):  # the pack pads
+    with trace.span(f"{prefix}/pad", n=D.n, n_pad=n_pad):  # the pack pads
         idx_s, val_s, *spilled = _pack_on_device(
             shard, local, val, width=width, spill=spill, n_pad=n_pad,
-            mesh=mesh, axis_name=axis_name,
+            mesh=mesh, row_axis=row_axis, col_axis=col_axis,
         )
-    return idx_s, val_s, tuple(spilled), shard_nnz, m_loc
+    return idx_s, val_s, tuple(spilled), cell_nnz, m_loc
+
+
+def _vertical_sparse_split(D: SparseCorpus, block_rows: int, mesh, axis_name):
+    """Split stage of the sparse vertical path (:func:`_split_on_mesh` over
+    one axis), rows padded to a multiple of ``block_rows``."""
+    n_pad = D.n + (-D.n) % block_rows
+    return _split_on_mesh(D, n_pad, mesh, None, axis_name, "vertical")
+
+
+def _query_block(sp_loc: SparseCorpus, spill, base, rows):
+    """The dense ``(rows, m_loc)`` query block of local rows ``base`` on: the
+    cut rows densified, plus the block's entries of the spill list."""
+    rowS, idxS, valS = spill
+    qd = densify_rows(sp_loc, base, rows)
+    here = (rowS >= base) & (rowS < base + rows)
+    return qd.at[jnp.where(here, rowS - base, rows), idxS].add(valS, mode="drop")
+
+
+def _partial_tile(qd, Ci, Cv, spill):
+    """The partial tile of the query block ``qd`` against a cell: the cut
+    rows ``(ncb, b, W)`` in ``gather_dot`` chunks of ``b`` rows, plus the
+    cell's spill list ``(row, idx, val)``, gathered to its columns."""
+    rowS, idxS, valS = spill
+
+    def chunk(_, ci):
+        return _, gather_dot(qd, Ci[ci], Cv[ci], chunk=_GATHER_CHUNK)
+
+    _, ss = lax.scan(chunk, 0, jnp.arange(Ci.shape[0]))  # (ncb, rows, b)
+    A = jnp.moveaxis(ss, 0, 1).reshape(qd.shape[0], -1)
+    return A.at[:, rowS].add(qd[:, idxS] * valS)
 
 
 @functools.partial(
@@ -928,21 +981,11 @@ def _vertical_sparse_post_split(
         sp_loc = SparseCorpus(idxL, valL, jnp.zeros((n,), jnp.int32), m_loc)
         Ci = idxL.reshape(ncb, block_rows, width)
         Cv = valL.reshape(ncb, block_rows, width)
+        spill = (rowS, idxS, valS)
 
         def partials(blk):
-            base = blk * block_rows
-            qd = densify_rows(sp_loc, base, block_rows)
-            here = (rowS >= base) & (rowS < base + block_rows)
-            qd = qd.at[jnp.where(here, rowS - base, block_rows), idxS].add(
-                valS, mode="drop"
-            )
-
-            def chunk(_, ci):
-                return _, gather_dot(qd, Ci[ci], Cv[ci], chunk=_GATHER_CHUNK)
-
-            _, ss = lax.scan(chunk, 0, jnp.arange(ncb))  # (ncb, b, block)
-            A = jnp.moveaxis(ss, 0, 1).reshape(block_rows, n)
-            return A.at[:, rowS].add(qd[:, idxS] * valS)
+            qd = _query_block(sp_loc, spill, blk * block_rows, block_rows)
+            return _partial_tile(qd, Ci, Cv, spill)
 
         return partials
 
@@ -1019,12 +1062,14 @@ def _partial_scores(D_loc, blk, block_rows):
     )
 
 
-def _allreduce_block(A, blk, *, threshold, k, axis_name, block_rows):
+def _allreduce_block(A, *, threshold, k, axis_name, row_offset, col_offset=0):
     """Exact matches of one query block: the all-reduce of its whole
-    ``(block_rows, n)`` partial tile."""
+    partial tile, whose ``[0, 0]`` is row ``row_offset``, column
+    ``col_offset``."""
     S = lax.psum(A, axis_name)
     return extract_matches(
-        S, threshold, k, row_offset=blk * block_rows, exclude_self=True
+        S, threshold, k, row_offset=row_offset, col_offset=col_offset,
+        exclude_self=True,
     )
 
 
@@ -1036,8 +1081,8 @@ def _vertical_allreduce(partials_fn, n, *, threshold, k, axis_name, block_rows):
         A = partials_fn(blk)
         with jax.named_scope("vertical/accumulate"):
             m = _allreduce_block(
-                A, blk, threshold=threshold, k=k, axis_name=axis_name,
-                block_rows=block_rows,
+                A, threshold=threshold, k=k, axis_name=axis_name,
+                row_offset=blk * block_rows,
             )
         return _, m
 
@@ -1085,21 +1130,27 @@ def _local_candidates(A, t_local, capacity):
     return c_val, c_idx, _overflow_rows(A, t_local, capacity)
 
 
-def _rescore_candidates(A, c_idx, blk, *, threshold, k, axis_name, block_rows):
+def _rescore_candidates(
+    A, c_idx, *, threshold, k, axis_name, row_offset, col_offset=None
+):
     """Exact matches at the union of every shard's candidate ids: a small
-    all-gather of the ids, then one psum of the partials there."""
+    all-gather of the ids, then one psum of the partials there. The ids are
+    tile-local; ``col_offset`` is the tile's first column where it is not
+    the corpus's."""
     all_idx = lax.all_gather(c_idx, axis_name, axis=1, tiled=True)  # (b, p*C)
     safe = jnp.maximum(all_idx, 0)
     mine = jnp.take_along_axis(A, safe, axis=1)
     mine = jnp.where(all_idx >= 0, mine, 0.0)
     total = lax.psum(mine, axis_name)  # exact scores at the union
+    if col_offset is not None:
+        all_idx = jnp.where(all_idx >= 0, all_idx + col_offset, -1)
     return matches_from_candidates(
         total, all_idx, threshold, k,
-        row_offset=blk * block_rows, exclude_self=True, dedupe=True,
+        row_offset=row_offset, exclude_self=True, dedupe=True,
     )
 
 
-def _pruned_or_exact(A, overflow, candidates, blk, **kw):
+def _pruned_or_exact(A, overflow, candidates, **kw):
     """One query block's matches, and whether it took the exact route.
 
     Lemma-1 pruning is exact only while no shard's candidates overflow the
@@ -1111,8 +1162,8 @@ def _pruned_or_exact(A, overflow, candidates, blk, **kw):
     exact = lax.pmax(overflow, kw["axis_name"]) > 0
     m = lax.cond(
         exact,
-        lambda: _allreduce_block(A, blk, **kw),
-        lambda: _rescore_candidates(A, candidates(), blk, **kw),
+        lambda: _allreduce_block(A, **kw),
+        lambda: _rescore_candidates(A, candidates(), **kw),
     )
     return m, exact.astype(jnp.int32)
 
@@ -1142,8 +1193,6 @@ def _vertical_compressed(
     """
     nb = n // block_rows
     t_local = local_threshold(threshold, p)
-    kw = dict(threshold=threshold, k=k, axis_name=axis_name,
-              block_rows=block_rows)
 
     def body(carry, blk):
         A = partials_fn(blk)  # (b, n) partials
@@ -1151,7 +1200,8 @@ def _vertical_compressed(
             overflow = _overflow_rows(A, t_local, capacity)
             m, exact = _pruned_or_exact(
                 A, overflow, lambda: _local_candidates(A, t_local, capacity)[1],
-                blk, **kw,
+                threshold=threshold, k=k, axis_name=axis_name,
+                row_offset=blk * block_rows,
             )
         return (carry[0] + overflow, carry[1] + exact), m
 
@@ -1223,8 +1273,6 @@ def _vertical_recursive(
     t_leaf = local_threshold(threshold, p)
     me = lax.axis_index(axis_name)
     levels = p.bit_length() - 1
-    kw = dict(threshold=threshold, k=k, axis_name=axis_name,
-              block_rows=block_rows)
 
     def body(carry, blk):
         A = partials_fn(blk)
@@ -1262,7 +1310,10 @@ def _vertical_recursive(
             # Top level: candidate ids are level-merged but may still differ per
             # device (capacity effects); take the union once, then exact-rescore
             # — or all-reduce the whole tile where any level overflowed.
-            m, exact = _pruned_or_exact(A, overflow, lambda: c_idx, blk, **kw)
+            m, exact = _pruned_or_exact(
+                A, overflow, lambda: c_idx, threshold=threshold, k=k,
+                axis_name=axis_name, row_offset=blk * block_rows,
+            )
         return (carry[0] + overflow, carry[1] + exact), m
 
     zero = _pvary(jnp.int32(0), axis_name)
@@ -1296,13 +1347,13 @@ def apss_2d(
     re-use of the vertical algorithm with the row communicator, verbatim in
     mesh-axis form.
 
-    ``D`` may be a :class:`~repro.core.sparse.SparseCorpus`: the checkerboard
-    cell ``(i, j)`` holds row shard ``i`` restricted to posting-list slice
-    ``j`` (host-side ``shard_dims`` pre-split — see ``_apss_2d_sparse`` for
-    the traced-vs-host tradeoff), the per-cell CSR pair rides the row-axis
-    ring, and the identical column-axis accumulations apply — both
-    representations run the one checkerboard driver through its
-    ``partials_fn`` seam (``_checkerboard_sweep``).
+    ``D`` may be a :class:`~repro.core.sparse.SparseCorpus` of any ``n``: the
+    checkerboard cell ``(i, j)`` holds row shard ``i`` restricted to the
+    posting lists dealt to column ``j``, split on the mesh (see
+    ``_apss_2d_sparse``), the cell rides the row-axis ring, and the identical
+    column-axis accumulations apply — both representations run the one
+    checkerboard driver through its ``partials_fn`` seam
+    (``_checkerboard_sweep``).
     """
     if isinstance(D, SparseCorpus):
         return _apss_2d_sparse(
@@ -1321,9 +1372,7 @@ def apss_2d(
         ticker = StepTicker()
         n, m = D.shape
         n_loc = n // q
-        bs = min(block_rows, n_loc)
-        while n_loc % bs:  # mirror _apss_2d_local's block clamp
-            bs -= 1
+        bs = _block_clamp(block_rows, n_loc)
         telemetry.record(telemetry.ApssStats(
             variant=f"2d/{accumulation}",
             n=n, m=m, devices=q * r, block_rows=bs, sparse=False,
@@ -1346,14 +1395,7 @@ def apss_2d(
         fn,
         mesh=mesh,
         in_specs=P(row_axis, col_axis),
-        out_specs=(
-            Matches(
-                values=P(row_axis, None),
-                indices=P(row_axis, None),
-                counts=P(row_axis),
-            ),
-            ApssStats(overflow_rows=P()),
-        ),
+        out_specs=(_matches_specs(row_axis), ApssStats(P(), P(), P())),
         check_vma=False,
     )(D)
     if return_stats:
@@ -1365,29 +1407,21 @@ def _accumulate_block_scores(
     A, *, col_axis, r, threshold, k, capacity, accumulation,
     row_offset, col_offset,
 ):
-    """Vertical accumulation of one (rows × cols) partial tile over col_axis."""
+    """Vertical accumulation of one (rows × cols) partial tile over col_axis.
+    Returns its matches, this chip's count of overflowing rows and whether
+    the tile took the exact route (every chip of ``col_axis`` agrees)."""
+    kw = dict(threshold=threshold, k=k, axis_name=col_axis,
+              row_offset=row_offset, col_offset=col_offset)
     if accumulation == "allreduce":
-        S = lax.psum(A, col_axis)
-        m = extract_matches(
-            S, threshold, k, row_offset=row_offset, col_offset=col_offset,
-            exclude_self=True,
-        )
-        return m, jnp.int32(0)
+        return _allreduce_block(A, **kw), jnp.int32(0), jnp.int32(1)
     if accumulation == "compressed":
         t_local = local_threshold(threshold, r)
-        c_val, c_idx, overflow = _local_candidates(A, t_local, capacity)
-        all_idx = lax.all_gather(c_idx, col_axis, axis=1, tiled=True)
-        safe = jnp.maximum(all_idx, 0)
-        mine = jnp.take_along_axis(A, safe, axis=1)
-        mine = jnp.where(all_idx >= 0, mine, 0.0)
-        total = lax.psum(mine, col_axis)
-        # Candidate ids are tile-local columns; globalize before extraction.
-        gidx = jnp.where(all_idx >= 0, all_idx + col_offset, -1)
-        m = matches_from_candidates(
-            total, gidx, threshold, k, row_offset=row_offset,
-            exclude_self=True, dedupe=True,
+        overflow = _overflow_rows(A, t_local, capacity)
+        m, exact = _pruned_or_exact(
+            A, overflow, lambda: _local_candidates(A, t_local, capacity)[1],
+            **kw,
         )
-        return m, overflow
+        return m, overflow, exact
     raise ValueError(f"unknown 2-D accumulation: {accumulation}")
 
 
@@ -1401,16 +1435,19 @@ def _block_clamp(block_rows: int, n_loc: int) -> int:
 
 def _checkerboard_sweep(
     partials_fn, buf0, n_loc, *, threshold, k, row_axis, col_axis, q, r,
-    bs, capacity, accumulation, ticker=None,
+    bs, capacity, accumulation, ticker=None, n_valid=None,
 ):
     """The one 2-D checkerboard driver both representations run through.
 
     Ring over ``row_axis`` of an opaque traveling pytree ``buf0`` (a dense
-    wire-format cell or a sparse CSR pair); per ring step,
+    wire-format cell or a sparse cell); per ring step,
     ``partials_fn(buf, blk) -> (bs, n_loc)`` scores local query block
     ``blk`` against the traveling corpus cell in the local dimension slice
     (einsum or gather-dot — the same seam the vertical dispatch uses), and
     ``_accumulate_block_scores`` composes the column-axis accumulation.
+    Columns from ``n_valid`` on are padding rows: their partials read
+    ``-inf``, so they never match or count. Device scopes ``twod/partials``,
+    ``twod/accumulate`` and ``twod/ring``.
 
     ``ticker`` (a ``distributed.straggler.StepTicker``) plants one host
     tick per rank per ring step — the dep argument is data computed by the
@@ -1420,43 +1457,55 @@ def _checkerboard_sweep(
     me_r = lax.axis_index(row_axis)
     row_off = me_r * n_loc
 
-    def compute_vs(buf, s, matches, overflow):
+    def compute_vs(buf, s, matches, routes):
         """Match my rows against the row block owned by (me_r - s)."""
         src = jnp.mod(me_r - s, q)
         col_off = src * n_loc
 
         def body(carry, blk):
-            A = partials_fn(buf, blk)
-            mm, o = _accumulate_block_scores(
-                A, col_axis=col_axis, r=r, threshold=threshold, k=k,
-                capacity=capacity, accumulation=accumulation,
-                row_offset=row_off + blk * bs, col_offset=col_off,
-            )
-            return carry + o, mm
+            with jax.named_scope("twod/partials"):
+                A = partials_fn(buf, blk)
+                if n_valid is not None:
+                    real = col_off + jnp.arange(n_loc) < n_valid
+                    A = jnp.where(real, A, NEG_INF)
+            with jax.named_scope("twod/accumulate"):
+                mm, o, e = _accumulate_block_scores(
+                    A, col_axis=col_axis, r=r, threshold=threshold, k=k,
+                    capacity=capacity, accumulation=accumulation,
+                    row_offset=row_off + blk * bs, col_offset=col_off,
+                )
+            return (carry[0] + o, carry[1] + e), mm
 
-        ov, ms = lax.scan(body, jnp.int32(0), jnp.arange(nb))
+        zero = (jnp.int32(0), jnp.int32(0))
+        (ov, ex), ms = lax.scan(body, zero, jnp.arange(nb))
         m_new = jax.tree.map(lambda x: x.reshape(n_loc, *x.shape[2:]), ms)
         if ticker is not None:
             rank = me_r * r + lax.axis_index(col_axis)
             ticker.emit(s, rank, jnp.sum(m_new.counts) + ov)
-        return merge_matches(matches, m_new), overflow + ov
+        return merge_matches(matches, m_new), (routes[0] + ov, routes[1] + ex)
 
     def step(s, carry):
-        buf, matches, overflow = carry
-        nxt = jax.tree.map(
-            lambda x: lax.ppermute(x, row_axis, perm=_ring_perm(q)), buf
-        )
-        matches, overflow = compute_vs(buf, s, matches, overflow)
-        return nxt, matches, overflow
+        buf, matches, routes = carry
+        with jax.named_scope("twod/ring"):
+            nxt = jax.tree.map(
+                lambda x: lax.ppermute(x, row_axis, perm=_ring_perm(q)), buf
+            )
+        matches, routes = compute_vs(buf, s, matches, routes)
+        return nxt, matches, routes
 
     matches0 = _pvary(_empty_local_matches(n_loc, k), (row_axis, col_axis))
-    buf, matches, overflow = lax.fori_loop(
-        0, q - 1, step,
-        (buf0, matches0, _pvary(jnp.int32(0), (row_axis, col_axis))),
+    zero = _pvary(jnp.int32(0), (row_axis, col_axis))
+    buf, matches, routes = lax.fori_loop(
+        0, q - 1, step, (buf0, matches0, (zero, zero))
     )
-    matches, overflow = compute_vs(buf, q - 1, matches, overflow)
-    overflow = lax.pmax(lax.pmax(overflow, col_axis), row_axis)
-    return matches, ApssStats(overflow_rows=overflow)
+    matches, (overflow, n_exact) = compute_vs(buf, q - 1, matches, routes)
+    # Every chip of a column group took the same routes; sum the groups.
+    n_exact = lax.psum(lax.pmax(n_exact, col_axis), row_axis)
+    return matches, ApssStats(
+        overflow_rows=lax.pmax(lax.pmax(overflow, col_axis), row_axis),
+        blocks_pruned=q * q * nb - n_exact,
+        blocks_exact=n_exact,
+    )
 
 
 def _apss_2d_local(
@@ -1482,6 +1531,35 @@ def _apss_2d_local(
     )
 
 
+class _TickRelay:
+    """The sparse 2-D program is jitted once per shape, but each join under
+    telemetry records a StepTicker of its own. The program plants its ring
+    ticks here (one relay, a static argument that never changes), and the
+    relay hands them to the ticker of the join in flight, which holds it
+    until its ticks have landed. It assumes one telemetry join at a time:
+    two at once would mix their ticks."""
+
+    ticker = None
+
+    def emit(self, step, rank, dep) -> None:
+        jax.debug.callback(self._tick, step, rank, jnp.asarray(dep))
+
+    def _tick(self, step, rank, dep) -> None:
+        if self.ticker is not None:
+            self.ticker._tick(step, rank, dep)
+
+
+_RELAY = _TickRelay()
+
+
+def _twod_rows(n: int, q: int, block_rows: int) -> tuple[int, int]:
+    """The query block ``bs`` and the padded row count ``n_pad`` of a 2-D
+    sparse join: ``n_pad / q`` rows a row shard, a multiple of ``bs``."""
+    per = -(-n // q)
+    bs = min(block_rows, per)
+    return bs, q * bs * -(-per // bs)
+
+
 def _apss_2d_sparse(
     D: SparseCorpus, threshold, k, mesh, row_axis, col_axis, *,
     accumulation, block_rows, candidate_capacity, return_stats,
@@ -1489,125 +1567,141 @@ def _apss_2d_sparse(
     """Sparse 2-D checkerboard: sparse row ring ∘ posting-list-sharded
     accumulation (the last cell of the variant matrix).
 
-    The dimension split is a HOST pre-split (``shard_dims``, like the sparse
-    vertical path): each checkerboard cell gets slice-relative indices and
-    the exact realized per-cell capacity ``cap_loc``, so the traveling CSR
-    pair is as narrow as the data allows. The alternative — a traced-side
-    split — would have to pad every cell to the GLOBAL row cap (traced
-    shapes cannot depend on the data), inflating ring wire volume by
-    ``≈ r·cap/cap_loc`` and scoring FLOPs to match; the price of the host
-    split is that (like sparse vertical) the entry is not traceable under
-    an outer ``jit`` (``planner.plan._has_host_stage``). See DESIGN.md §5.
+    The dimension split runs on the mesh, as the vertical path's does
+    (:func:`_split_on_mesh` with the row axis): the dimensions are dealt to
+    the ``r`` columns of the mesh by posting-list length, the host reads the
+    ``(n, r)`` counts once to cut the cells' width ``W`` (the entries past it
+    ride a spill list with the cell), and chip ``(i, j)`` packs its own cell.
+    Rows are padded with empty rows to a multiple of ``q · bs``; they never
+    match and are dropped from the answer. The host sizing makes the entry
+    not traceable under an outer ``jit`` (``planner.plan._has_host_stage``);
+    what follows it is one jitted program (:func:`_2d_sparse_post_split`).
+    See DESIGN.md §5.
 
     Local (Lemma-1) pruning survives the composition: the compressed
     accumulation thresholds per-cell partials at ``t/r`` exactly as the 1-D
-    vertical algorithm does, and the per-cell tile bounds stay conservative
-    (``core.pruning.checkerboard_live_mask``).
+    vertical algorithm does, and a (query block, ring step) whose candidates
+    overflow the capacity on any chip of its column group takes the
+    all-reduce of its whole partial tile instead.
     """
     q = mesh.shape[row_axis]
     r = mesh.shape[col_axis]
     n = D.n
-    if n % q:
-        raise ValueError(f"n={n} must be a multiple of {row_axis}={q}")
     C = candidate_capacity or default_candidate_capacity(k)
-    # Host split: (r, n, cap_loc) slice-relative indices/values + (r, n) nnz.
-    idx_s, val_s, nnz_s, m_loc = shard_dims(D, r)
-    cap_loc = idx_s.shape[-1]
-    n_loc = n // q
-    bs = _block_clamp(block_rows, n_loc)
-
-    ticker = None
+    bs, n_pad = _twod_rows(n, q, block_rows)
+    idx_s, val_s, spill, cell_nnz, m_loc = _split_on_mesh(
+        D, n_pad, mesh, row_axis, col_axis, "twod"
+    )
+    width = idx_s.shape[-1]
+    routes, ticker = {}, None
     if telemetry.enabled():
         from repro.distributed.straggler import StepTicker
 
-        ticker = StepTicker()
+        ticker = _RELAY.ticker = StepTicker()
+    with trace.span(
+        "twod/dispatch", accumulation=accumulation, capacity=C, block_rows=bs,
+    ):
+        try:
+            out, stats = _2d_sparse_post_split(
+                idx_s, val_s, *spill, n_valid=n, m_loc=m_loc,
+                threshold=threshold, k=k, mesh=mesh, row_axis=row_axis,
+                col_axis=col_axis, accumulation=accumulation, block_rows=bs,
+                candidate_capacity=C, relay=None if ticker is None else _RELAY,
+            )
+            if ticker is not None:
+                # waits for the join: the counts exist once it has run
+                routes = {f: int(getattr(stats, f)) for f in (
+                    "blocks_pruned", "blocks_exact", "overflow_rows")}
+                trace.annotate(**routes)
+                jax.effects_barrier()  # every ring tick has landed
+        finally:
+            if ticker is not None:
+                _RELAY.ticker = None
+    if ticker is not None:
+        n_loc = n_pad // q
         telemetry.record(telemetry.ApssStats(
             variant=f"2d/{accumulation}",
             n=n, m=D.m, devices=q * r, block_rows=bs, sparse=True,
             hops=telemetry.twod_hops(
                 q, r, str(row_axis), str(col_axis), n_loc, D.m, 4, bs, C,
-                accumulation, cap_loc=cap_loc,
+                accumulation, cap_loc=width, spill=spill[0].shape[-1] // q,
             ),
-            flops=telemetry.sparse_join_flops(n_loc, n, cap_loc),
+            flops=telemetry.sparse_join_flops(n_loc, n_pad, width),
             extra={
                 "mesh": {str(row_axis): q, str(col_axis): r},
-                "cap_loc": cap_loc,
+                "capacity": C, "cap_loc": width, "n_pad": n_pad,
+                "shard_nnz": [int(x) for x in cell_nnz], **routes,
             },
             step_ticker=ticker,
         ))
-
-    out, stats = _2d_sparse_post_split(
-        idx_s, val_s, nnz_s, m_loc=m_loc, threshold=threshold, k=k,
-        mesh=mesh, row_axis=row_axis, col_axis=col_axis,
-        accumulation=accumulation, block_rows=block_rows,
-        candidate_capacity=C, ticker=ticker,
-    )
     if return_stats:
         return out, stats
     return out
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "n_valid", "m_loc", "threshold", "k", "mesh", "row_axis", "col_axis",
+        "accumulation", "block_rows", "candidate_capacity", "relay",
+    ),
+)
 def _2d_sparse_post_split(
-    idx_s, val_s, nnz_s, *, m_loc, threshold, k, mesh, row_axis, col_axis,
-    accumulation, block_rows, candidate_capacity, ticker=None,
+    idx_s, val_s, spill_row, spill_idx, spill_val, *, n_valid, m_loc,
+    threshold, k, mesh, row_axis, col_axis, accumulation, block_rows,
+    candidate_capacity, relay=None,
 ):
-    """Everything AFTER the host ``shard_dims`` split — jit-lowerable, so
-    the compile audit can AOT-compile the sparse checkerboard family
-    through this seam (mirrors ``_vertical_sparse_post_split``)."""
+    """Everything AFTER the split (:func:`_split_on_mesh`) — one jitted
+    program, so a join of the same shapes compiles once (the compile audit
+    AOT-compiles the sparse checkerboard family through this seam, as it
+    does ``_vertical_sparse_post_split``). ``block_rows`` divides the row
+    shards. Returns the ``n_valid`` real rows' matches and the route counts.
+
+    The traveling cell is the cut rows and the spill list: a partial tile is
+    ``gather_dot`` over the cell's ``(n_loc, W)`` rows plus its spill
+    entries, gathered to their columns, as on the vertical path. ``relay``
+    (a :class:`_TickRelay`, or ``None``) plants the ring steps' host ticks.
+    """
     q = mesh.shape[row_axis]
     r = mesh.shape[col_axis]
-    fn = functools.partial(
-        _apss_2d_sparse_local,
-        m_loc=m_loc, threshold=threshold, k=k, row_axis=row_axis,
-        col_axis=col_axis, q=q, r=r, block_rows=block_rows,
-        capacity=candidate_capacity, accumulation=accumulation,
-        ticker=ticker,
-    )
+    bs = block_rows
+
+    def local(idxL, valL, rowS, idxS, valS):
+        # cell dims (1, ...) → local
+        idxL, valL, rowS, idxS, valS = (
+            a[0] for a in (idxL, valL, rowS, idxS, valS)
+        )
+        n_loc, width = idxL.shape
+        sp_loc = SparseCorpus(idxL, valL, jnp.zeros((n_loc,), jnp.int32), m_loc)
+        spill = (rowS, idxS, valS)
+        cells = (n_loc // bs, bs, width)
+
+        def partials(buf, blk):
+            Ci, Cv, *travel = buf
+            qd = _query_block(sp_loc, spill, blk * bs, bs)
+            return _partial_tile(
+                qd, Ci.reshape(cells), Cv.reshape(cells), travel
+            )
+
+        return _checkerboard_sweep(
+            partials, (idxL, valL, *spill), n_loc,
+            threshold=threshold, k=k, row_axis=row_axis, col_axis=col_axis,
+            q=q, r=r, bs=bs, capacity=candidate_capacity,
+            accumulation=accumulation, ticker=relay,
+            n_valid=n_valid if n_valid < q * n_loc else None,
+        )
+
+    cells, spills = P(col_axis, row_axis, None), P(col_axis, row_axis)
     # Same VMA caveat as every sparse schedule: no checker rule for the
     # scatter/gather ops inside the sparse tile primitive.
-    return shard_map(
-        fn,
+    out, stats = shard_map(
+        local,
         mesh=mesh,
-        in_specs=(
-            P(col_axis, row_axis, None),
-            P(col_axis, row_axis, None),
-            P(col_axis, row_axis),
-        ),
-        out_specs=(
-            Matches(
-                values=P(row_axis, None),
-                indices=P(row_axis, None),
-                counts=P(row_axis),
-            ),
-            ApssStats(overflow_rows=P()),
-        ),
+        in_specs=(cells, cells, spills, spills, spills),
+        out_specs=(_matches_specs(row_axis), ApssStats(P(), P(), P())),
         check_vma=False,
-    )(jnp.asarray(idx_s), jnp.asarray(val_s), jnp.asarray(nnz_s))
-
-
-def _apss_2d_sparse_local(
-    idx, val, nnz, *, m_loc, threshold, k, row_axis, col_axis, q, r,
-    block_rows, capacity, accumulation, ticker=None,
-):
-    # Shard dims (1, n_loc, cap_loc) / (1, n_loc) → local cell.
-    idx, val, nnz = idx[0], val[0], nnz[0]
-    n_loc = idx.shape[0]
-    bs = _block_clamp(block_rows, n_loc)
-    sp_loc = SparseCorpus(idx, val, nnz, m_loc)
-
-    def partials(buf, blk):
-        qd = densify_rows(sp_loc, blk * bs, bs)  # (bs, m_loc)
-        bi, bv = buf
-        return gather_dot(qd, bi, bv)            # (bs, n_loc)
-
-    # The traveling cell is the CSR pair only: scoring sums every slot and
-    # padding is inert, so the nnz vector never needs to ride the ring.
-    return _checkerboard_sweep(
-        partials, (idx, val), n_loc,
-        threshold=threshold, k=k, row_axis=row_axis, col_axis=col_axis,
-        q=q, r=r, bs=bs, capacity=capacity, accumulation=accumulation,
-        ticker=ticker,
-    )
+    )(idx_s, val_s, spill_row, spill_idx, spill_val)
+    return jax.tree.map(lambda x: x[:n_valid], out), stats
 
 
 # ---------------------------------------------------------------------------

@@ -26,9 +26,9 @@ vertical / hierarchical / 2-D checkerboard — plus the serving
 ``query_topk`` inners and the mutable delta join, captured from REAL call
 sites via ``obs.compile.capture_calls`` (their worklist arguments are
 built host-side, so the audit lowers the exact program the hot path
-runs). Host-staged sparse families (dimensions dealt by frequency: the
-vertical split sized on the host, the 2-D ``shard_dims`` pre-split) lower
-through the post-split seams
+runs). Host-staged sparse families (dimensions dealt by frequency on the
+mesh, the split sized on the host: vertical and 2-D) lower through the
+post-split seams
 ``core.distributed._vertical_sparse_post_split`` / ``_2d_sparse_post_split``.
 
 Known, documented gaps (reported as entry notes, not failures):
@@ -251,7 +251,6 @@ def _lower_planned(cfg, data, threshold: float, k: int, mesh):
     import jax
 
     from repro.core import distributed as dist
-    from repro.core.sparse import shard_dims
     from repro.planner import plan as planner_plan
 
     name = _family_name(cfg)
@@ -276,17 +275,20 @@ def _lower_planned(cfg, data, threshold: float, k: int, mesh):
             jax.jit(seam), idx_s, val_s, *spill, name=name,
         )
     if cfg.kind == "2d":
-        r = mesh.shape[names[1]]
-        idx_s, val_s, nnz_s, m_loc = shard_dims(data, r)
+        q = mesh.shape[names[0]]
+        bs, n_pad = dist._twod_rows(data.n, q, cfg.block_rows)
+        idx_s, val_s, spill, _, m_loc = dist._split_on_mesh(
+            data, n_pad, mesh, names[0], names[1], "twod"
+        )
         seam = functools.partial(
             dist._2d_sparse_post_split,
-            m_loc=m_loc, threshold=float(threshold), k=k, mesh=mesh,
-            row_axis=names[0], col_axis=names[1],
-            accumulation=cfg.accumulation, block_rows=cfg.block_rows,
+            n_valid=data.n, m_loc=m_loc, threshold=float(threshold), k=k,
+            mesh=mesh, row_axis=names[0], col_axis=names[1],
+            accumulation=cfg.accumulation, block_rows=bs,
             candidate_capacity=dist.default_candidate_capacity(k),
         )
         return obs_compile.lower_and_compile(
-            jax.jit(seam), idx_s, val_s, nnz_s, name=name,
+            jax.jit(seam), idx_s, val_s, *spill, name=name,
         )
     raise ValueError(f"no lowering seam for host-staged config {cfg.name}")
 

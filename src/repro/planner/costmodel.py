@@ -257,8 +257,9 @@ def variant_hops(
 def _cell_cap(s: CorpusSummary, r: int) -> int:
     """Planner-side estimate of the 2-D cell capacity ``cap_loc`` — the
     balanced split ``⌈cap/r⌉``. The realized value (known only after the
-    host ``shard_dims`` pre-split) is the max per-slice row count and grows
-    toward ``cap`` under posting-list skew; the runtime telemetry record
+    split's host read of the per-shard counts) is the cut width, rounded up
+    to the gather chunk and grown under posting-list skew; the runtime
+    telemetry record
     carries the exact number, so ``bench_planner`` surfaces any drift as a
     predicted-vs-measured gap rather than silent model error."""
     return max(1, -(-s.cap // r))

@@ -276,20 +276,19 @@ def candidate_configs(
     if len(names) == 2:
         q, r = sizes[names[0]], sizes[names[1]]
         # Both representations split the dimension axis r ways: dense as
-        # P(row, col) column shards (m must divide), sparse as shard_dims
-        # posting lists (any m).
-        if s.n % q == 0:
-            n_loc = s.n // q
-            for sparse in reps:
-                if not sparse and s.m % r:
-                    continue
-                for b in blocks:
-                    for acc in ("allreduce", "compressed"):
-                        cfgs.append(
-                            VariantConfig(
-                                "2d", sparse, min(b, n_loc), accumulation=acc,
-                            )
+        # P(row, col) shards (n and m must divide), sparse as posting lists
+        # dealt on the mesh, rows padded to the block (any n and m).
+        n_loc = -(-s.n // q)
+        for sparse in reps:
+            if not sparse and (s.m % r or s.n % q):
+                continue
+            for b in blocks:
+                for acc in ("allreduce", "compressed"):
+                    cfgs.append(
+                        VariantConfig(
+                            "2d", sparse, min(b, n_loc), accumulation=acc,
                         )
+                    )
     return list(dict.fromkeys(cfgs))
 
 
@@ -388,7 +387,8 @@ def execute(
     frozen ``VariantConfig``), so repeated executions — the autotuner, the
     benchmark, a caller's request loop — pay compilation once per config,
     exactly like the hand-written call sites. Host-staged configs (sparse
-    worklist / ``shard_dims``) run eagerly, as they do everywhere else.
+    worklist, the sized dimension split) run eagerly, as they do everywhere
+    else.
 
     ``prepared=True`` declares ``corpus`` already in the config's
     representation: timed callers (autotune, ``bench_planner``) convert

@@ -265,11 +265,12 @@ def csr_block_bytes(rows: int, cap: int) -> int:
     return rows * cap * 8 + rows * 4
 
 
-def csr_cell_bytes(rows: int, cap: int) -> int:
+def csr_cell_bytes(rows: int, cap: int, spill: int = 0) -> int:
     """Wire bytes of a traveling 2-D checkerboard CSR cell: (idx i32 + val
-    f32)·cap_loc only — the sparse 2-D ring ships no nnz vector (scoring
-    sums every slot and padding slots are arithmetically inert)."""
-    return rows * cap * 8
+    f32)·cap_loc a row, plus ``spill`` (row i32, idx i32, val f32) entries
+    past the width — the sparse 2-D ring ships no nnz vector (scoring sums
+    every slot and padding slots are arithmetically inert)."""
+    return rows * cap * 8 + spill * 12
 
 
 def matches_bytes(rows: int, k: int) -> int:
@@ -411,6 +412,7 @@ def twod_hops(
     capacity: int,
     accumulation: str,
     cap_loc: int | None = None,
+    spill: int = 0,
 ) -> tuple[CollectiveHop, ...]:
     """2-D checkerboard: a row-axis ring of per-cell corpus blocks composed
     with a vertical accumulation of each ``(bs, n_loc)`` partial tile per ring
@@ -418,7 +420,7 @@ def twod_hops(
 
     ``cap_loc`` switches the ring payload to the sparse cell: a dense cell is
     ``(n_loc, m/r)`` values, a sparse cell the per-cell CSR pair of width
-    ``cap_loc`` (the realized max per-cell row count after ``shard_dims``).
+    ``cap_loc`` (the split's cut width) and its ``spill`` list.
     The inner accumulation hops are representation-agnostic either way — they
     carry candidate ids/scores, never corpus payloads."""
     hops: list[CollectiveHop] = []
@@ -427,7 +429,7 @@ def twod_hops(
             block = dense_block_bytes(n_loc, m // r, itemsize)
             payload = "dense_block"
         else:
-            block = csr_cell_bytes(n_loc, cap_loc)
+            block = csr_cell_bytes(n_loc, cap_loc, spill)
             payload = "csr_cell"
         hops.append(
             CollectiveHop("ppermute", payload, row_axis, block, q - 1)

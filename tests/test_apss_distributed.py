@@ -75,6 +75,22 @@ def test_2d(corpus, mesh4x2, ref, accumulation):
     _check(got, ref)
 
 
+def test_2d_overflowing_blocks_take_the_exact_route(corpus, mesh4x2, ref):
+    """A capacity of 2 overflows on some tiles: the dense 2-D
+    sweep scores those by the all-reduce of the whole tile, so the join
+    stays the oracle's, and the route counts cover every (query block, ring
+    step) tile of every row shard."""
+    got, stats = jax.jit(
+        lambda d: apss_2d(
+            d, T, K, mesh4x2, accumulation="compressed", block_rows=16,
+            candidate_capacity=2, return_stats=True,
+        )
+    )(jnp.asarray(corpus))
+    _check(got, ref)
+    assert int(stats.overflow_rows) > 0 and int(stats.blocks_exact) > 0
+    assert int(stats.blocks_pruned) + int(stats.blocks_exact) == 4 * 4 * 2
+
+
 def test_hierarchical_2level(corpus, ref):
     mesh = make_mesh((2, 4), ("pod", "data"))
     got = jax.jit(

@@ -166,11 +166,13 @@ def test_serving_query_records_live_fraction():
 
 def test_sparse_2d_composed_wire_volume():
     """Composed 2-D schedule: the row-axis ring ships the per-cell CSR pair
-    exactly ``q-1`` times at ``csr_cell_bytes(n_loc, cap_loc)`` per hop
-    (``cap_loc`` the REALIZED post-split width, not the global cap); the
-    inner accumulation hops are representation-agnostic — identical to the
-    dense record's — and run once per ring step (hop counts ×q)."""
+    and its spill list exactly ``q-1`` times at ``csr_cell_bytes(n_loc,
+    cap_loc, spill)`` per hop (``cap_loc`` the split's cut width, a multiple
+    of the gather chunk, not the global cap); the inner accumulation hops
+    are representation-agnostic — identical to the dense record's — and run
+    once per ring step (hop counts ×q)."""
     from repro.compat import make_mesh
+    from repro.core import distributed as dist
     from repro.core.distributed import apss_2d
     from repro.core.sparse import shard_dims
     from repro.planner.telemetry import csr_cell_bytes
@@ -179,7 +181,7 @@ def test_sparse_2d_composed_wire_volume():
     sp = from_dense(D)
     mesh = make_mesh((4, 2), ("data", "model"))
     q, r, n_loc = 4, 2, 32
-    cap_loc = shard_dims(sp, r)[0].shape[-1]
+    cap_loc, spill = dist._cut_width(shard_dims(sp, r)[2].T, q, q * n_loc)
     with CommLog() as log:
         apss_2d(
             jnp.asarray(D), T, K, mesh, accumulation="compressed",
@@ -192,17 +194,18 @@ def test_sparse_2d_composed_wire_volume():
     (ring,) = [h for h in sparse_rec.hops if h.payload == "csr_cell"]
     assert ring.op == "ppermute" and ring.axis == "data"
     assert ring.hops == q - 1
-    assert ring.bytes_per_hop == csr_cell_bytes(n_loc, cap_loc)
+    assert ring.bytes_per_hop == csr_cell_bytes(n_loc, cap_loc, spill)
 
     inner_s = [h for h in sparse_rec.hops if h.payload != "csr_cell"]
     inner_d = [h for h in dense_rec.hops if h.payload != "dense_block"]
     assert inner_s == inner_d  # candidate ids/scores never carry the corpus
     assert inner_s and all(h.hops % q == 0 for h in inner_s)
 
-    # The sparse cell undercuts the dense cell's (n_loc, m/r) payload.
+    # The sparse cell undercuts the dense cell's (n_loc, m/r) payload: at 5%
+    # density its width is 64 (the realized 39 rounded up to the chunk).
     db = dense_rec.bytes_by_payload()["dense_block"]
     sb = sparse_rec.bytes_by_payload()["csr_cell"]
-    assert sb < 0.25 * db  # 5% density: 8·cap_loc ≪ 4·m/r
+    assert sb < 0.3 * db  # 8·cap_loc + 12·spill/n_loc ≪ 4·m/r
 
 
 def test_vertical_compressed_vs_allreduce_volume(mesh8_model):
